@@ -1004,7 +1004,7 @@ class Accelerator:
             try:
                 jax.profiler.start_server(port)
                 server_started = True
-            except Exception as e:  # port in use / older jax: trace still works
+            except Exception as e:  # port in use: the trace still works
                 logger.warning(f"profile(): could not start profiler server on port {port}: {e}")
         os.makedirs(log_dir, exist_ok=True)
         meta = {

@@ -83,10 +83,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", {n})
-except AttributeError:
-    pass  # older jax: the XLA_FLAGS export above already forced {n} host devices
+jax.config.update("jax_num_cpu_devices", {n})
 main_path = sys.argv[2] if len(sys.argv) > 2 and sys.argv[2] else None
 if main_path:
     # multiprocessing-spawn style: re-import the caller's script as

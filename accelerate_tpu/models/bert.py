@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from ..utils.constants import MESH_AXIS_TENSOR
 from .attention import dense_init, dot_product_attention, dropout, resolve_dot
 from .config import TransformerConfig, get_config
-from .llama import BATCH_AXES, _constrain
 
 
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float) -> jax.Array:
@@ -139,7 +138,6 @@ class Bert:
             + jnp.take(emb["token_type"], token_type_ids, axis=0)
         )
         h = layer_norm(h, emb["norm_scale"], emb["norm_bias"], cfg.norm_eps)
-        h = _constrain(h, BATCH_AXES, None, None)
         use_dropout = dropout_rng is not None and cfg.dropout_rate > 0.0
         if use_dropout:
             emb_rng, layers_rng = jax.random.split(dropout_rng)

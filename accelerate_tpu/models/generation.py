@@ -182,8 +182,8 @@ def generate(
 
     ``return_device=True`` returns the concatenated ids as a DEVICE array with
     no host fetch — benchmarks use it so the clock can stop on
-    ``block_until_ready`` instead of paying the transport's fixed device→host
-    fetch latency inside the timed region.
+    ``block_until_ready`` instead of paying the device→host fetch inside the
+    timed region.
 
     ``eos_token_id`` carries a per-row done mask through the decode scan:
     once a row emits EOS, every later position feeds and emits EOS (a no-op

@@ -21,11 +21,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils.constants import MESH_AXIS_SEQUENCE, MESH_AXIS_TENSOR
+from ..utils.constants import MESH_AXIS_TENSOR
 from .attention import dense_init, dot_product_attention, dropout, resolve_dot
 from .bert import layer_norm
 from .config import TransformerConfig, get_config
-from .llama import BATCH_AXES, _constrain
 
 
 class GPT2:
@@ -236,7 +235,6 @@ class GPT2:
         h = jnp.take(params["embed_tokens"], input_ids, axis=0) + jnp.take(
             params["embed_positions"], positions, axis=0
         )
-        h = _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None)
         mask = None
         if attention_mask is not None:
             mask = attention_mask[:, None, None, :].astype(bool)
@@ -255,7 +253,7 @@ class GPT2:
                 lp = xs[0] if use_dropout else xs
                 rngs = tuple(xs[1]) if use_dropout else (None, None)
                 h = self._block(h, lp, mask, rngs, kv_mask=attention_mask)
-                return _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None), None
+                return h, None
 
             xs = (params["layers"], layer_rngs) if use_dropout else params["layers"]
             body = (

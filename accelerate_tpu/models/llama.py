@@ -6,8 +6,8 @@ Design (vs the reference's torch models, which it only orchestrates):
   partition specs apply uniformly to every layer.
 - attention/MLP projections carry explicit TP partition rules (megatron-style
   column/row split) that the sharding engine folds with the fsdp axis.
-- activations get sharding constraints (batch over data axes, sequence over
-  the ``sequence`` axis) so GSPMD propagates the layout end to end.
+- activation layouts are GSPMD's, propagated from the batch and parameter
+  shardings (ring attention places the ``sequence`` axis through shard_map).
 - bf16-friendly: RMSNorm and softmax accumulate in fp32.
 
 Capability parity: the model families the reference's examples/benchmarks
@@ -21,27 +21,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ..utils.constants import (
-    MESH_AXIS_DATA,
-    MESH_AXIS_EXPERT,
-    MESH_AXIS_FSDP,
-    MESH_AXIS_SEQUENCE,
-    MESH_AXIS_TENSOR,
-)
+from ..utils.constants import MESH_AXIS_EXPERT, MESH_AXIS_TENSOR
 from .attention import apply_rotary, dense_init, dot_product_attention, dropout, rotary_embedding
 from .config import TransformerConfig, get_config
-
-BATCH_AXES = (MESH_AXIS_DATA, MESH_AXIS_FSDP)
-
-
-def _constrain(x: jax.Array, *spec) -> jax.Array:
-    """Best-effort sharding constraint (no-op outside a mesh context)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except (ValueError, RuntimeError):
-        return x
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -149,9 +132,9 @@ class Llama:
     # -- parameters --------------------------------------------------------
 
     def init(self, rng: jax.Array) -> dict:
-        # One compiled program instead of ~10 per-tensor RNG dispatches — on
-        # remote-attached TPUs each dispatch is a round trip. The jit wrapper
-        # is cached on the instance so repeated init() reuses the compile.
+        # One compiled program instead of ~10 per-tensor RNG dispatches. The
+        # jit wrapper is cached on the instance so repeated init() reuses the
+        # compile.
         if not hasattr(self, "_init_jit"):
             self._init_jit = jax.jit(self._init)
         return self._init_jit(rng)
@@ -233,7 +216,6 @@ class Llama:
         d, nh, nkv = cfg.dim_per_head, cfg.num_heads, cfg.kv_heads
 
         h = jnp.take(params["embed_tokens"], input_ids, axis=0)
-        h = _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None)
         if positions is None:
             positions = jnp.arange(s)[None, :]
         elif positions.ndim == 1:
@@ -260,7 +242,6 @@ class Llama:
                 attention_fn=self.attention_fn, kv_mask=attention_mask,
                 dot_fn=self.dot_fn, return_aux=True,
             )
-            h = _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None)
             return h, aux
 
         total_aux = jnp.zeros((), jnp.float32)

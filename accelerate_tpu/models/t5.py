@@ -26,10 +26,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..utils.constants import MESH_AXIS_SEQUENCE, MESH_AXIS_TENSOR
+from ..utils.constants import MESH_AXIS_TENSOR
 from .attention import dense_init, dropout, resolve_dot
 from .config import TransformerConfig, get_config
-from .llama import BATCH_AXES, _constrain, rms_norm
+from .llama import rms_norm
 
 NEG_INF = -1e30
 
@@ -282,7 +282,6 @@ class T5:
         cfg = self.config
         b, s = input_ids.shape
         h = jnp.take(params["shared_embed"], input_ids, axis=0)
-        h = _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None)
         positions = jnp.arange(s)
         bias = relative_bias(
             params["enc_rel_bias"], positions, positions,
@@ -305,7 +304,7 @@ class T5:
             lp = xs[0] if use_dropout else xs
             rngs = tuple(xs[1]) if use_dropout else (None, None)
             h = self._enc_layer(h, lp, bias, mask, rngs, kv_mask=attention_mask)
-            return _constrain(h, BATCH_AXES, MESH_AXIS_SEQUENCE, None), None
+            return h, None
 
         xs = (params["encoder"], layer_rngs) if use_dropout else params["encoder"]
         body = (
@@ -335,7 +334,6 @@ class T5:
 
         b, s = decoder_input_ids.shape
         h = jnp.take(params["shared_embed"], decoder_input_ids, axis=0)
-        h = _constrain(h, BATCH_AXES, None, None)
         positions = jnp.arange(s)
         self_bias = relative_bias(
             params["dec_rel_bias"], positions, positions,
@@ -367,7 +365,7 @@ class T5:
                     h, lp, self_bias, self_mask, enc_out, enc_mask, rngs,
                     kv_masks=(decoder_attention_mask, attention_mask),
                 )
-                return _constrain(h, BATCH_AXES, None, None), None
+                return h, None
 
             xs = (params["layers"], layer_rngs) if use_dropout else params["layers"]
             body = (

@@ -641,6 +641,27 @@ def _mask_limit(kv_mask: jax.Array):
     return jnp.broadcast_to(mask[:, None, :], (b, 8, s)), limit
 
 
+def _einsum_reason(q, s: int, t: int, blocks: tuple) -> Optional[str]:
+    """Why this call cannot run the kernel and takes the exact einsum path
+    instead (None = the kernel runs). ``blocks`` are the fitted
+    (fwd q, fwd k, bwd q, bwd k) tiles."""
+    bq, bk, bbq, bbk = blocks
+    # interpret-mode pallas inside a shard_map manual region (CPU pipeline
+    # tests) trips a jax hlo_interpreter lowering-cache bug; real TPUs lower
+    # through Mosaic and keep the kernel
+    if _interpret() and getattr(getattr(q, "aval", None), "vma", None):
+        return "interpret-mode Pallas inside a shard_map manual region"
+    if any(x % 128 for x in blocks) or s % bq or t % bk or s % bbq or t % bbk:
+        return f"sequence lengths ({s}, {t}) do not tile into 128-multiple blocks"
+    return None
+
+
+def _log_einsum(op: str, reason: str) -> None:
+    from ..logging import get_logger
+
+    get_logger(__name__).warning_once(f"{op}: einsum path, not the Pallas kernel: {reason}")
+
+
 def flash_attention(
     q: jax.Array,  # [B, S, N, D] (model-zoo layout)
     k: jax.Array,  # [B, T, KV, D]
@@ -674,14 +695,13 @@ def flash_attention(
     bq, bk = _fit_block(block_q, s), _fit_block(block_k, t)
     bbq = _fit_block(bwd_block_q or BWD_BLOCK_Q, s)
     bbk = _fit_block(bwd_block_k or BWD_BLOCK_K, t)
-    # interpret-mode pallas inside a shard_map manual region (CPU pipeline
-    # tests) trips a jax hlo_interpreter lowering-cache bug — use the exact
-    # einsum path there; real TPUs lower through Mosaic and keep the kernel
-    in_manual_region = bool(getattr(getattr(q, "aval", None), "vma", None))
-    untileable = any(x % 128 for x in (bq, bk, bbq, bbk)) or s % bq or t % bk or s % bbq or t % bbk
-    if (in_manual_region and _interpret()) or untileable or (causal and s != t):
+    reason = _einsum_reason(q, s, t, (bq, bk, bbq, bbk))
+    if reason is None and causal and s != t:
+        reason = f"causal attention over distinct q/kv lengths ({s}, {t})"
+    if reason is not None:
         from ..models.attention import dot_product_attention
 
+        _log_einsum("flash_attention", reason)
         mask = None if kv_mask is None else kv_mask[:, None, None, :].astype(bool)
         return dot_product_attention(q, k, v, mask=mask, causal=causal, scale=scale, bias=bias)
     if scale is None:
@@ -764,9 +784,9 @@ def flash_attention_block(
     bq, bk = _fit_block(block_q, s), _fit_block(block_k, t)
     bbq = _fit_block(bwd_block_q or BWD_BLOCK_Q, s)
     bbk = _fit_block(bwd_block_k or BWD_BLOCK_K, t)
-    in_manual_region = bool(getattr(getattr(q, "aval", None), "vma", None))
-    untileable = any(x % 128 for x in (bq, bk, bbq, bbk)) or s % bq or t % bk or s % bbq or t % bbk
-    if (in_manual_region and _interpret()) or untileable:
+    reason = _einsum_reason(q, s, t, (bq, bk, bbq, bbk))
+    if reason is not None:
+        _log_einsum("flash_attention_block", reason)
         return _einsum_attention_lse(q, k, v, kv_mask, causal, q_offset, kv_offset, scale)
     if scale is None:
         scale = 1.0 / math.sqrt(d)

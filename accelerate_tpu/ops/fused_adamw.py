@@ -25,9 +25,9 @@ implementations differ between Mosaic and XLA; a scalar per step costs
 nothing); ``u = mû/(√(ν̂+eps_root)+eps) + wd·p``; ``p' = p - lr·u`` — so
 ``tests/test_fused_adamw.py`` pins tolerance-0 equality against
 ``optax.adamw`` per step, and the ZeRO update-equivalence gate holds with
-the kernel engaged. Leaves whose element count cannot tile (and every leaf
-on Mosaic-unaligned geometries) take a reference path built from the SAME
-formula, keeping the transform exact leaf by leaf.
+the kernel engaged. Under Mosaic a leaf whose element count is not a
+multiple of 128 (a two-element bias) takes a reference path built from the
+SAME formula, keeping the transform exact leaf by leaf, and says so once.
 """
 
 from __future__ import annotations
@@ -43,8 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .runtime import fit_block as _fit
 from .runtime import interpret_mode, sds
 
-# lane width 128 is fixed; rows per block bound the VMEM working set
-# (4 operands + 3 outputs x 8 sublane-rows x 512 lanes x 4B ~= 7 MB ceiling)
+# lane width 128 is fixed; rows per block bound the VMEM working set: 4 inputs
+# + 3 outputs of (256, 512) fp32, double-buffered, are ~7 MiB of the 16 MiB
+# scoped-VMEM default
 _LANES = 512
 _BLOCK_ROWS = 256
 
@@ -62,15 +63,17 @@ class AdamWHyperparams(NamedTuple):
 
 def _leaf_geometry(n: int) -> Optional[tuple[int, int, int]]:
     """(rows, cols, block_rows) tiling ``n`` elements, or None when the leaf
-    cannot tile (kernel falls back to the reference formula for that leaf).
-    Mosaic needs 128-multiple lanes; interpret mode takes any 2-D split."""
+    cannot tile (the update then runs the reference formula for that leaf).
+    Mosaic needs 128-multiple lanes; interpret mode takes any 2-D split. A
+    leaf of at most one block is a single full-array block (legal whatever
+    its row count); a longer one walks ``_BLOCK_ROWS``-row blocks with a
+    ragged last block, whose out-of-range rows Pallas pads on read and
+    drops on write."""
     cols = _fit(_LANES, n, floor=1)
-    if n % cols:
+    if not interpret_mode() and cols % 128:
         return None
     rows = n // cols
-    if not interpret_mode() and (cols % 128 or rows % 8):
-        return None
-    return rows, cols, _fit(_BLOCK_ROWS, rows, floor=1)
+    return rows, cols, min(rows, _BLOCK_ROWS)
 
 
 def _adamw_kernel(bc_ref, p_ref, mu_ref, nu_ref, g_ref, po_ref, muo_ref, nuo_ref, *, hp):
@@ -104,6 +107,13 @@ def _reference_leaf(p, mu, nu, g, bc1, bc2, hp: AdamWHyperparams):
 def _fused_leaf(p, mu, nu, g, bc, hp: AdamWHyperparams):
     geom = _leaf_geometry(p.size)
     if geom is None:
+        from ..logging import get_logger
+
+        get_logger(__name__).warning_once(
+            f"fused_adamw: a leaf of shape {p.shape} ({p.size} elements) is not a "
+            "multiple of 128 lanes — Mosaic cannot tile it, so it takes the "
+            "reference adamw formula instead of the kernel."
+        )
         return _reference_leaf(p, mu, nu, g, bc[0, 0], bc[0, 1], hp)
     rows, cols, br = geom
     shape = p.shape
@@ -115,7 +125,7 @@ def _fused_leaf(p, mu, nu, g, bc, hp: AdamWHyperparams):
     specs = [pl.BlockSpec((br, cols), block, memory_space=pltpu.VMEM)]
     p_new, mu_new, nu_new = pl.pallas_call(
         functools.partial(_adamw_kernel, hp=hp),
-        grid=(rows // br,),
+        grid=(pl.cdiv(rows, br),),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + specs * 4,
         out_specs=specs * 3,
         out_shape=[

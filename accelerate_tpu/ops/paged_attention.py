@@ -8,29 +8,39 @@ proportional to tokens actually held, but the gather still moves — and
 temporarily materializes — ``view_len`` worth of K/V per slot per token,
 regardless of how few positions are valid.
 
-This kernel attends the page pool DIRECTLY: each program owns one
-(slot, kv-head) pair — the slot axis rides in as a vmap-batched grid
-dimension, so one slot-batched launch serves every lane of the decode step —
-walks that slot's int32 page-table row up to its dynamic ``length`` bound,
-DMAs one ``[page_size, D]`` page block at a time from HBM into VMEM, and
-folds it into an online softmax. The gathered view is never materialized,
-invalid pages are never read (a fresh request touches one page, not
-``view_len``), and the current token's K/V — not yet scattered into the
-pool — joins the softmax as a final key, so the engine's write-back stays
-a separate scatter exactly as in the reference program.
+This kernel attends the page pool DIRECTLY: one program per slot walks that
+slot's int32 page-table row (scalar-prefetched into SMEM) up to its dynamic
+``length`` bound, DMAs one whole ``[page_size, KV, D]`` page — contiguous in
+the pool — from HBM into VMEM at a time, and folds it into an online softmax
+for every head at once. The gathered view is never materialized, invalid
+pages are never read (a fresh request touches one page, not ``view_len``),
+and the candidate window's own K/V — not yet scattered into the pool — joins
+the softmax as a final block under a causal in-window mask, so the engine's
+write-back stays a separate scatter exactly as in the reference program.
+Plain decode is the window of one token.
 
-Numerics: scores accumulate in fp32 (``preferred_element_type``), the
-running max starts at the flash kernel's ``M_INIT`` so padded tail
-positions of a partial page underflow ``exp`` to exactly 0. The new-token
-score is always valid, so a decode row can never be fully masked. At
+The pool keeps heads on the sublane axis (``[.., KV, D]`` tiles), so scores
+are a lane reduction per head on the VPU rather than an MXU matmul — every
+op is a ``[.., KV, D]`` elementwise product, a minor-axis reduce, or a
+major-axis reduce, which is what Mosaic lowers for this layout without a
+relayout. All arithmetic is fp32.
+
+The engine calls the op per slot under its slot ``vmap``; a custom batching
+rule turns that into ONE slot-batched ``pallas_call`` per layer per step
+with the slot axis as the grid.
+
+Numerics: the running max starts at the flash kernel's ``M_INIT`` so padded
+tail positions of a partial page underflow ``exp`` to exactly 0. A window
+row's own key is always valid, so a row can never be fully masked. At
 temperature 0 the engine's kernel path emits the same tokens as the
-gather-reference path (pinned by tests/test_paged_attention.py over mixed
-lengths for both decode protocols); the blocked accumulation order means
-logits agree to roundoff, not bit-for-bit.
+gather-reference path in fp32 (pinned by tests/test_paged_attention.py over
+mixed lengths for both decode protocols); the blocked accumulation order
+means logits agree to roundoff, not bit-for-bit.
 
 Off-TPU the kernel runs in interpret mode (tier-1 exercises the page walk
-for real); shapes Mosaic cannot tile (lane-unaligned head dim) fall back to
-a gather reference with identical masking semantics.
+for real). Geometries Mosaic cannot tile are named by
+:func:`paged_kernel_fallback_reason`; the engine then keeps its gather
+program and reports why.
 """
 
 from __future__ import annotations
@@ -50,257 +60,170 @@ from .runtime import interpret_mode
 def paged_kernel_fallback_reason(
     page_shape: tuple, num_heads: int, kv_heads: int
 ) -> Optional[str]:
-    """Why the paged decode kernel cannot serve this pool geometry (None =
-    it can). Interpret mode runs any shape; Mosaic needs the head dim to
-    fill lanes. The engine records the reason in its ``{"kind":"kernels"}``
-    telemetry so a fleet's kernel coverage is a query away."""
-    ps, d = int(page_shape[-3]), int(page_shape[-1])
+    """Why the paged kernel cannot serve this pool geometry (None = it can).
+    Interpret mode runs any shape; Mosaic DMAs whole ``[page_size, KV, D]``
+    pages, whose ``(KV, D)`` face must fill ``(8, 128)`` tiles. The engine
+    records the reason in its ``{"kind":"kernels"}`` telemetry so a fleet's
+    kernel coverage is a query away."""
+    d = int(page_shape[-1])
     if num_heads % kv_heads:
         return f"num_heads {num_heads} not a multiple of kv_heads {kv_heads}"
     if interpret_mode():
         return None
     if d % 128:
         return f"head dim {d} is not a multiple of 128 (Mosaic lane tiling)"
-    if ps % 8:
-        return f"page_size {ps} is not a multiple of 8 (fp32 sublane tiling)"
+    if kv_heads % 8:
+        return f"kv_heads {kv_heads} is not a multiple of 8 (Mosaic sublane tiling)"
     return None
 
 
-def _decode_kernel(
-    table_ref,  # SMEM [1, pps] int32: this slot's page-table row
-    length_ref,  # SMEM [1, 1] int32: valid positions already in the pool
-    q_ref,  # VMEM [1, group, D]: the q heads sharing this kv head (pre-scaled)
-    kn_ref,  # VMEM [1, D]: current token's key for this kv head
-    vn_ref,  # VMEM [1, D]: current token's value
-    pool_k_ref,  # ANY (HBM) [P, ps, KV, D]
-    pool_v_ref,  # ANY (HBM) [P, ps, KV, D]
-    o_ref,  # VMEM [1, group, D] out
-    k_scratch,  # VMEM [ps, D] pool dtype
-    v_scratch,  # VMEM [ps, D]
-    sems,  # DMA semaphores (2,)
-    *,
-    page_size: int,
-):
-    g = pl.program_id(0)  # kv head (slot axis joins via vmap batching)
-    length = length_ref[0, 0]
-    q = q_ref[0]  # [group, D]
-    group, d = q.shape
-
-    m = jnp.full((group, 1), M_INIT, jnp.float32)
-    l = jnp.zeros((group, 1), jnp.float32)
-    acc = jnp.zeros((group, d), jnp.float32)
-
-    # pages holding positions 0..length-1 (zero-trip for a fresh/idle lane)
-    npages = jax.lax.div(length + jnp.int32(page_size - 1), jnp.int32(page_size))
-    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (group, page_size), 1)
-
-    def body(j, carry):
-        m, l, acc = carry
-        page = table_ref[0, j]
-        k_dma = pltpu.make_async_copy(pool_k_ref.at[page, :, g, :], k_scratch, sems.at[0])
-        v_dma = pltpu.make_async_copy(pool_v_ref.at[page, :, g, :], v_scratch, sems.at[1])
-        k_dma.start()
-        v_dma.start()
-        k_dma.wait()
-        v_dma.wait()
-        s = jax.lax.dot_general(
-            q, k_scratch[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [group, ps]
-        # mask the partial last page: positions >= length hold stale pool
-        # data (or the unwritten tail) and must underflow exp to exactly 0
-        s = jnp.where(j * page_size + pos_in_page < length, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m - m_new)
-        l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * correction + jax.lax.dot_general(
-            p.astype(v_scratch.dtype), v_scratch[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
-
-    m, l, acc = jax.lax.fori_loop(0, npages, body, (m, l, acc))
-
-    # the current token (position == length) is not in the pool yet — it is
-    # the engine's post-step scatter — so it joins as one final key here
-    kn = kn_ref[:]  # [1, D]
-    vn = vn_ref[:]
-    s_new = jax.lax.dot_general(
-        q, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [group, 1]
-    m_new = jnp.maximum(m, s_new)
+def _fold(carry, q, k, v, valid):
+    """Fold one block of keys into a row's online softmax. ``q`` is
+    ``[KV, D]`` (one query per kv head), ``k``/``v`` are ``[T, KV, D]``,
+    ``valid`` broadcasts against ``[T, KV, 1]``; all fp32."""
+    m, l, acc = carry
+    s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [T, KV, 1]
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=0))
+    p = jnp.exp(s - m_new[None])
     correction = jnp.exp(m - m_new)
-    p_new = jnp.exp(s_new - m_new)
-    l = l * correction + p_new
-    acc = acc * correction + jax.lax.dot_general(
-        p_new.astype(vn.dtype), vn, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    return (
+        m_new,
+        l * correction + jnp.sum(p, axis=0),
+        acc * correction + jnp.sum(p * v, axis=0),
     )
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
-def _reference(q, k_new, v_new, pool_k, pool_v, table, length, scale):
-    """Gather-based fallback with the kernel's exact masking semantics —
-    attends the table-gathered view plus the new token. Only reached for
-    Mosaic-untileable geometries; the engine's ``use_kernels=False`` path is
-    a different (byte-identical-to-PR-7) program and never lands here."""
-    from ..models.attention import dot_product_attention
-
-    taken_k = jnp.take(pool_k, table, axis=0).reshape(-1, *pool_k.shape[2:])
-    taken_v = jnp.take(pool_v, table, axis=0).reshape(-1, *pool_v.shape[2:])
-    keys = jnp.concatenate([taken_k, k_new[0]], axis=0)[None]  # [1, T+1, KV, D]
-    values = jnp.concatenate([taken_v, v_new[0]], axis=0)[None]
-    t = taken_k.shape[0]
-    valid = jnp.concatenate(
-        [jnp.arange(t) < length, jnp.ones((1,), bool)]
-    )[None, None, None, :]
-    return dot_product_attention(q, keys, values, mask=valid, scale=scale)
-
-
-def paged_decode_attention(
-    q: jax.Array,  # [1, 1, NH, D]: one slot's single decode query
-    k_new: jax.Array,  # [1, 1, KV, D]: current token's key (pre-scatter)
-    v_new: jax.Array,  # [1, 1, KV, D]
-    pool_k: jax.Array,  # [P, page_size, KV, D]: one layer of the page pool
-    pool_v: jax.Array,  # [P, page_size, KV, D]
-    table: jax.Array,  # [pps] int32 page-table row
-    length: jax.Array,  # scalar int32: positions already in the pool
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """One decode token's attention over its paged KV — the ``attend`` hook
-    the serving engine threads through the models' decode-cache protocol
-    (``decoder_layer`` / ``GPT2._block``) when ``use_kernels`` is on. The
-    engine's vmap over slots batches the launch, so the compiled program is
-    ONE slot-batched ``pallas_call`` per layer per decode step."""
-    _, _, nh, d = q.shape
-    kv = k_new.shape[2]
-    ps = pool_k.shape[-3]
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    if paged_kernel_fallback_reason(pool_k.shape, nh, kv) is not None:
-        return _reference(q, k_new, v_new, pool_k, pool_v, table, length, scale)
-    # the reference einsum path scales q (in q's dtype) before the score
-    # matmul — mirror it so kernel and reference agree to roundoff
-    qs = (q * jnp.asarray(scale, q.dtype))[0, 0]  # [NH, D]
-    group = nh // kv
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=ps),
-        grid=(kv,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # table [1, pps]
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # length [1, 1]
-            pl.BlockSpec((1, group, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda g: (g, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda g: (g, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, group, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((kv, group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((ps, d), pool_k.dtype),
-            pltpu.VMEM((ps, d), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret_mode(),
-    )(
-        table.reshape(1, -1).astype(jnp.int32),
-        jnp.asarray(length, jnp.int32).reshape(1, 1),
-        qs.reshape(kv, group, d),
-        k_new[0, 0],
-        v_new[0, 0],
-        pool_k,
-        pool_v,
-    )
-    return out.reshape(1, 1, nh, d)
-
-
-def _verify_kernel(
-    table_ref,  # SMEM [1, pps] int32: this slot's page-table row
-    length_ref,  # SMEM [1, 1] int32: committed positions in the pool
-    q_ref,  # VMEM [1, W*group, D]: window queries, row = wi*group + gi (pre-scaled)
-    kn_ref,  # VMEM [1, W, D]: the window's keys for this kv head (pre-scatter)
-    vn_ref,  # VMEM [1, W, D]
+def _paged_kernel(
+    tables_ref,  # SMEM [S, pps] int32 (scalar prefetch): page-table rows
+    lengths_ref,  # SMEM [S] int32 (scalar prefetch): committed positions
+    q_ref,  # VMEM [1, W*group, KV, D]: row wi*group+gi (pre-scaled)
+    kn_ref,  # VMEM [1, W, KV, D]: the window's keys (pre-scatter)
+    vn_ref,  # VMEM [1, W, KV, D]
     pool_k_ref,  # ANY (HBM) [P, ps, KV, D]
     pool_v_ref,  # ANY (HBM) [P, ps, KV, D]
-    o_ref,  # VMEM [1, W*group, D] out
-    k_scratch,  # VMEM [ps, D] pool dtype
-    v_scratch,  # VMEM [ps, D]
+    o_ref,  # VMEM [1, W*group, KV, D] out
+    k_scratch,  # VMEM [ps, KV, D] pool dtype
+    v_scratch,  # VMEM [ps, KV, D]
     sems,  # DMA semaphores (2,)
     *,
     page_size: int,
     window: int,
     group: int,
 ):
-    g = pl.program_id(0)  # kv head (slot axis joins via vmap batching)
-    length = length_ref[0, 0]
-    q = q_ref[0]  # [W*group, D]
-    rows, d = q.shape
+    slot = pl.program_id(0)
+    length = lengths_ref[slot]
+    kv, d = q_ref.shape[-2:]
+    f32 = jnp.float32
+    queries = [q_ref[0, r].astype(f32) for r in range(window * group)]  # [KV, D] each
+    init = (
+        jnp.full((kv, 1), M_INIT, f32),
+        jnp.zeros((kv, 1), f32),
+        jnp.zeros((kv, d), f32),
+    )
 
-    m = jnp.full((rows, 1), M_INIT, jnp.float32)
-    l = jnp.zeros((rows, 1), jnp.float32)
-    acc = jnp.zeros((rows, d), jnp.float32)
-
-    # committed pages (positions 0..length-1): every window row attends all
-    # of them — the page walk is the decode kernel's, with W*group query rows
+    # committed pages (positions 0..length-1; zero-trip for a fresh/idle
+    # lane): every window row attends all of them
     npages = jax.lax.div(length + jnp.int32(page_size - 1), jnp.int32(page_size))
-    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 1)
+    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 0)
 
     def body(j, carry):
-        m, l, acc = carry
-        page = table_ref[0, j]
-        k_dma = pltpu.make_async_copy(pool_k_ref.at[page, :, g, :], k_scratch, sems.at[0])
-        v_dma = pltpu.make_async_copy(pool_v_ref.at[page, :, g, :], v_scratch, sems.at[1])
+        page = tables_ref[slot, j]
+        k_dma = pltpu.make_async_copy(pool_k_ref.at[page], k_scratch, sems.at[0])
+        v_dma = pltpu.make_async_copy(pool_v_ref.at[page], v_scratch, sems.at[1])
         k_dma.start()
         v_dma.start()
         k_dma.wait()
         v_dma.wait()
-        s = jax.lax.dot_general(
-            q, k_scratch[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, ps]
-        s = jnp.where(j * page_size + pos_in_page < length, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m - m_new)
-        l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * correction + jax.lax.dot_general(
-            p.astype(v_scratch.dtype), v_scratch[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+        k = k_scratch[...].astype(f32)
+        v = v_scratch[...].astype(f32)
+        # mask the partial last page: positions >= length hold stale pool
+        # data (or the unwritten tail) and must underflow exp to exactly 0
+        valid = j * page_size + pos_in_page < length
+        return tuple(_fold(c, q, k, v, valid) for c, q in zip(carry, queries))
 
-    m, l, acc = jax.lax.fori_loop(0, npages, body, (m, l, acc))
+    carry = jax.lax.fori_loop(0, npages, body, (init,) * len(queries))
 
     # the candidate window (positions length..length+W-1) is not in the pool
     # yet — the engine's write-back is a separate masked scatter — so it folds
-    # in as one final block with a causal mask INSIDE the window: query row
-    # wi*group+gi (window position wi) may attend window keys 0..wi. Row 0
-    # attends exactly its own key, reducing to the decode kernel at W=1.
-    kn = kn_ref[0]  # [W, D]
-    vn = vn_ref[0]
-    s_w = jax.lax.dot_general(
-        q, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [rows, W]
-    row_pos = jax.lax.broadcasted_iota(jnp.int32, (rows, window), 0) // group
-    key_pos = jax.lax.broadcasted_iota(jnp.int32, (rows, window), 1)
-    s_w = jnp.where(key_pos <= row_pos, s_w, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s_w, axis=-1, keepdims=True))
-    correction = jnp.exp(m - m_new)
-    p = jnp.exp(s_w - m_new)
-    l = l * correction + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc * correction + jax.lax.dot_general(
-        p.astype(vn.dtype), vn, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    # in as one final block with a causal mask INSIDE the window: the row at
+    # window position wi may attend window keys 0..wi
+    kn = kn_ref[0].astype(f32)
+    vn = vn_ref[0].astype(f32)
+    key_pos = jax.lax.broadcasted_iota(jnp.int32, (window, kv, 1), 0)
+    for r, (c, q) in enumerate(zip(carry, queries)):
+        _, l, acc = _fold(c, q, kn, vn, key_pos <= r // group)
+        o_ref[0, r] = (acc / l).astype(o_ref.dtype)
+
+
+def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths):
+    """The slot-batched launch: ``q`` ``[S, W, NH, D]`` (pre-scaled),
+    ``k_new``/``v_new`` ``[S, W, KV, D]``, ``tables`` ``[S, pps]``,
+    ``lengths`` ``[S]`` → ``[S, W, NH, D]``."""
+    s, w, nh, d = q.shape
+    kv = k_new.shape[2]
+    ps = pool_k.shape[-3]
+    group = nh // kv
+    rows = w * group
+    # head h = g*group + gi reads kv head g (the zoo's GQA convention): lay
+    # the queries out as rows of one-query-per-kv-head, row = wi*group + gi
+    q_rows = q.reshape(s, w, kv, group, d).transpose(0, 1, 3, 2, 4).reshape(s, rows, kv, d)
+
+    def per_slot(n):
+        return pl.BlockSpec((1, n, kv, d), lambda i, *_: (i, 0, 0, 0), memory_space=pltpu.VMEM)
+
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, page_size=ps, window=w, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[
+                per_slot(rows),
+                per_slot(w),
+                per_slot(w),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=per_slot(rows),
+            scratch_shapes=[
+                pltpu.VMEM((ps, kv, d), pool_k.dtype),
+                pltpu.VMEM((ps, kv, d), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, rows, kv, d), q.dtype),
+        interpret=interpret_mode(),
+        name="paged_attention",
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q_rows, k_new, v_new, pool_k, pool_v)
+    return out.reshape(s, w, group, kv, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
+
+
+@jax.custom_batching.custom_vmap
+def _paged_one_slot(q, k_new, v_new, table, length, pool_k, pool_v):
+    return _paged_call(
+        q[None], k_new[None], v_new[None], pool_k, pool_v, table[None], length[None]
+    )[0]
+
+
+@_paged_one_slot.def_vmap
+def _paged_slots(axis_size, in_batched, q, k_new, v_new, table, length, pool_k, pool_v):
+    """The engine's slot ``vmap`` lands here: per-slot operands arrive
+    stacked, the pool is shared — one launch with the slot axis as grid."""
+    if any(in_batched[5:]):
+        raise NotImplementedError("paged attention batches slots over ONE shared page pool")
+    q, k_new, v_new, table, length = (
+        x if batched else jnp.broadcast_to(x, (axis_size, *x.shape))
+        for x, batched in zip((q, k_new, v_new, table, length), in_batched)
     )
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    return _paged_call(q, k_new, v_new, pool_k, pool_v, table, length), True
 
 
-def _verify_reference(q, k_new, v_new, pool_k, pool_v, table, length, scale):
-    """Gather-based fallback with the verify kernel's exact masking
-    semantics: the table-gathered view (positions < length valid) plus the
-    candidate window under a lower-triangular in-window mask."""
+def _reference(q, k_new, v_new, pool_k, pool_v, table, length, scale):
+    """Gather-based oracle with the kernel's exact masking semantics: the
+    table-gathered view (positions < length valid) plus the candidate window
+    under a lower-triangular in-window mask. Tests compare the kernel against
+    it; the engine's ``use_kernels=False`` path is a different
+    (byte-identical-to-PR-7) program and never lands here."""
     from ..models.attention import dot_product_attention
 
     taken_k = jnp.take(pool_k, table, axis=0).reshape(-1, *pool_k.shape[2:])
@@ -325,53 +248,25 @@ def paged_verify_attention(
     length: jax.Array,  # scalar int32: committed positions in the pool
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Speculative-decoding verify: score a W=k+1 candidate window against a
-    slot's paged KV in ONE launch — the decode kernel with a window axis.
-    Each (slot, kv-head) program walks the committed pages exactly as
-    :func:`paged_decode_attention` does, then folds the window's own keys in
-    under a causal in-window mask. The serving engine threads this as the
-    ``attend`` hook of the window protocol
-    (:func:`~..models.generation.forward_window_with_cache`); its vmap over
-    slots batches the launch."""
-    _, w, nh, d = q.shape
-    kv = k_new.shape[2]
-    ps = pool_k.shape[-3]
+    """One slot's attention over its paged KV plus a W-token candidate
+    window — the ``attend`` hook the serving engine threads through the
+    models' decode-cache protocol (``decoder_layer`` / ``GPT2._block``) and
+    window protocol (:func:`~..models.generation.forward_window_with_cache`)
+    when ``use_kernels`` is on. W=1 is plain decode; W=k+1 scores a
+    speculative window in one launch. The caller (the engine) has already
+    checked :func:`paged_kernel_fallback_reason`."""
+    d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    if paged_kernel_fallback_reason(pool_k.shape, nh, kv) is not None:
-        return _verify_reference(q, k_new, v_new, pool_k, pool_v, table, length, scale)
-    qs = (q * jnp.asarray(scale, q.dtype))[0]  # [W, NH, D]
-    group = nh // kv
-    # row layout (kv, W*group): row wi*group+gi is window position wi of the
-    # gi-th query head sharing kv head g — head h = g*group+gi, as in decode
-    qs = qs.reshape(w, kv, group, d).transpose(1, 0, 2, 3).reshape(kv, w * group, d)
-    out = pl.pallas_call(
-        functools.partial(_verify_kernel, page_size=ps, window=w, group=group),
-        grid=(kv,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # table [1, pps]
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # length [1, 1]
-            pl.BlockSpec((1, w * group, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, w, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, w, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, w * group, d), lambda g: (g, 0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((kv, w * group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((ps, d), pool_k.dtype),
-            pltpu.VMEM((ps, d), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret_mode(),
-    )(
-        table.reshape(1, -1).astype(jnp.int32),
-        jnp.asarray(length, jnp.int32).reshape(1, 1),
-        qs,
-        jnp.moveaxis(k_new[0], 1, 0),  # (KV, W, D)
-        jnp.moveaxis(v_new[0], 1, 0),
-        pool_k,
-        pool_v,
+    # the reference einsum path scales q (in q's dtype) before the score
+    # matmul — mirror it so kernel and reference agree to roundoff
+    qs = q * jnp.asarray(scale, q.dtype)
+    out = _paged_one_slot(
+        qs[0], k_new[0], v_new[0], table.astype(jnp.int32), jnp.asarray(length, jnp.int32),
+        pool_k, pool_v,
     )
-    return out.reshape(kv, w, group, d).transpose(1, 0, 2, 3).reshape(1, w, nh, d)
+    return out[None]
+
+
+# plain decode is the one-token window
+paged_decode_attention = paged_verify_attention

@@ -22,9 +22,11 @@ Grid: ``(N/bn, K/bk)`` with the K axis innermost — each program owns one
 output-column block, accumulating K-block partial products into a VMEM fp32
 scratch that flushes to the output on the last K step (revisiting an output
 block on consecutive grid steps is legal on TPU: the grid is sequential).
-Off-TPU the kernel runs in interpret mode; Mosaic-untileable geometries
-(lane/sublane-unaligned K or N) fall back to dequantize-then-matmul — per
-call, not per layer, so even the fallback never keeps a resident shadow.
+Off-TPU the kernel runs in interpret mode; what Mosaic cannot lower
+(lane/sublane-unaligned K or N, and every int4 weight — see
+:func:`quant_fallback_reason`) falls back to dequantize-then-matmul and says
+so once — per call, not per layer, so even the fallback never keeps a
+resident shadow.
 """
 
 from __future__ import annotations
@@ -57,6 +59,10 @@ def quant_fallback_reason(k: int, n: int, bits: int) -> Optional[str]:
         return f"K={k}, N={n} not tileable by power-of-two blocks"
     if interpret_mode():
         return None
+    if bits == 4:
+        # v5e, jax 0.9.0: "failed to legalize operation 'arith.shli'" on the
+        # vector<..xi8> shifts of the in-kernel nibble unpack
+        return "int4 nibble unpack does not lower through Mosaic (int8 vector shifts)"
     if bk % 32 or bn % 128:
         return (
             f"fitted blocks ({bk}, {bn}) miss Mosaic's int8 tiling "
@@ -88,6 +94,51 @@ def _matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits, k_blocks):
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
+def _fused_2d(x2: jax.Array, q: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
+    """The kernel launch: ``x2`` ``[M, K]`` against packed ``q`` → ``[M, N]``."""
+    m, k = x2.shape
+    n = q.shape[-1]
+    bk = _fit(BLOCK_K, k, 2 if bits == 4 else 1)
+    bn = _fit(BLOCK_N, n, 1)
+    # int4 packs two K rows per stored byte: the stored block is bk // 2 rows
+    wk_block = bk // 2 if bits == 4 else bk
+    return pl.pallas_call(
+        functools.partial(_matmul_kernel, bits=bits, k_blocks=k // bk),
+        grid=(n // bn, k // bk),
+        in_specs=[
+            pl.BlockSpec((m, bk), lambda ni, ki: (0, ki), memory_space=pltpu.VMEM),
+            pl.BlockSpec((wk_block, bn), lambda ni, ki: (ki, ni), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni), memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((m, bn), lambda ni, ki: (0, ni), memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((m, n), x2.dtype),
+        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
+        interpret=interpret_mode(),
+        name="quant_matmul",
+    )(x2, q, scale.reshape(1, n))
+
+
+@functools.lru_cache(maxsize=None)
+def _fused(bits: int):
+    """``_fused_2d`` with a matmul's own batching rule: rows batched over a
+    SHARED weight fold into M (the serving engine's slot ``vmap`` becomes one
+    launch that reads the weight once, not one grid sweep per slot)."""
+
+    @jax.custom_batching.custom_vmap
+    def call(x2, q, scale):
+        return _fused_2d(x2, q, scale, bits)
+
+    @call.def_vmap
+    def _(axis_size, in_batched, x2, q, scale):
+        if in_batched[1] or in_batched[2]:
+            raise NotImplementedError("quant_matmul batches rows over ONE shared weight")
+        m = x2.shape[1]
+        out = call(x2.reshape(axis_size * m, x2.shape[2]), q, scale)
+        return out.reshape(axis_size, m, out.shape[-1]), True
+
+    return call
+
+
 def quant_matmul(x: jax.Array, w: QuantizedWeight) -> jax.Array:
     """``x @ dequantize(w)`` without ever materializing the dequantized
     weight: ``x`` is ``[..., K]``, ``w`` a packed int8/int4
@@ -99,30 +150,16 @@ def quant_matmul(x: jax.Array, w: QuantizedWeight) -> jax.Array:
         kq *= 2
     if kq != k:
         raise ValueError(f"contraction mismatch: x[..., {k}] @ quantized [{kq}, {n}]")
-    if quant_fallback_reason(k, n, w.bits) is not None:
+    reason = quant_fallback_reason(k, n, w.bits)
+    if reason is not None:
+        from ..logging import get_logger
+
+        get_logger(__name__).warning_once(
+            f"quant_matmul: int{w.bits} [{k}, {n}] runs dequantize-then-matmul, "
+            f"not the fused kernel: {reason}"
+        )
         return x @ w.dequantize().astype(x.dtype)
-    bk = _fit(BLOCK_K, k, 2 if w.bits == 4 else 1)
-    bn = _fit(BLOCK_N, n, 1)
-    m = 1
-    for dim in lead:
-        m *= dim
-    x2 = x.reshape(m, k)
-    # int4 packs two K rows per stored byte: the stored block is bk // 2 rows
-    wk_block = bk // 2 if w.bits == 4 else bk
-    out = pl.pallas_call(
-        functools.partial(_matmul_kernel, bits=w.bits, k_blocks=k // bk),
-        grid=(n // bn, k // bk),
-        in_specs=[
-            pl.BlockSpec((m, bk), lambda ni, ki: (0, ki), memory_space=pltpu.VMEM),
-            pl.BlockSpec((wk_block, bn), lambda ni, ki: (ki, ni), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bn), lambda ni, ki: (0, ni), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((m, bn), lambda ni, ki: (0, ni), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        interpret=interpret_mode(),
-    )(x2, w.q, w.scale.reshape(1, n))
-    return out.reshape(*lead, n)
+    return _fused(w.bits)(x.reshape(-1, k), w.q, w.scale).reshape(*lead, n)
 
 
 def quant_dot(a: jax.Array, w) -> jax.Array:

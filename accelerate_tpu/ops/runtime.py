@@ -11,9 +11,8 @@ env override for the two debugging directions:
   through kernel logic with python-level semantics when chasing a Mosaic
   miscompile or a numerics drift;
 - ``ACCELERATE_PALLAS_INTERPRET=0`` forces Mosaic lowering everywhere —
-  the assert-compiled mode a TPU bench round runs under, so a kernel that
-  silently fell back to the interpreter (and its ~100x slowdown) fails
-  loudly instead of polluting the recorded numbers.
+  the assert-compiled mode, and how a Mosaic compile is rehearsed ahead of
+  time on a machine without the chip (.claude/skills/verify/SKILL.md).
 
 Unset, the policy is the historical one from ``ops/flash_attention.py``:
 interpret everywhere except a real TPU backend.
@@ -74,8 +73,10 @@ def sds(shape, dtype, like) -> jax.ShapeDtypeStruct:
 
 def kernels_default() -> bool:
     """Default for ``use_kernels``-style knobs when the caller passes None:
-    on for real TPU backends (the kernels are the fast path there), off for
-    CPU/GPU meshes (the reference paths are byte-identical to what every
-    pre-kernel program ran, and interpret-mode kernels are slower than the
-    XLA reference on a host CPU). Tests and benches opt in explicitly."""
+    on for real TPU backends (every kernel compiles under Mosaic and runs on
+    a v5e — ``chip_smoke.py`` proves it on each PR; which path is faster is
+    not measured yet), off for CPU/GPU meshes (the reference paths are
+    byte-identical to what every pre-kernel program ran, and interpret-mode
+    kernels are slower than the XLA reference on a host CPU). Tests opt in
+    explicitly."""
     return jax.default_backend() == "tpu"

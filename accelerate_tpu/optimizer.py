@@ -136,7 +136,7 @@ class AcceleratedOptimizer:
             # (memory-kind annotations inside jit trip XLA's SPMD partitioner).
             # Scalars (step counters) stay in device memory — pinning them
             # saves nothing. Backends without a "pinned_host" memory space
-            # (CPU on older jax — where "device" memory already IS host RAM)
+            # (where "device" memory already IS host RAM)
             # skip the annotation: offload degrades to a placement no-op.
             try:
                 kinds = {m.kind for m in mesh.devices.flat[0].addressable_memories()}

@@ -48,9 +48,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import HAS_PCAST, shard_map
 
 from ..utils.constants import MESH_AXIS_PIPELINE, MESH_AXIS_SEQUENCE
 
@@ -272,11 +271,6 @@ def make_pipeline_layers_fn(
                 rng_base = jax.random.wrap_key_data(rest.pop())
 
             def to_varying(x):
-                if not HAS_PCAST:
-                    # pre-vma jax: no replication typing in manual regions —
-                    # values are already varying, shard_map transposes handle
-                    # the grad psum (see compat.HAS_PCAST)
-                    return x
                 have = set(getattr(x.aval, "vma", ()) or ())
                 missing = tuple(manual_axes - have)
                 return jax.lax.pcast(x, missing, to="varying") if missing else x

@@ -31,7 +31,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention_block

@@ -338,6 +338,13 @@ class ServingEngine:
                         self.cache.k.shape[1:], nh, kv
                     )
             self._use_decode_kernel = self._kernel_fallback_reason is None
+            if not self._use_decode_kernel:
+                from ..logging import get_logger
+
+                get_logger(__name__).warning(
+                    "paged decode kernel not engaged — decoding through the "
+                    f"gather program: {self._kernel_fallback_reason}"
+                )
         self._kernels_reported = False  # one {"kind": "kernels"} record per engine
         self.scheduler = ContinuousBatchingScheduler(num_slots, max_queue=max_queue)
         self._pending = np.zeros((num_slots,), np.int32)  # next input token per slot

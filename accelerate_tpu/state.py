@@ -28,6 +28,7 @@ import jax
 
 from .analysis.concurrency import named_lock
 from .logging import get_logger
+from .utils.compile_cache import enable_compile_cache
 from .utils.constants import CANONICAL_MESH_AXES, MESH_AXIS_DATA
 from .utils.dataclasses import (
     DistributedType,
@@ -53,13 +54,8 @@ def _init_timeout_kwargs() -> dict[str, int]:
 
 
 def distributed_is_initialized() -> bool:
-    """Whether the jax.distributed rendezvous already ran (version-portable:
-    ``jax.distributed.is_initialized`` only exists on newer jax)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src import distributed as _distributed
-
-    return _distributed.global_state.client is not None
+    """Whether the jax.distributed rendezvous already ran."""
+    return jax.distributed.is_initialized()
 
 
 class PartialState:
@@ -87,6 +83,7 @@ class PartialState:
                         "call PartialState._reset_state() first (tests) or construct it once."
                     )
                 return
+            enable_compile_cache()
             self._bootstrap_distributed(**kwargs)
             self.debug = parse_flag_from_env("ACCELERATE_DEBUG_MODE")
             self.parallelism = parallelism or ParallelismConfig.from_env()
@@ -127,13 +124,13 @@ class PartialState:
         devices = jax.devices()
         axis_sizes = self.parallelism.axis_sizes(len(devices))
         shape = tuple(axis_sizes[a] for a in CANONICAL_MESH_AXES)
-        # mesh_utils lays devices out to keep inner axes on the fastest ICI links.
-        try:
-            from jax.experimental import mesh_utils
+        # mesh_utils lays TPU devices out to keep inner axes on the fastest
+        # ICI links (a plain reshape on other platforms). A shape it cannot
+        # place on the physical topology raises: a silently reshaped mesh
+        # would train correctly over the slowest links.
+        from jax.experimental import mesh_utils
 
-            device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:  # CPU meshes / odd shapes: plain reshape is fine
-            device_array = np.asarray(devices).reshape(shape)
+        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
         self.mesh = jax.sharding.Mesh(device_array, CANONICAL_MESH_AXES)
 
     def rebuild_mesh(
@@ -180,9 +177,9 @@ class PartialState:
         tears down and re-initializes ``jax.distributed`` over the new
         member set at the same step boundary (the membership epoch is the
         agreement on WHO). That call is env-gated behind
-        ``ACCELERATE_ELASTIC_REAL_REJOIN=1`` because on 0.4.37-era runtimes
-        a shutdown+initialize cycle is only supported on real TPU backends
-        — the CPU simulation must never attempt it — and it carries a
+        ``ACCELERATE_ELASTIC_REAL_REJOIN=1`` because a shutdown+initialize
+        cycle is only supported on real TPU backends — the CPU simulation
+        must never attempt it — and it carries a
         CONTRACT: the launcher/supervisor must refresh the coordinate env
         vars (``get_multihost_env``: coordinator address, num_processes,
         process_id) to the SURVIVOR set before the boundary, because the

@@ -46,12 +46,9 @@ def _install_dispatcher() -> None:
     with _install_lock:
         if _dispatcher_installed:
             return
-        try:
-            import jax.monitoring
+        import jax.monitoring
 
-            jax.monitoring.register_event_duration_secs_listener(_dispatch_duration)
-        except (ImportError, AttributeError):
-            pass  # older jax: jit-cache events still flow
+        jax.monitoring.register_event_duration_secs_listener(_dispatch_duration)
         from ..utils import jit_cache
 
         jit_cache.cache_event_hook = _dispatch_cache_event

@@ -77,7 +77,7 @@ class ProfileWindow:
             try:
                 jax.profiler.start_server(self.port)
                 self._server_started = True
-            except Exception as e:  # port in use, older jax
+            except Exception as e:  # port in use: the trace still works
                 logger.warning(f"Could not start profiler server on port {self.port}: {e}")
         path = self.trace_dir()
         os.makedirs(path, exist_ok=True)

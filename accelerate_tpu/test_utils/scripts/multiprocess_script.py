@@ -19,8 +19,7 @@ import numpy as np
 
 
 def main():
-    # CI harness: force the CPU backend through jax.config — environments
-    # with a site-installed TPU platform ignore the JAX_PLATFORMS env var
+    # CI harness: force the CPU backend with N virtual host devices
     force_cpu = os.environ.get("ACCELERATE_TEST_FORCE_CPU_DEVICES")
     if force_cpu:
         import jax
@@ -30,10 +29,7 @@ def main():
             + f" --xla_force_host_platform_device_count={int(force_cpu)}"
         ).strip()
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", int(force_cpu))
-        except AttributeError:
-            pass  # older jax: XLA_FLAGS above forces the host device count
+        jax.config.update("jax_num_cpu_devices", int(force_cpu))
 
     import optax
 

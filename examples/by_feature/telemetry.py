@@ -54,10 +54,9 @@ def build(args):
         model.config,
         batch_size=args.batch_size,
         seq_len=64,
-        # CPU has no meaningful hardware peak; a nominal 1 TFLOP/s keeps the
-        # MFU field populated for the demo (on TPU, omit this — the real
-        # chip peak is looked up automatically)
-        peak_flops_per_device=None if accelerator.device.platform == "tpu" else 1e12,
+        # the chip's peak is looked up from its device_kind; a CPU has no
+        # meaningful peak, so a CPU run reports no MFU
+        peak_flops_per_device=None,
     )
     manager = accelerator.checkpoint_manager(
         os.path.join(args.project_dir, "checkpoints"), handle_signals=()
@@ -112,11 +111,12 @@ def main(argv=None):
     sink = os.path.join(args.project_dir, "telemetry.jsonl")
     record = [json.loads(line) for line in open(sink)][-1]
     metrics = record["metrics"]
+    mfu = f"{metrics['mfu']:.4f}" if "mfu" in metrics else "not measured"
     accelerator.print(
         "telemetry: "
         f"p50 {metrics.get('step_time_p50_ms', float('nan')):.2f} ms/step, "
         f"{metrics.get('tokens_per_sec', 0):.0f} tokens/sec, "
-        f"MFU {metrics.get('mfu', 0):.4f}, "
+        f"MFU {mfu}, "
         f"{metrics['compile_count']} compiles ({metrics['compile_seconds']:.1f}s), "
         f"goodput {metrics['goodput']:.3f} after {record['goodput']['restarts']} restart"
     )

@@ -6,10 +6,11 @@ user keeps the loop, ``Accelerator`` makes it run unchanged on one chip, a
 TPU slice, or a virtual CPU mesh — sharding, precision, and collectives all
 come from ``prepare()`` + ``backward()`` + ``gather_for_metrics()``.
 
-Run (single chip or real slice):
-    python examples/nlp_example.py --mixed_precision bf16
-Run on the 8-device virtual CPU mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+Run from the repo root (``PYTHONPATH=.`` stands in for an install). On the
+machine with the chip, alone — one process uses the chip:
+    PYTHONPATH=. python examples/nlp_example.py --mixed_precision bf16
+On the 8-device virtual CPU mesh:
+    PYTHONPATH=. XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/nlp_example.py --num_epochs 2
 """
 

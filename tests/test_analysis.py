@@ -195,9 +195,7 @@ def test_sanitizer_catches_cache_miss_with_key():
 
 
 def test_fp64_leak_detection():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         lowered = jax.jit(lambda a: a * 2.0).lower(jnp.ones((4,), jnp.float64))
         report = audit_lowered(lowered, compile=False, label="x64", expect_donation=False)
         assert [f.code for f in report.errors] == ["FP64_LEAK"]
