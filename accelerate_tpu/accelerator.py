@@ -48,7 +48,7 @@ from .resilience.guards import next_guard_state, zero_guard_state
 from .scheduler import AcceleratedScheduler
 from .state import AcceleratorState, GradientState, PartialState
 from .state import distributed_is_initialized as _distributed_is_initialized
-from .telemetry import Telemetry, TelemetryConfig
+from .telemetry import Telemetry, TelemetryConfig, profiler
 from .utils.dataclasses import (
     CompilationConfig,
     FP8RecipeKwargs,
@@ -1021,6 +1021,7 @@ class Accelerator:
                 _json.dump(meta, f, indent=2, default=str)
         except OSError:
             pass  # metadata is best-effort; the trace is the payload
+        profiler.clear()  # this session's step spans only
         jax.profiler.start_trace(log_dir)
         # the guard flips only once the trace is live: a failed start_trace
         # must not leave the accelerator permanently "profiling"
@@ -1357,6 +1358,13 @@ class Accelerator:
             return jitted.lower(model.params, opt_state_in, batch, scale_in, growth_in)
 
         def step(batch):
+            # three step spans while a profiler session is on (telemetry/
+            # profiler.py); with none, one tracing() call and the shared no-op
+            mark = profiler.span if profiler.tracing() else profiler.no_span
+            with mark("train.step", step=optimizer._step_count):
+                return run_step(batch, mark)
+
+        def run_step(batch, mark):
             # no scaler → scale stays a STATIC None (empty pytree through jit):
             # every scaling op is elided at trace time instead of shipping a
             # runtime 1.0 the compiler cannot fold
@@ -1378,31 +1386,34 @@ class Accelerator:
                 if guard is not None and guard.state is None:
                     guard.arm(model, optimizer)
                 gstate_in = guard.state if guard is not None else zero_guard_state()
-                params, opt_state, loss, scale, growth, skipped, gstate_out = jitted(
-                    model.params, opt_state_in, batch, scale, growth, gstate_in, corrupt
-                )
+                with mark("train.dispatch"):
+                    params, opt_state, loss, scale, growth, skipped, gstate_out = jitted(
+                        model.params, opt_state_in, batch, scale, growth, gstate_in, corrupt
+                    )
                 if guard is not None:
                     guard.state = gstate_out
             else:
-                params, opt_state, loss, scale, growth, skipped = jitted(
-                    model.params, opt_state_in, batch, scale, growth
-                )
-            model.params = params
-            optimizer.opt_state = opt_state
-            if optimizer.cpu_offload:
-                optimizer.opt_state = jax.device_put(opt_state, optimizer._opt_state_shardings)
-            if scaler_cfg is not None:
-                optimizer.scale, optimizer.growth_tracker = scale, growth
-            # lazy device scalar; step_was_skipped converts — so the scheduler
-            # sees overflow-skipped steps exactly as on the eager path
-            optimizer._skipped = skipped
-            optimizer._step_count += 1
-            if optimizer.telemetry is not None:
-                optimizer.telemetry._on_optimizer_step()
-            if guard is not None:
-                # fence-cadence host check: snapshot refresh / LKG restore.
-                # Off the cadence this is two integer ops — no host sync.
-                guard.after_step(model, optimizer)
+                with mark("train.dispatch"):
+                    params, opt_state, loss, scale, growth, skipped = jitted(
+                        model.params, opt_state_in, batch, scale, growth
+                    )
+            with mark("train.host"):
+                model.params = params
+                optimizer.opt_state = opt_state
+                if optimizer.cpu_offload:
+                    optimizer.opt_state = jax.device_put(opt_state, optimizer._opt_state_shardings)
+                if scaler_cfg is not None:
+                    optimizer.scale, optimizer.growth_tracker = scale, growth
+                # lazy device scalar; step_was_skipped converts — so the scheduler
+                # sees overflow-skipped steps exactly as on the eager path
+                optimizer._skipped = skipped
+                optimizer._step_count += 1
+                if optimizer.telemetry is not None:
+                    optimizer.telemetry._on_optimizer_step()
+                if guard is not None:
+                    # fence-cadence host check: snapshot refresh / LKG restore.
+                    # Off the cadence this is two integer ops — no host sync.
+                    guard.after_step(model, optimizer)
             return loss
 
         # analysis seam: the returned step carries its program (analysis/

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 
 from ..models.generation import make_sampler, resolve_decode_protocol
 from ..ops.runtime import kernels_default
+from ..telemetry import profiler
 from ..telemetry.serving import ServingStats
 from ..utils.jit_cache import dot_keyed_jit
 from .kv_cache import SlotKVCache, bucket_for, prefill_buckets
@@ -975,6 +976,18 @@ class ServingEngine:
         parked pages to a decode-pool replica via ``adopt_kv`` and acks with
         ``release_parked``. Paged engines only: the dense slab has no
         page-granular layout to relay."""
+        with profiler.span("engine.submit") as live:
+            request = self._enqueue(
+                prompt, max_new_tokens, request_id, submitted_at, deadline_s, prefill_only
+            )
+            if live is not None:
+                live.set_metadata(request=request.id, prompt_tokens=int(request.prompt.size))
+        return request.id
+
+    def _enqueue(
+        self, prompt, max_new_tokens, request_id, submitted_at, deadline_s, prefill_only
+    ) -> Request:
+        """``submit``'s body: validate, shed or queue; the queued request."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt must hold at least one token")
@@ -1067,7 +1080,7 @@ class ServingEngine:
                 replica=self.name,
             )
         self.stats.record_submit()
-        return request.id
+        return request
 
     def cancel(self, request_id: int) -> bool:
         """Client cancellation. Queued or active, the request is retired (and
@@ -1268,7 +1281,7 @@ class ServingEngine:
             return True
         return position + bucket_for(remaining, self.buckets) <= self.cache.view_len
 
-    def _admit(self, slot: int, request: Request) -> None:
+    def _admit(self, slot: int, request: Request, mark) -> None:
         if self.paged:
             # prefill runs in _advance_prefills (chunked: one span per step;
             # monolithic: the whole suffix this same step) — admission only
@@ -1285,23 +1298,27 @@ class ServingEngine:
         if prefill_len > 0:
             bucket = bucket_for(prefill_len, self.buckets)
             request.prefill_bucket = bucket
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :prefill_len] = request.prompt[:-1]
-            if self.tracer is not None:
-                # closed at this step's decode fence, the first host stamp
-                # sequenced after the dispatched prefill's device work
-                self.tracer.span_start(
-                    request.id, "prefill", replica=self.name,
-                    tokens=prefill_len, bucket=bucket,
+            with mark(
+                "engine.prefill_dispatch", request=request.id, span=bucket,
+                tokens=prefill_len, position=0,
+            ):
+                ids = np.zeros((1, bucket), np.int32)
+                ids[0, :prefill_len] = request.prompt[:-1]
+                if self.tracer is not None:
+                    # closed at this step's decode fence, the first host stamp
+                    # sequenced after the dispatched prefill's device work
+                    self.tracer.span_start(
+                        request.id, "prefill", replica=self.name,
+                        tokens=prefill_len, bucket=bucket,
+                    )
+                    self._prefill_open.add(request.id)
+                slot_k, slot_v = self._prefill_program(bucket)(
+                    self.params, ids, self._prefill_cache(bucket)
                 )
-                self._prefill_open.add(request.id)
-            slot_k, slot_v = self._prefill_program(bucket)(
-                self.params, ids, self._prefill_cache(bucket)
-            )
-            self.cache.k, self.cache.v = self._insert_program(bucket)(
-                self.cache.k, self.cache.v, slot_k, slot_v, np.int32(slot)
-            )
-            self.stats.record_prefill(bucket)
+                self.cache.k, self.cache.v = self._insert_program(bucket)(
+                    self.cache.k, self.cache.v, slot_k, slot_v, np.int32(slot)
+                )
+            self.stats.record_prefill(bucket, prefill_len)
         # the prompt's last token is the first decode input: its logits ARE
         # the request's first token, so prefill logits are never consumed
         self._pending[slot] = request.prompt[-1]
@@ -1310,14 +1327,16 @@ class ServingEngine:
 
     # -- paged prefill / page-pressure machinery ----------------------------
 
-    def _advance_prefills(self) -> list[ServingResult]:
+    def _advance_prefills(self, mark) -> tuple[list[ServingResult], int]:
         """Run ONE prefill span per still-prefilling slot (chunked prefill:
         long prompts spread over the step cadence, so already-admitted
         requests keep decoding every step instead of stalling behind a
         monolithic prefill; without ``prefill_chunk`` the single span
         completes immediately). Returns requests failed by page pressure plus
-        the ``"prefilled"`` results of parked prefill-only requests."""
+        the ``"prefilled"`` results of parked prefill-only requests, and the
+        number of prefill programs dispatched."""
         failed: list[ServingResult] = []
+        programs = 0
         for slot in list(self.scheduler.active_slots):
             request = self.scheduler.slots[slot]
             if request is None or self.cache.active[slot]:
@@ -1346,34 +1365,39 @@ class ServingEngine:
                 if status == "yielded":
                     continue  # requeued at the head; elders decode this step
             take = min(span, remaining)
-            ids = np.zeros((1, span), np.int32)
-            ids[0, :take] = request.prompt[request.prefilled : request.prefilled + take]
-            # a span is a CHUNK only when the request's prefill is actually
-            # split: more remains after it, or it continues earlier spans —
-            # a single-span (monolithic or fallback) prefill is not chunked
-            # activity, and counting it (or warmup's synthetic schedules)
-            # would overstate how much chunking ran
-            chunked_span = not self._warming and (
-                take < remaining or request.prefilled > request.prefix_hit
-            )
-            if self.tracer is not None:
-                # one span per chunk (prefill[i]): opened at dispatch, closed
-                # at the first decode fence sequenced after it
-                self.tracer.span_start(
-                    request.id, "prefill", replica=self.name,
-                    tokens=take, span=span, position=request.prefilled,
+            programs += 1
+            with mark(
+                "engine.prefill_dispatch", request=request.id, span=span,
+                tokens=take, position=request.prefilled,
+            ):
+                ids = np.zeros((1, span), np.int32)
+                ids[0, :take] = request.prompt[request.prefilled : request.prefilled + take]
+                # a span is a CHUNK only when the request's prefill is actually
+                # split: more remains after it, or it continues earlier spans —
+                # a single-span (monolithic or fallback) prefill is not chunked
+                # activity, and counting it (or warmup's synthetic schedules)
+                # would overstate how much chunking ran
+                chunked_span = not self._warming and (
+                    take < remaining or request.prefilled > request.prefix_hit
                 )
-                self._prefill_open.add(request.id)
-            # the table ROW is copied at dispatch: jax's CPU H2D is zero-copy,
-            # so handing the program a live view of `tables` races host-side
-            # mutation (park/retire zero the row right after this dispatch,
-            # with no same-step decode fence in between) against XLA's read —
-            # the prefill would scatter into the null page and silently lose
-            # the request's KV
-            self.cache.k, self.cache.v = self._paged_prefill_program(span)(
-                self.params, ids, self.cache.k, self.cache.v,
-                self.cache.tables[slot].copy(), np.int32(request.prefilled),
-            )
+                if self.tracer is not None:
+                    # one span per chunk (prefill[i]): opened at dispatch, closed
+                    # at the first decode fence sequenced after it
+                    self.tracer.span_start(
+                        request.id, "prefill", replica=self.name,
+                        tokens=take, span=span, position=request.prefilled,
+                    )
+                    self._prefill_open.add(request.id)
+                # the table ROW is copied at dispatch: jax's CPU H2D is zero-copy,
+                # so handing the program a live view of `tables` races host-side
+                # mutation (park/retire zero the row right after this dispatch,
+                # with no same-step decode fence in between) against XLA's read —
+                # the prefill would scatter into the null page and silently lose
+                # the request's KV
+                self.cache.k, self.cache.v = self._paged_prefill_program(span)(
+                    self.params, ids, self.cache.k, self.cache.v,
+                    self.cache.tables[slot].copy(), np.int32(request.prefilled),
+                )
             if self.spec is not None and self.spec.enabled:
                 # mirror the span into the draft pool (same ids, same row,
                 # same start) so the slot can draft the moment it decodes —
@@ -1385,14 +1409,14 @@ class ServingEngine:
                 if int(self.spec.draft_len[slot]) == request.prefilled:
                     self.spec.draft_len[slot] = request.prefilled + take
             request.prefilled += take
-            self.stats.record_prefill(span)
+            self.stats.record_prefill(span, take)
             if chunked_span:
                 self.stats.record_prefill_chunk()
             if request.prefilled >= prefill_len:
                 parked = self._finish_prefill(slot, request)
                 if parked is not None:
                     failed.append(parked)
-        return failed
+        return failed, programs
 
     def _finish_prefill(self, slot: int, request: Request) -> Optional[ServingResult]:
         """Every prompt token is in cache pages: register the aligned prefix
@@ -2020,37 +2044,81 @@ class ServingEngine:
         finite-logits probe of any quarantined slot, which rides the same
         fixed-shape program), quarantine slots that produced non-finite
         logits, retire finished requests. Returns the requests that finished
-        THIS step (including expired/cancelled ones, with their reason)."""
+        THIS step (including expired/cancelled ones, with their reason).
+
+        The step runs in phases — admit, prefill, prepare_writes,
+        decode_dispatch, fetch, deliver (telemetry/serving.py ``PHASES``) —
+        and ``stats.phase_seconds`` adds up each from ``perf_counter`` stamps
+        at their boundaries, always. While a profiler session is on, each
+        phase is also an ``engine.<phase>`` step span under one
+        ``engine.step`` root (telemetry/profiler.py); with none, the step
+        asks ``tracing()`` once and ``mark`` is the shared no-op."""
+        mark = profiler.span if profiler.tracing() else profiler.no_span
+        with mark("engine.step") as root:
+            return self._step(mark, root)
+
+    def _step(self, mark, root) -> list[ServingResult]:
         t0 = time.perf_counter()
-        self._report_kernels()
-        finished: list[ServingResult] = self._retire_degraded(t0)
-        self._inject_chaos_burst()
-        for slot, request in self.scheduler.admit_ready(self._free_slot):
-            if self.tracer is not None:
-                self.tracer.span_end(
-                    request.id, "queued", stamp=request.admitted_at, stats=self.stats
-                )
-                self.tracer.event(
-                    request.id, "admitted", stamp=request.admitted_at,
-                    replica=self.name, slot=slot, prefix_hit=request.prefix_hit,
-                )
-            self._admit(slot, request)
+        number = self._steps
+        phases: dict[str, float] = {}
+        if root is not None:
+            root.set_metadata(
+                step=number, active=len(self.scheduler.active_slots),
+                waiting=self.scheduler.waiting,
+            )
+        with mark("engine.admit") as live:
+            self._report_kernels()
+            finished: list[ServingResult] = self._retire_degraded(t0)
+            self._inject_chaos_burst()
+            admitted, longest_wait = 0, 0.0
+            for slot, request in self.scheduler.admit_ready(self._free_slot):
+                if self.tracer is not None:
+                    self.tracer.span_end(
+                        request.id, "queued", stamp=request.admitted_at, stats=self.stats
+                    )
+                    self.tracer.event(
+                        request.id, "admitted", stamp=request.admitted_at,
+                        replica=self.name, slot=slot, prefix_hit=request.prefix_hit,
+                    )
+                self._admit(slot, request, mark)
+                if not self._warming:  # warm-up's synthetic requests wait through compiles
+                    wait = request.admitted_at - request.submitted_at
+                    self.stats.record_admission(wait)
+                    admitted += 1
+                    longest_wait = max(longest_wait, wait)
+            if live is not None:
+                live.set_metadata(admitted=admitted, queue_wait_ms_max=longest_wait * 1e3)
+        stamp = time.perf_counter()
+        phases["admit"] = stamp - t0
         if self.paged:
             # one prefill span per still-prefilling slot (chunked prefill
             # interleaves long prompts into the step cadence), then make
             # every decode write position privately backed (grow / COW)
-            finished.extend(self._advance_prefills())
-            finished.extend(self._prepare_decode_writes())
+            with mark("engine.prefill") as live:
+                failed, programs = self._advance_prefills(mark)
+                finished.extend(failed)
+                if live is not None:
+                    live.set_metadata(programs=programs)
+            before, stamp = stamp, time.perf_counter()
+            phases["prefill"] = stamp - before
+            with mark("engine.prepare_writes"):
+                finished.extend(self._prepare_decode_writes())
+            before, stamp = stamp, time.perf_counter()
+            phases["prepare_writes"] = stamp - before
 
+        # whether any lane decodes this step: a few cheap statements outside
+        # every child span (the root's self time); the decode_dispatch phase's
+        # counter starts at the last stamp, so the phases still add up
         active_idx = self.scheduler.active_slots
         quarantined = sorted(self.cache.quarantined)
-        if not active_idx and not quarantined:
-            return finished
-        if self.paged and not quarantined and not any(
-            self.cache.active[s] for s in active_idx
-        ):
+        if (not active_idx and not quarantined) or (
             # every occupied slot is still prefilling: no lane would decode,
             # so skip the device step — the next step() runs their next chunk
+            self.paged and not quarantined and not any(
+                self.cache.active[s] for s in active_idx
+            )
+        ):
+            self._close_step(root, number, phases, stamp - t0)
             return finished
         if not active_idx and quarantined and self.scheduler.waiting:
             # fail loudly rather than spin run() forever: every slot is
@@ -2064,56 +2132,99 @@ class ServingEngine:
                     "are producing non-finite logits unconditionally"
                 )
 
-        # the watchdog watches steady-state decode, not XLA compilation: the
-        # very first decode (and any step that compiled a new program) may
-        # legitimately take seconds, and a trip there is pure noise
         compiles_before = self.compiles.compile_count
-        if self._watchdog is not None and self._decode_warm:
-            self._watchdog.arm()
         spec_on = self.spec is not None and self.spec.enabled
         if spec_on and self.chaos is not None and self.chaos.spec_disable(self._steps):
             # mid-stream chaos drill: flip to plain decode PERMANENTLY, this
             # very step — the stream must continue without a drop or dup
             self.disable_speculation("chaos")
             spec_on = False
-        keys = jax.random.split(jax.random.fold_in(self._rng, self._steps), self.cache.num_slots)
         drafted = None
         emit = None
-        if spec_on:
-            # the speculative step REPLACES the plain decode: every active
-            # lane rides the verify program (a non-drafting lane's window is
-            # just its pending token — emit 1, the plain-decode token), and
-            # the quarantine probe rides the target's finite verdict as usual
-            tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
-        elif self.paged:
-            nxt, ok, self.cache.k, self.cache.v = self._paged_decode_program()(
-                self.params,
-                self.cache.k,
-                self.cache.v,
-                self._pending,
-                self.cache.lengths,
-                self.cache.active,
-                self.cache.tables,
-                keys,
+        # a speculative step interleaves its dispatches and fetches: one span,
+        # one phase, in place of decode_dispatch and fetch
+        device_phase = "spec_step" if spec_on else "decode_dispatch"
+        with mark("engine." + device_phase):
+            # the watchdog watches steady-state decode, not XLA compilation: the
+            # very first decode (and any step that compiled a new program) may
+            # legitimately take seconds, and a trip there is pure noise
+            if self._watchdog is not None and self._decode_warm:
+                self._watchdog.arm()
+            keys = jax.random.split(jax.random.fold_in(self._rng, self._steps), self.cache.num_slots)
+            if spec_on:
+                # the speculative step REPLACES the plain decode: every active
+                # lane rides the verify program (a non-drafting lane's window is
+                # just its pending token — emit 1, the plain-decode token), and
+                # the quarantine probe rides the target's finite verdict as usual
+                tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
+            elif self.paged:
+                nxt, ok, self.cache.k, self.cache.v = self._paged_decode_program()(
+                    self.params,
+                    self.cache.k,
+                    self.cache.v,
+                    self._pending,
+                    self.cache.lengths,
+                    self.cache.active,
+                    self.cache.tables,
+                    keys,
+                )
+            else:
+                nxt, ok, self.cache.k, self.cache.v = self._decode_program()(
+                    self.params,
+                    self.cache.k,
+                    self.cache.v,
+                    self._pending,
+                    self.cache.lengths,
+                    self.cache.active,
+                    keys,
+                )
+        before, stamp = stamp, time.perf_counter()
+        phases[device_phase] = stamp - before
+        if not spec_on:
+            with mark("engine.fetch"):
+                tokens_mat = np.asarray(nxt)[:, None]  # host fetch = per-step fence
+                finite = np.asarray(ok)
+        with mark("engine.deliver") as live:
+            # `now` closes the fetch phase (empty after a speculative step)
+            # and is the decode fence's stamp
+            now = time.perf_counter()
+            phases["fetch"] = now - stamp
+            retired = len(finished)
+            delivered, context = self._deliver(
+                t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
+                compiles_before, finished,
             )
-            tokens_mat = np.asarray(nxt)[:, None]  # host fetch = per-step fence
-            finite = np.asarray(ok)
-        else:
-            nxt, ok, self.cache.k, self.cache.v = self._decode_program()(
-                self.params,
-                self.cache.k,
-                self.cache.v,
-                self._pending,
-                self.cache.lengths,
-                self.cache.active,
-                keys,
-            )
-            tokens_mat = np.asarray(nxt)[:, None]  # host fetch = per-step fence
-            finite = np.asarray(ok)
+            if live is not None:
+                live.set_metadata(retired=len(finished) - retired)
+        stamp = time.perf_counter()
+        phases["deliver"] = stamp - now
+        self._close_step(root, number, phases, stamp - t0, delivered, context, decoded=1)
+        return finished
+
+    def _close_step(
+        self, root, number: int, phases: dict, seconds: float,
+        tokens: int = 0, context: int = 0, decoded: int = 0,
+    ) -> None:
+        """The step's books: its phase split into the always-on counters
+        (warm-up's compiles are not a serving step's time) and what only the
+        end of a step knows onto its ``engine.step`` span."""
+        if not self._warming:
+            self.stats.record_phases(number, phases, seconds)
+        if root is not None:
+            root.set_metadata(tokens=tokens, context=context, decoded=decoded)
+
+    def _deliver(
+        self, t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
+        compiles_before, finished,
+    ) -> tuple[int, int]:
+        """Everything after the token fetch: act on each lane's verdict
+        (quarantine, cancel, deliver its tokens, retire), release probed
+        slots, roll back speculative windows, record the step. Appends to
+        ``finished``; returns (tokens delivered, the sum of the live lengths
+        they were decoded at)."""
         if self._watchdog is not None:
             self._watchdog.disarm()
         self._steps += 1
-        now = time.perf_counter()
         compiled_this_step = self.compiles.compile_count > compiles_before
         if (
             self.step_timeout_s is not None
@@ -2145,7 +2256,7 @@ class ServingEngine:
                     if marked is not None and self.cache.active[slot]:
                         self.tracer.mark_decode(marked.id, self._steps, now)
 
-        delivered = 0
+        delivered = context = 0
         for slot in active_idx:
             request = self.scheduler.slots[slot]
             if request is None or not self.cache.active[slot]:
@@ -2229,6 +2340,7 @@ class ServingEngine:
                 delivered += 1
                 token = int(tokens_mat[slot, j])
                 request.generated.append(token)
+                context += int(self.cache.lengths[slot])
                 self.cache.lengths[slot] += 1
                 if request.first_token_at is None:
                     request.first_token_at = now
@@ -2288,8 +2400,9 @@ class ServingEngine:
             now - t0, active=len(active_idx), waiting=self.scheduler.waiting,
             tokens=delivered,
             pages_in_use=self.cache.pages_in_use if self.paged else None,
+            context=context,
         )
-        return finished
+        return delivered, context
 
     @property
     def busy(self) -> bool:

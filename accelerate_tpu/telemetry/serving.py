@@ -21,6 +21,21 @@ from typing import Optional
 import numpy as np
 
 
+# the phases of one ``ServingEngine.step()``, in the order it runs them: the
+# names of the ``engine.<phase>`` step spans (telemetry/profiler.py), which
+# are cut at the same boundaries; a speculative step adds ``spec_step``
+PHASES = ("admit", "prefill", "prepare_writes", "decode_dispatch", "fetch", "deliver")
+
+
+def _keep(samples: list, value, cap: int) -> None:
+    """Append one raw sample to a list that must not grow for the life of the
+    process: past ``cap`` it is decimated rather than slid, as
+    ``StepTimer._record`` does, so early-run samples stay represented."""
+    samples.append(value)
+    if len(samples) > cap:
+        samples[:] = samples[::2]
+
+
 def _percentiles_ms(samples: list[float], prefix: str, qs=(50, 90, 99)) -> dict:
     if not samples:
         return {}
@@ -36,11 +51,12 @@ class ServingStats:
     (the honest "what pool would this traffic have needed" number), prefix
     hit rate, chunked-prefill and preemption counters."""
 
+    max_samples = 4096  # cap of every raw-sample list below (a decode step a sample: two minutes at 33 a second)
+
     def __init__(self, num_slots: int, num_pages: Optional[int] = None, page_size: Optional[int] = None):
         self.num_slots = num_slots
         self.num_pages = num_pages
         self.page_size = page_size
-        self.started_at = time.perf_counter()
         self.first_decode_at: Optional[float] = None
         self.steps = 0
         self.decode_seconds = 0.0
@@ -48,7 +64,17 @@ class ServingStats:
         self.ttft_seconds: list[float] = []  # submit → first token, per request
         self.latency_seconds: list[float] = []  # submit → finish, per request
         self.tokens_generated = 0
-        self.prefill_tokens = 0
+        self.prefill_tokens = 0  # bucket positions computed
+        self.prefill_tokens_real = 0  # prompt tokens among them
+        self.decode_context_tokens = 0  # sum of the live lengths the decoded tokens attended to
+        # where a step's host time went, always on (plain adds at the engine's
+        # phase boundaries, the same that cut the engine.* step spans)
+        self.phase_seconds: dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        # what a stall leaves behind when no trace was on
+        self.longest_step: dict = {"seconds": 0.0, "step": None, "phases": {}}
+        self.admissions = 0
+        self.queue_wait_seconds_sum = 0.0  # admitted_at - submitted_at, tracer or no tracer
+        self.queue_wait_seconds_max = 0.0
         self.occupancy_sum = 0.0
         self.queue_depth_sum = 0.0
         self.requests_submitted = 0
@@ -142,8 +168,23 @@ class ServingStats:
     def record_watchdog_trip(self) -> None:
         self.watchdog_trips += 1
 
-    def record_prefill(self, bucket: int) -> None:
+    def record_prefill(self, bucket: int, tokens: int) -> None:
+        """One prefill program: ``bucket`` positions computed for ``tokens`` of a prompt."""
         self.prefill_tokens += bucket
+        self.prefill_tokens_real += tokens
+
+    def record_admission(self, wait_s: float) -> None:
+        self.admissions += 1
+        self.queue_wait_seconds_sum += wait_s
+        self.queue_wait_seconds_max = max(self.queue_wait_seconds_max, wait_s)
+
+    def record_phases(self, step: int, phases: dict[str, float], seconds: float) -> None:
+        """One ``step()``'s split by phase (those it reached) and its whole
+        wall time, decode step or not."""
+        for name, spent in phases.items():
+            self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + spent
+        if seconds > self.longest_step["seconds"]:
+            self.longest_step = {"seconds": seconds, "step": step, "phases": phases}
 
     def record_prefill_chunk(self) -> None:
         self.prefill_chunks += 1
@@ -180,12 +221,12 @@ class ServingStats:
         self.handoffs_adopted += 1
         self.handoff_pages_moved += pages
         self.handoff_bytes_moved += bytes_moved
-        self.handoff_seconds.append(seconds)
+        _keep(self.handoff_seconds, seconds, self.max_samples)
 
     def record_span(self, kind: str, seconds: float) -> None:
         """One closed trace span's duration, as a raw sample keyed by span
         kind (queued / prefill / parked / handoff_attempt / decode)."""
-        self.span_seconds.setdefault(kind, []).append(seconds)
+        _keep(self.span_seconds.setdefault(kind, []), seconds, self.max_samples)
         self.trace_spans += 1
 
     def record_trace_completed(self) -> None:
@@ -204,7 +245,8 @@ class ServingStats:
         self.spec_steps += 1
         self.spec_proposed_tokens += proposed
         self.spec_accepted_tokens += int(sum(accepted_lengths))
-        self.spec_accepted_lengths.extend(int(a) for a in accepted_lengths)
+        for accepted in accepted_lengths:
+            _keep(self.spec_accepted_lengths, int(accepted), self.max_samples)
 
     def record_spec_fallback(self) -> None:
         self.spec_fallbacks += 1
@@ -222,17 +264,21 @@ class ServingStats:
         waiting: int,
         tokens: Optional[int] = None,
         pages_in_use: Optional[int] = None,
+        context: int = 0,
     ) -> None:
         """``tokens`` = tokens actually delivered this step (defaults to
         ``active``; the engine passes fewer when a quarantined slot's token
         was discarded — throughput must never count undelivered tokens).
-        ``pages_in_use`` feeds the paged-pool economy metrics."""
+        ``pages_in_use`` feeds the paged-pool economy metrics. ``context`` =
+        the sum, over the delivered tokens, of the live length each was
+        decoded at: what decode attention had to read, in tokens."""
         if self.first_decode_at is None:
             self.first_decode_at = time.perf_counter() - duration_s
         self.steps += 1
         self.decode_seconds += duration_s
-        self.step_seconds.append(duration_s)
+        _keep(self.step_seconds, duration_s, self.max_samples)
         self.tokens_generated += active if tokens is None else tokens
+        self.decode_context_tokens += context
         self.occupancy_sum += active / self.num_slots
         self.queue_depth_sum += waiting
         self.max_active = max(self.max_active, active)
@@ -242,11 +288,11 @@ class ServingStats:
             self.page_occupancy_sum += pages_in_use / max(self.num_pages - 1, 1)
 
     def record_first_token(self, ttft_s: float) -> None:
-        self.ttft_seconds.append(ttft_s)
+        _keep(self.ttft_seconds, ttft_s, self.max_samples)
 
     def record_finish(self, latency_s: float) -> None:
         self.requests_completed += 1
-        self.latency_seconds.append(latency_s)
+        _keep(self.latency_seconds, latency_s, self.max_samples)
 
     # -- readout -----------------------------------------------------------
 
@@ -272,6 +318,9 @@ class ServingStats:
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
             "prefill_tokens": self.prefill_tokens,
+            "prefill_tokens_real": self.prefill_tokens_real,
+            "decode_context_tokens": self.decode_context_tokens,
+            "admissions": self.admissions,
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "requests_rejected": self.requests_rejected,
@@ -298,6 +347,10 @@ class ServingStats:
         if self.steps:
             out["queue_depth_mean"] = round(self.queue_depth_sum / self.steps, 3)
             out["decode_seconds"] = round(self.decode_seconds, 4)
+        out.update(_host_time_keys(
+            self.phase_seconds, self.longest_step, self.admissions,
+            self.queue_wait_seconds_sum, self.queue_wait_seconds_max,
+        ))
         if self.num_pages:
             out["num_pages"] = self.num_pages
             out["page_size"] = self.page_size
@@ -341,6 +394,21 @@ class ServingStats:
         return out
 
 
+def _host_time_keys(phase_seconds: dict, longest_step: dict, admissions: int, wait_sum: float, wait_max: float) -> dict:
+    """The flat keys of the always-on host-time counters, for one engine's
+    snapshot and for the fleet's rollup alike."""
+    out = {f"phase_{name}_seconds": round(seconds, 4) for name, seconds in phase_seconds.items()}
+    if longest_step["step"] is not None:
+        out["longest_step_ms"] = round(longest_step["seconds"] * 1e3, 3)
+        out["longest_step_number"] = longest_step["step"]
+        for name, seconds in longest_step["phases"].items():
+            out[f"longest_step_{name}_ms"] = round(seconds * 1e3, 3)
+    if admissions:
+        out["queue_wait_mean_ms"] = round(wait_sum / admissions * 1e3, 3)
+        out["queue_wait_max_ms"] = round(wait_max * 1e3, 3)
+    return out
+
+
 def fleet_rollup(
     stats_list: list["ServingStats"], roles: Optional[list[str]] = None
 ) -> dict:
@@ -365,7 +433,8 @@ def fleet_rollup(
     if not stats_list:
         return out
     counters = (
-        "steps", "tokens_generated", "prefill_tokens", "requests_submitted",
+        "steps", "tokens_generated", "prefill_tokens", "prefill_tokens_real",
+        "decode_context_tokens", "admissions", "requests_submitted",
         "requests_completed", "requests_rejected", "requests_expired",
         "requests_cancelled", "requests_requeued", "requests_failed",
         "requests_rehomed", "slot_quarantines", "slot_quarantine_releases",
@@ -404,6 +473,17 @@ def fleet_rollup(
             sum(s.queue_depth_sum for s in stats_list) / steps, 3
         )
         out["decode_seconds"] = round(sum(s.decode_seconds for s in stats_list), 4)
+    # host time: phases add across replicas; the longest step and the longest
+    # queue wait are the fleet's worst, whichever replica had them
+    phases: dict[str, float] = {}
+    for s in stats_list:
+        for name, seconds in s.phase_seconds.items():
+            phases[name] = phases.get(name, 0.0) + seconds
+    out.update(_host_time_keys(
+        phases, max((s.longest_step for s in stats_list), key=lambda longest: longest["seconds"]),
+        out["admissions"], sum(s.queue_wait_seconds_sum for s in stats_list),
+        max(s.queue_wait_seconds_max for s in stats_list),
+    ))
     for samples, prefix in (
         ([t for s in stats_list for t in s.step_seconds], "per_token"),
         ([t for s in stats_list for t in s.ttft_seconds], "ttft"),

@@ -47,8 +47,13 @@ sequences (submit / admit / park / retire / handoff boundaries, plus the
 per-step decode fence): tracing adds ZERO device work, zero extra host
 syncs, and no new compiled programs — ``analyze --self-check`` gates the
 traced decode/prefill programs against the same checked-in contracts as the
-untraced ones, and ``bench.py`` records ``tracing_overhead_pct`` from
-paired windows (modeled on ``resilience_guard_overhead_pct``).
+untraced ones. What it costs has not been measured on a chip (``bench.py``'s
+``tracing_overhead_pct`` is superseded, PERF.md §2, and left no record).
+
+These spans say where a REQUEST's latency went. Why the DEVICE sat idle
+inside a step is another question, answered by the step spans of
+``telemetry/profiler.py`` (``engine.*`` / ``train.*``), which live on the
+profiler's clock while a profiler session is on.
 
 A completed trace flushes as one ``{"kind": "trace"}`` record into
 ``telemetry.jsonl`` and feeds the SLO monitor (telemetry/slo.py) when one
