@@ -74,6 +74,26 @@ def grouped_output(p: jax.Array, v: jax.Array) -> jax.Array:
     return jnp.einsum("bnst,btnd->bsnd", p, v)
 
 
+def split_decode_cache(cache: dict) -> tuple[dict, dict]:
+    """How a decode protocol's layer scan receives its cache: ``(shared,
+    per_layer)`` — ``per_layer`` is scanned beside the layers' params,
+    ``shared`` is closed over by the scan body.
+
+    A dense cache (or the serving engine's gathered view) is scanned: each
+    layer's ``[B, T, KV, D]`` slice feeds XLA's own fusions. Under the paged
+    ``attend`` protocol "k"/"v" are the whole page POOL ``[L, P, ps, KV,
+    D]``, which must NOT be scanned: the per-layer slice would be the operand
+    of the kernel's custom call, an operand must be a buffer of its own, and
+    XLA would copy one layer's whole pool out, K and V, every layer of every
+    step. Closed over, the pool is an invariant of the scan's ``while``
+    (passed by reference); only the layer INDEX is scanned, and ``attend``
+    addresses the pool by (layer, page)."""
+    if "attend" in cache:
+        shared = {key: cache[key] for key in ("k", "v", "table", "attend")}
+        return shared, {"layer": jnp.arange(cache["k"].shape[0], dtype=jnp.int32)}
+    return {}, {"k": cache["k"], "v": cache["v"]}
+
+
 def dot_product_attention(
     q: jax.Array,  # [B, S, N, D]
     k: jax.Array,  # [B, T, K, D]

@@ -17,7 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .attention import rotary_embedding
+from .attention import rotary_embedding, split_decode_cache
 from .config import TransformerConfig
 from .llama import Llama, decoder_layer, rms_norm
 
@@ -45,10 +45,11 @@ def forward_with_cache(model: Llama, params: dict, input_ids: jax.Array, cache: 
     cos, sin = rotary_embedding(positions, cfg.dim_per_head, cfg.rope_theta, dtype=h.dtype)
 
     # paged-kernel decode (serving engine, use_kernels=True): the cache's
-    # "k"/"v" are the page POOL (scanned per layer) and "attend" masks inside
-    # the kernel against "table"/"length" — no [S, T] mask to build here
-    extra = {key: cache[key] for key in ("table", "attend") if key in cache}
-    if extra:
+    # "k"/"v" are the page POOL (shared by every layer, addressed by the
+    # scanned layer index) and "attend" masks inside the kernel against
+    # "table"/"length" — no [S, T] mask to build here
+    shared, per_layer = split_decode_cache(cache)
+    if shared:
         mask = None
     else:
         # positions <= current are attendable: causal within the block, full over cache
@@ -59,15 +60,15 @@ def forward_with_cache(model: Llama, params: dict, input_ids: jax.Array, cache: 
 
     def body(carry, xs):
         h = carry
-        lp, k_cache, v_cache = xs
+        lp, layer_cache = xs
         h, new_cache = decoder_layer(
             cfg, h, lp, cos, sin, mask,
-            cache={"k": k_cache, "v": v_cache, "length": length, **extra},
+            cache={**shared, **layer_cache, "length": length},
             dot_fn=getattr(model, "dot_fn", None),
         )
         return h, (new_cache["k"], new_cache["v"])
 
-    h, (k_cache, v_cache) = jax.lax.scan(body, h, (params["layers"], cache["k"], cache["v"]))
+    h, (k_cache, v_cache) = jax.lax.scan(body, h, (params["layers"], per_layer))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     head = params["embed_tokens"].T if cfg.tie_embeddings else params["lm_head"]
     logits = h[:, -1] @ head.astype(h.dtype)
@@ -96,19 +97,19 @@ def forward_window_with_cache(model: Llama, params: dict, input_ids: jax.Array, 
     h = jnp.take(params["embed_tokens"], input_ids, axis=0)
     positions = length + jnp.arange(s)[None, :]
     cos, sin = rotary_embedding(positions, cfg.dim_per_head, cfg.rope_theta, dtype=h.dtype)
-    extra = {key: cache[key] for key in ("table", "attend") if key in cache}
+    shared, per_layer = split_decode_cache(cache)
 
     def body(carry, xs):
         h = carry
-        lp, k_cache, v_cache = xs
+        lp, layer_cache = xs
         h, new_cache = decoder_layer(
             cfg, h, lp, cos, sin, None,
-            cache={"k": k_cache, "v": v_cache, "length": length, **extra},
+            cache={**shared, **layer_cache, "length": length},
             dot_fn=getattr(model, "dot_fn", None),
         )
         return h, (new_cache["k"], new_cache["v"])
 
-    h, (k_cache, v_cache) = jax.lax.scan(body, h, (params["layers"], cache["k"], cache["v"]))
+    h, (k_cache, v_cache) = jax.lax.scan(body, h, (params["layers"], per_layer))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     head = params["embed_tokens"].T if cfg.tie_embeddings else params["lm_head"]
     logits = h @ head.astype(h.dtype)  # all positions, not just the last

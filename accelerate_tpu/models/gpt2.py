@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.constants import MESH_AXIS_TENSOR
-from .attention import dense_init, dot_product_attention, dropout, resolve_dot
+from .attention import dense_init, dot_product_attention, dropout, resolve_dot, split_decode_cache
 from .bert import layer_norm
 from .config import TransformerConfig, get_config
 
@@ -113,9 +113,10 @@ class GPT2:
         q, k, v = (t.reshape(b, s, nh, d) for t in (q, k, v))
         new_cache = None
         if cache is not None and "attend" in cache:
-            # paged-kernel decode: attention reads the page pool directly
-            # (ops/paged_attention.py); the engine scatters the returned
-            # new-token K/V — see models/llama.py decoder_layer
+            # paged-kernel decode: attention reads the stacked page pool
+            # directly, at cache["layer"] (ops/paged_attention.py); the
+            # engine scatters the returned new-token K/V — see
+            # models/llama.py decoder_layer
             attn = cache["attend"](q, k, v, cache)
             new_cache = {"k": k, "v": v}
         elif cache is not None:
@@ -166,21 +167,20 @@ class GPT2:
         decode_suffix, scanned over the stacked layers."""
         b, s = input_ids.shape
         length = cache["length"]
-        # paged-kernel decode threads the pool's table + attend hook through
-        # (see models/llama.py decoder_layer); max_len only shapes the mask,
-        # which the kernel path computes internally from table/length
-        extra = {key: cache[key] for key in ("table", "attend") if key in cache}
-        max_len = self.config.max_seq_len if extra else cache["k"].shape[2]
+        # paged-kernel decode threads the pool, its table + attend hook and
+        # the scanned layer index through (see models/llama.py
+        # decoder_layer); max_len only shapes the mask, which the kernel
+        # path computes internally from table/length
+        shared, per_layer = split_decode_cache(cache)
+        max_len = self.config.max_seq_len if shared else cache["k"].shape[2]
         carry = self.decode_prefix(params, input_ids, length, max_len=max_len)
 
         def body(carry, xs):
-            lp, k_cache, v_cache = xs
-            carry, nc = self.stream_layer_cached(
-                carry, lp, {"k": k_cache, "v": v_cache, **extra}, length
-            )
+            lp, layer_cache = xs
+            carry, nc = self.stream_layer_cached(carry, lp, {**shared, **layer_cache}, length)
             return carry, (nc["k"], nc["v"])
 
-        carry, (k_cache, v_cache) = jax.lax.scan(body, carry, (params["layers"], cache["k"], cache["v"]))
+        carry, (k_cache, v_cache) = jax.lax.scan(body, carry, (params["layers"], per_layer))
         logits = self.decode_suffix(params, carry)
         return logits, {"k": k_cache, "v": v_cache, "length": length + s}
 
@@ -197,17 +197,15 @@ class GPT2:
             )
         b, s = input_ids.shape
         length = cache["length"]
-        extra = {key: cache[key] for key in ("table", "attend") if key in cache}
+        shared, per_layer = split_decode_cache(cache)
         carry = self.decode_prefix(params, input_ids, length, max_len=self.config.max_seq_len)
 
         def body(carry, xs):
-            lp, k_cache, v_cache = xs
-            carry, nc = self.stream_layer_cached(
-                carry, lp, {"k": k_cache, "v": v_cache, **extra}, length
-            )
+            lp, layer_cache = xs
+            carry, nc = self.stream_layer_cached(carry, lp, {**shared, **layer_cache}, length)
             return carry, (nc["k"], nc["v"])
 
-        carry, (k_cache, v_cache) = jax.lax.scan(body, carry, (params["layers"], cache["k"], cache["v"]))
+        carry, (k_cache, v_cache) = jax.lax.scan(body, carry, (params["layers"], per_layer))
         h, _ = carry
         h = layer_norm(h, params["final_norm_scale"], params["final_norm_bias"], self.config.norm_eps)
         logits = (h @ params["embed_tokens"].T.astype(h.dtype)).astype(jnp.float32)

@@ -68,10 +68,12 @@ def decoder_layer(
     new_cache = None
     if cache is not None and "attend" in cache:
         # paged-kernel decode (serving engine, use_kernels=True): the cache
-        # carries one layer of the page POOL plus this slot's table row, and
-        # ``attend`` (ops/paged_attention.py) reads the pool directly — no
-        # gathered view, no in-layer cache write. The new token's K/V return
-        # as the cache delta; the engine scatters them into the pool.
+        # carries the whole stacked page POOL, this layer's index into it
+        # ("layer") and this slot's table row, and ``attend``
+        # (ops/paged_attention.py) reads the pool in place — no per-layer
+        # slice, no gathered view, no in-layer cache write. The new token's
+        # K/V return as the cache delta; the engine scatters them into the
+        # pool.
         attn = cache["attend"](q, k, v, cache)
         new_cache = {"k": k, "v": v, "length": cache["length"]}
     elif cache is not None:

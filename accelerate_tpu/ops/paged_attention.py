@@ -19,6 +19,17 @@ the softmax as a final block under a causal in-window mask, so the engine's
 write-back stays a separate scatter exactly as in the reference program.
 Plain decode is the window of one token.
 
+The kernel takes the whole STACKED pool ``[L, P, page_size, KV, D]`` where it
+lies in HBM and addresses it by (layer, page); the layer index is a third
+scalar-prefetch operand. It does not take one layer's pool ``[P, ...]``: a
+per-layer slice of the stacked pool fed to a custom call is a COPY — the
+call's operand must be a buffer of its own, so XLA materializes the layer's
+whole pool, K and V, for every layer of every step, eight times what the
+kernel then reads (18 % of the serving cell's device time until PR 28;
+PERF.md §6). The decode protocols therefore close their layer scan over the
+pool and scan the layer index (``models/attention.py:split_decode_cache``).
+A single-layer pool is the stacked pool with ``L = 1`` and ``layer = 0``.
+
 The pool keeps heads on the sublane axis (``[.., KV, D]`` tiles), so scores
 are a lane reduction per head on the VPU rather than an MXU matmul — every
 op is a ``[.., KV, D]`` elementwise product, a minor-axis reduce, or a
@@ -97,11 +108,12 @@ def _fold(carry, q, k, v, valid):
 def _paged_kernel(
     tables_ref,  # SMEM [S, pps] int32 (scalar prefetch): page-table rows
     lengths_ref,  # SMEM [S] int32 (scalar prefetch): committed positions
+    layer_ref,  # SMEM [1] int32 (scalar prefetch): which layer of the pool
     q_ref,  # VMEM [1, W*group, KV, D]: row wi*group+gi (pre-scaled)
     kn_ref,  # VMEM [1, W, KV, D]: the window's keys (pre-scatter)
     vn_ref,  # VMEM [1, W, KV, D]
-    pool_k_ref,  # ANY (HBM) [P, ps, KV, D]
-    pool_v_ref,  # ANY (HBM) [P, ps, KV, D]
+    pool_k_ref,  # ANY (HBM) [L, P, ps, KV, D]: the stacked pool, in place
+    pool_v_ref,  # ANY (HBM) [L, P, ps, KV, D]
     o_ref,  # VMEM [1, W*group, KV, D] out
     k_scratch,  # VMEM [ps, KV, D] pool dtype
     v_scratch,  # VMEM [ps, KV, D]
@@ -113,6 +125,7 @@ def _paged_kernel(
 ):
     slot = pl.program_id(0)
     length = lengths_ref[slot]
+    layer = layer_ref[0]
     kv, d = q_ref.shape[-2:]
     f32 = jnp.float32
     queries = [q_ref[0, r].astype(f32) for r in range(window * group)]  # [KV, D] each
@@ -129,8 +142,8 @@ def _paged_kernel(
 
     def body(j, carry):
         page = tables_ref[slot, j]
-        k_dma = pltpu.make_async_copy(pool_k_ref.at[page], k_scratch, sems.at[0])
-        v_dma = pltpu.make_async_copy(pool_v_ref.at[page], v_scratch, sems.at[1])
+        k_dma = pltpu.make_async_copy(pool_k_ref.at[layer, page], k_scratch, sems.at[0])
+        v_dma = pltpu.make_async_copy(pool_v_ref.at[layer, page], v_scratch, sems.at[1])
         k_dma.start()
         v_dma.start()
         k_dma.wait()
@@ -156,10 +169,11 @@ def _paged_kernel(
         o_ref[0, r] = (acc / l).astype(o_ref.dtype)
 
 
-def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths):
+def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
     """The slot-batched launch: ``q`` ``[S, W, NH, D]`` (pre-scaled),
-    ``k_new``/``v_new`` ``[S, W, KV, D]``, ``tables`` ``[S, pps]``,
-    ``lengths`` ``[S]`` → ``[S, W, NH, D]``."""
+    ``k_new``/``v_new`` ``[S, W, KV, D]``, the stacked pool
+    ``[L, P, ps, KV, D]``, ``tables`` ``[S, pps]``, ``lengths`` ``[S]``,
+    ``layer`` scalar → ``[S, W, NH, D]``."""
     s, w, nh, d = q.shape
     kv = k_new.shape[2]
     ps = pool_k.shape[-3]
@@ -175,7 +189,7 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths):
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=ps, window=w, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(s,),
             in_specs=[
                 per_slot(rows),
@@ -194,36 +208,43 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths):
         out_shape=jax.ShapeDtypeStruct((s, rows, kv, d), q.dtype),
         interpret=interpret_mode(),
         name="paged_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q_rows, k_new, v_new, pool_k, pool_v)
+    )(
+        tables.astype(jnp.int32), lengths.astype(jnp.int32), layer.astype(jnp.int32).reshape(1),
+        q_rows, k_new, v_new, pool_k, pool_v,
+    )
     return out.reshape(s, w, group, kv, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
 
 
 @jax.custom_batching.custom_vmap
-def _paged_one_slot(q, k_new, v_new, table, length, pool_k, pool_v):
+def _paged_one_slot(q, k_new, v_new, table, length, layer, pool_k, pool_v):
     return _paged_call(
-        q[None], k_new[None], v_new[None], pool_k, pool_v, table[None], length[None]
+        q[None], k_new[None], v_new[None], pool_k, pool_v, table[None], length[None], layer
     )[0]
 
 
 @_paged_one_slot.def_vmap
-def _paged_slots(axis_size, in_batched, q, k_new, v_new, table, length, pool_k, pool_v):
+def _paged_slots(axis_size, in_batched, q, k_new, v_new, table, length, layer, pool_k, pool_v):
     """The engine's slot ``vmap`` lands here: per-slot operands arrive
-    stacked, the pool is shared — one launch with the slot axis as grid."""
+    stacked, the pool and the layer index are shared — one launch with the
+    slot axis as grid."""
     if any(in_batched[5:]):
-        raise NotImplementedError("paged attention batches slots over ONE shared page pool")
+        raise NotImplementedError(
+            "paged attention batches slots over ONE shared page pool, one layer at a time"
+        )
     q, k_new, v_new, table, length = (
         x if batched else jnp.broadcast_to(x, (axis_size, *x.shape))
         for x, batched in zip((q, k_new, v_new, table, length), in_batched)
     )
-    return _paged_call(q, k_new, v_new, pool_k, pool_v, table, length), True
+    return _paged_call(q, k_new, v_new, pool_k, pool_v, table, length, layer), True
 
 
 def _reference(q, k_new, v_new, pool_k, pool_v, table, length, scale):
-    """Gather-based oracle with the kernel's exact masking semantics: the
-    table-gathered view (positions < length valid) plus the candidate window
-    under a lower-triangular in-window mask. Tests compare the kernel against
-    it; the engine's ``use_kernels=False`` path is a different
-    (byte-identical-to-PR-7) program and never lands here."""
+    """Gather-based oracle with the kernel's exact masking semantics, over
+    ONE layer's pool ``[P, ps, KV, D]`` (the caller slices the stacked pool;
+    an oracle may copy): the table-gathered view (positions < length valid)
+    plus the candidate window under a lower-triangular in-window mask. Tests
+    compare the kernel against it; the engine's ``use_kernels=False`` path is
+    a different (byte-identical-to-PR-7) program and never lands here."""
     from ..models.attention import dot_product_attention
 
     taken_k = jnp.take(pool_k, table, axis=0).reshape(-1, *pool_k.shape[2:])
@@ -242,10 +263,11 @@ def paged_verify_attention(
     q: jax.Array,  # [1, W, NH, D]: one slot's candidate-window queries
     k_new: jax.Array,  # [1, W, KV, D]: the window's keys (pre-scatter)
     v_new: jax.Array,  # [1, W, KV, D]
-    pool_k: jax.Array,  # [P, page_size, KV, D]: one layer of the page pool
-    pool_v: jax.Array,  # [P, page_size, KV, D]
+    pool_k: jax.Array,  # [L, P, page_size, KV, D]: the whole stacked page pool
+    pool_v: jax.Array,  # [L, P, page_size, KV, D]
     table: jax.Array,  # [pps] int32 page-table row
     length: jax.Array,  # scalar int32: committed positions in the pool
+    layer: jax.Array,  # scalar int32: the layer of the pool to attend
     scale: Optional[float] = None,
 ) -> jax.Array:
     """One slot's attention over its paged KV plus a W-token candidate
@@ -263,7 +285,7 @@ def paged_verify_attention(
     qs = q * jnp.asarray(scale, q.dtype)
     out = _paged_one_slot(
         qs[0], k_new[0], v_new[0], table.astype(jnp.int32), jnp.asarray(length, jnp.int32),
-        pool_k, pool_v,
+        jnp.asarray(layer, jnp.int32), pool_k, pool_v,
     )
     return out[None]
 

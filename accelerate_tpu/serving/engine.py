@@ -546,12 +546,20 @@ class ServingEngine:
     # zeros to the null page, and quarantine scrubs freed pages on device.
 
     @staticmethod
-    def _gathered_view(pool_k, pool_v, row, length):
+    def _gathered_view(pool_k, pool_v, row, length, layer=None):
         """One slot's cache dict: pages gathered through its table row into
         the contiguous ``[L, 1, view_len, ...]`` layout the protocol expects.
+        With ``layer`` (an ``attend`` hook's index), that layer's view alone,
+        ``[1, 1, view_len, ...]``: the same gather over the pool flattened to
+        ``[1, L*P, ...]`` (a bitcast), by ``layer*P + row`` — never a slice
+        of the layer's whole pool.
         Static on purpose: the paged programs close over it, and those live
         in the model-lifetime jit cache — a bound method would pin the whole
         engine (KV pool included) long after the engine is discarded."""
+        if layer is not None:
+            row = layer * pool_k.shape[1] + row
+            pool_k = pool_k.reshape(1, -1, *pool_k.shape[2:])
+            pool_v = pool_v.reshape(1, -1, *pool_v.shape[2:])
         taken_k = jnp.take(pool_k, row, axis=1)  # [L, pps, ps, ...]
         taken_v = jnp.take(pool_v, row, axis=1)
         shape = (taken_k.shape[0], 1, taken_k.shape[1] * taken_k.shape[2]) + taken_k.shape[3:]
@@ -567,17 +575,19 @@ class ServingEngine:
             def decode_step(params, pk, pv, tokens, lengths, active, tables, keys):
                 if use_kernel:
                     # the Pallas path (ops/paged_attention.py): attention
-                    # reads the pool + this slot's table row DIRECTLY — the
-                    # gathered view is never materialized, invalid pages are
-                    # never read. The vmap below batches the slot axis into
-                    # the kernel grid, so this stays one slot-batched launch
-                    # per layer per step; the protocol returns the new
-                    # token's K/V as the cache delta, already extracted.
+                    # reads the stacked pool IN PLACE, by (the protocol's
+                    # scanned layer index, this slot's table row) — neither
+                    # a layer's pool nor the gathered view is materialized,
+                    # invalid pages are never read. The vmap below batches
+                    # the slot axis into the kernel grid, so this stays one
+                    # slot-batched launch per layer per step; the protocol
+                    # returns the new token's K/V as the cache delta,
+                    # already extracted.
                     from ..ops.paged_attention import paged_decode_attention
 
                     def attend(q, kn, vn, c):
                         return paged_decode_attention(
-                            q, kn, vn, c["k"], c["v"], c["table"], c["length"]
+                            q, kn, vn, c["k"], c["v"], c["table"], c["length"], c["layer"]
                         )
 
                     def one_slot(token, row, length, key):
@@ -643,9 +653,11 @@ class ServingEngine:
         page with zeroed values.
 
         The attend hook is the same duality as decode: the Pallas verify
-        kernel (``paged_verify_attention``) or the ``_gathered_view``
-        reference — committed pages gathered through the table row, window
-        keys concatenated behind them, causal-inside-the-window mask."""
+        kernel (``paged_verify_attention``) or the gather reference — this
+        layer's committed pages gathered through the table row (what
+        ``_gathered_view`` holds for decode), window keys concatenated
+        behind them, causal-inside-the-window mask. Either way the hook
+        receives the whole stacked pool and the protocol's layer index."""
         fwd_window = self._fwd_window
         ps = self.cache.page_size
         pps = self.cache.pages_per_slot
@@ -659,22 +671,23 @@ class ServingEngine:
 
                 def attend(q, kn, vn, c):
                     return paged_verify_attention(
-                        q, kn, vn, c["k"], c["v"], c["table"], c["length"]
+                        q, kn, vn, c["k"], c["v"], c["table"], c["length"], c["layer"]
                     )
             else:
                 from ..models.attention import dot_product_attention
 
                 def attend(q, kn, vn, c):
                     # the reference verify path: gather the slot's committed
-                    # pages exactly as decode does, then attend over
+                    # pages of THIS layer out of the stacked pool (decode's
+                    # gathered view, one layer of it), then attend over
                     # [committed view | window] with the in-window causal
                     # mask — row j sees positions < length plus window rows
                     # <= j. (The model's DUS write path cannot serve here:
                     # near view_len the clamp would misplace window K/V.)
-                    view = gathered(c["k"][None], c["v"][None], c["table"], c["length"])
+                    view = gathered(c["k"], c["v"], c["table"], c["length"], layer=c["layer"])
+                    t = view["k"].shape[2]
                     keys = jnp.concatenate([view["k"][0].astype(q.dtype), kn], axis=1)
                     values = jnp.concatenate([view["v"][0].astype(q.dtype), vn], axis=1)
-                    t = view["k"].shape[2]
                     committed = jnp.broadcast_to(
                         jnp.arange(t)[None, :] < c["length"], (w, t)
                     )

@@ -5,8 +5,9 @@ multiplicative lever (docs/serving.md, "Speculative decoding") is to let a
 SMALL draft model from the same zoo propose ``k`` candidate tokens cheaply,
 then have the target model score the whole ``k+1``-token window — the still
 pending input token plus the candidates — in ONE decode-shaped step
-(``ops/paged_attention.paged_verify_attention`` on the kernel path, the
-``_gathered_view`` + in-window causal mask on the reference path). The
+(``ops/paged_attention.paged_verify_attention`` on the kernel path, a
+gather of the layer's committed pages + in-window causal mask on the
+reference path). The
 engine accepts the longest prefix of candidates that agrees with the
 target's own greedy choices, so at temperature 0 the emitted stream is
 token-bit-equal to plain decode — the draft model can only change HOW MANY

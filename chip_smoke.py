@@ -450,26 +450,33 @@ def _engine_kwargs(run: Run) -> dict:
 
 
 def _paged_op_check(run: Run, engine) -> None:
-    """The decode kernel against its gather oracle on this engine's own pool
-    geometry and dtype, at the tolerance of that dtype."""
+    """The decode kernel against its gather oracle on this engine's own
+    stacked pool geometry and dtype, at the tolerance of that dtype. Every
+    layer but the one attended holds NaN, so a wrong layer shows."""
     import jax.numpy as jnp
 
     from accelerate_tpu.ops.paged_attention import _reference, paged_decode_attention
 
-    _, pages, page_size, kv, d = engine.cache.k.shape
+    layers, pages, page_size, kv, d = engine.cache.k.shape
     dtype = engine.cache.k.dtype
     nh = engine.model.config.num_heads
     rng = np.random.default_rng(1)
+    layer = layers - 1
 
     def draw(*shape):
         return jnp.asarray(rng.normal(size=shape), dtype)
 
-    pool_k, pool_v = draw(pages, page_size, kv, d), draw(pages, page_size, kv, d)
+    def stacked(one_layer):
+        return jnp.full((layers, *one_layer.shape), jnp.nan, dtype).at[layer].set(one_layer)
+
+    layer_k, layer_v = draw(pages, page_size, kv, d), draw(pages, page_size, kv, d)
     q, kn, vn = draw(1, 1, nh, d), draw(1, 1, kv, d), draw(1, 1, kv, d)
     table = jnp.asarray(rng.permutation(pages)[:4], jnp.int32)
     length = jnp.int32(2 * page_size + 3)  # two full pages and a partial one
-    got = paged_decode_attention(q, kn, vn, pool_k, pool_v, table, length)
-    want = _reference(q, kn, vn, pool_k, pool_v, table, length, scale=1.0 / d**0.5)
+    got = paged_decode_attention(
+        q, kn, vn, stacked(layer_k), stacked(layer_v), table, length, jnp.int32(layer)
+    )
+    want = _reference(q, kn, vn, layer_k, layer_v, table, length, scale=1.0 / d**0.5)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol

@@ -2,13 +2,15 @@
 TPU, its named CPU rehearsal must run the real phases green with
 interpret-mode kernels, and one failed phase must fail the run. (Every
 phase at rehearsal size: ``python chip_smoke.py --cpu-rehearsal``, ~40 s —
-too long for this suite's budget, which pays for one phase; see
+too long for this suite's budget, which pays for the two serving phases; see
 .claude/skills/verify/SKILL.md.)"""
 
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 import chip_smoke
 
@@ -37,15 +39,24 @@ def test_without_a_tpu_it_exits_nonzero_and_prints_no_result():
     assert "{" not in proc.stdout  # no JSON result, not even a failed one
 
 
-def test_cpu_rehearsal_runs_green_with_interpreted_kernels(capsys):
-    rc = chip_smoke.main(["--cpu-rehearsal", "--phases", "serve_int8"])
+@pytest.mark.parametrize(
+    "name, kernels",
+    [
+        ("serve_int8", {"quant_matmul": "interpreted", "paged_attention": "interpreted"}),
+        # its op-level check is the one caller of the paged kernel outside the
+        # engine and the tests: a change of the kernel's signature shows here
+        ("serve", {"paged_attention": "interpreted"}),
+    ],
+)
+def test_cpu_rehearsal_runs_green_with_interpreted_kernels(capsys, name, kernels):
+    rc = chip_smoke.main(["--cpu-rehearsal", "--phases", name])
     result, summary = _result_and_summary(capsys.readouterr().out)
     assert rc == 0, summary
     assert result["ok"] is True and summary["rehearsal"]
     assert result["device"]["platform"] == "cpu"  # never mistaken for a chip result
     assert isinstance(result["device"]["kind"], str) and isinstance(result["device"]["count"], int)
-    phase = summary["phases"]["serve_int8"]
-    assert phase["kernels"] == {"quant_matmul": "interpreted", "paged_attention": "interpreted"}
+    phase = summary["phases"][name]
+    assert phase["kernels"] == kernels
     assert phase["steady_state_compiles"] == 0
     assert phase["tokens_equal"] == phase["tokens"]  # fp32 at tiny size: no ties
     assert summary["skipped"]["mesh"] == "not selected"
