@@ -1,5 +1,7 @@
 """Driver of the serving mixes: ``ServingEngine`` -> ``warmup`` ->
-``submit`` / ``step`` in a closed loop, one client per slot."""
+``submit`` / ``step`` in a closed loop, one client per slot. What differs
+between model families (the model, its weights, its reference) is the
+family's, ``ctx.family``."""
 
 from __future__ import annotations
 
@@ -7,23 +9,25 @@ import gc
 
 import numpy as np
 
-from ..lib import configs, reference_llama, stats, traffic, weights, work
-from ..lib.harness import Spans, memory_peak_bytes, now, traced_window
+from ..lib import compare, stats, traffic
+from ..lib.harness import Spans, family_counters, memory_peak_bytes, now, traced_window
 
 
 class ClosedLoop:
-    """The clients: each sends its next request when its last one finishes."""
+    """The clients: each sends its next request when its last one finishes.
+    ``cfg`` is not used here; ``tests/test_step_spans.py`` builds the loop with it."""
 
     def __init__(self, engine, streams, cfg, spans):
-        self.engine, self.streams, self.cfg, self.spans = engine, streams, cfg, spans
+        self.engine, self.streams, self.spans = engine, streams, spans
         self.owner: dict[int, int] = {}
-        self.prefill_flops = 0.0
+        # of each decoding step so far, the live context length each of its tokens was
+        # decoded at, one entry a token: what was cached before it
+        self.contexts: list[np.ndarray] = []
 
     def submit(self, client: int) -> None:
         prompt, output_len = self.streams.next(client)
         with self.spans("bench.submit"):
             self.owner[self.engine.submit(prompt, max_new_tokens=output_len)] = client
-        self.prefill_flops += work.llama_forward_flops(self.cfg, 0, prompt.size - 1)
 
     def step(self) -> tuple[list, float, int, int]:
         """One engine step. Returns (finished results, seconds, tokens decoded,
@@ -42,17 +46,19 @@ class ClosedLoop:
             done = [r.prompt.size - 1 + r.generated.size for r in finished if r.finish_reason in ("length", "eos")]
             tokens = int(live.size) + len(done)
             context = int(live.sum()) + sum(done) - tokens
+            self.contexts.append(np.concatenate([live, np.asarray(done, live.dtype)]) - 1)
         for result in finished:
             self.submit(self.owner.pop(result.request_id))
         return finished, seconds, tokens, context
 
 
-def run_window(loop: ClosedLoop, seconds: float) -> dict:
+def run_window(loop: ClosedLoop, seconds: float, family) -> dict:
     """Drive the loop for ``seconds``; everything the metrics read."""
     engine = loop.engine
     stats = engine.stats
-    base = (stats.tokens_generated, stats.steps, stats.occupancy_sum, engine.compiles.compile_count, loop.prefill_flops,
+    base = (stats.tokens_generated, stats.steps, stats.occupancy_sum, engine.compiles.compile_count, len(loop.contexts),
             stats.requests_preempted, stats.page_pressure_events, stats.requests_requeued)
+    opened = family_counters(family, engine)
     results, step_ms, tokens, context = [], [], 0, 0
     start = now()
     while now() - start < seconds:
@@ -66,12 +72,13 @@ def run_window(loop: ClosedLoop, seconds: float) -> dict:
     return {
         "results": results, "elapsed_s": elapsed, "engine_step_ms": step_ms,
         "tokens_emitted": engine.stats.tokens_generated - base[0], "decode_tokens": tokens,
+        "contexts": np.concatenate([np.zeros(0, np.int64), *loop.contexts[base[4]:]]),
         "decode_context_sum": context, "decode_steps": decode_steps,
         "occupancy": (engine.stats.occupancy_sum - base[2]) / max(decode_steps, 1),
         "compiles": engine.compiles.compile_count - base[3],
-        "prefill_flops": loop.prefill_flops - base[4],
         "preempted": stats.requests_preempted - base[5], "page_pressure": stats.page_pressure_events - base[6],
         "requeued": stats.requests_requeued - base[7],
+        "family": family_counters(family, engine, since=opened),
     }
 
 
@@ -90,15 +97,13 @@ def sample_rows(results: list, count: int, seed: int) -> list:
 def run(ctx) -> dict:
     import jax.numpy as jnp
 
-    from accelerate_tpu.models import Llama
-    from accelerate_tpu.models.config import TransformerConfig
     from accelerate_tpu.serving.engine import ServingEngine
 
-    cfg, mix = ctx.config, ctx.mix
+    cfg, mix, family = ctx.config, ctx.mix, ctx.family
     spans = Spans()
     dtype = jnp.dtype(mix["weights_dtype"])
-    model = Llama(TransformerConfig(**configs.transformer_fields(cfg)))
-    params = weights.llama_params(cfg, ctx.seed, dtype)
+    model = family.build(cfg)
+    params = family.params(cfg, ctx.seed, dtype)
     engine_args = {**mix["engine"], "buckets": tuple(mix["engine"]["buckets"])}
     engine = ServingEngine(model, params, **engine_args)
     kernels = engine.kernel_summary()
@@ -118,9 +123,9 @@ def run(ctx) -> dict:
 
     if ctx.trace:
         with traced_window(spans, ctx.trace_dir):
-            window = run_window(loop, min(ctx.seconds, mix["trace_seconds"]))
+            window = run_window(loop, min(ctx.seconds, mix["trace_seconds"]), family)
     else:
-        window = run_window(loop, ctx.seconds)
+        window = run_window(loop, ctx.seconds, family)
     results = window.pop("results")
     peak = memory_peak_bytes()
 
@@ -137,8 +142,8 @@ def run(ctx) -> dict:
     del loop, engine, params, model, results, finished
     gc.collect()
     closed = now()
-    checked = reference_llama.served_gaps(
-        cfg, ctx.seed, rows, dtype, mix["reference_row_block"], mix["engine"]["max_len"], mix["output_len"]["max"], ctx.control
+    checked = compare.served_gaps(
+        family.logits_at, cfg, ctx.seed, rows, dtype, mix["reference_row_block"], mix["engine"]["max_len"], mix["output_len"]["max"], ctx.control
     )
     notes = [
         f"note: {ctx.before_device_s:.1f} s to import jax and start the device, not counted; set-up {setup_s:.1f} s (weights and "

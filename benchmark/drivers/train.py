@@ -1,6 +1,7 @@
 """Driver of the training mixes: ``Accelerator`` -> ``prepare_model`` /
 ``prepare_optimizer`` -> ``compiled_step``, a fresh seeded batch put on the
-device every step."""
+device every step. What differs between model families (the model, its
+weights, loss and batches, its reference) is the family's, ``ctx.family``."""
 
 from __future__ import annotations
 
@@ -8,8 +9,10 @@ import gc
 
 import numpy as np
 
-from ..lib import compare, configs, reference_bert, traffic, weights
-from ..lib.harness import Spans, memory_peak_bytes, now, traced_window
+from ..lib import compare
+from ..lib.harness import Spans, family_counters, memory_peak_bytes, now, traced_window
+
+MASTERS = np.float32  # the type the weights from the seed are made and kept in
 
 
 def _adam_mu(opt_state):
@@ -28,22 +31,18 @@ def build(ctx):
     import optax
 
     from accelerate_tpu import Accelerator
-    from accelerate_tpu.models import Bert
-    from accelerate_tpu.models.config import TransformerConfig
 
-    cfg, mix = ctx.config, ctx.mix
+    cfg, mix, family = ctx.config, ctx.mix, ctx.family
     opt = mix["optimizer"]
     accelerator = Accelerator(mixed_precision=mix["mixed_precision"])
-    model = Bert(TransformerConfig(**configs.transformer_fields(cfg)))
-    prepared = accelerator.prepare_model(model, params=weights.bert_params(cfg, ctx.seed))
+    model = family.build(cfg)
+    prepared = accelerator.prepare_model(model, params=family.params(cfg, ctx.seed, MASTERS))
     schedule = optax.linear_schedule(opt["lr_init"], opt["lr_peak"], opt["warmup_steps"])
     optimizer = accelerator.prepare_optimizer(optax.adamw(
         schedule, b1=opt["b1"], b2=opt["b2"], eps=opt["eps"], weight_decay=opt["weight_decay"],
     ))
-    step = accelerator.compiled_step(Bert.loss_fn(model))
-    batches = traffic.classification_batches(
-        mix, cfg["vocab_size"], cfg["type_vocab_size"], cfg["assumed"]["num_labels"], ctx.seed
-    )
+    step = accelerator.compiled_step(family.loss_fn(model))
+    batches = family.batches(mix, cfg, ctx.seed)
     sharding = accelerator.state.data_sharding()
 
     def feed(index: int) -> dict:
@@ -65,10 +64,10 @@ def first_steps(ctx, prepared, optimizer, step, feed) -> dict:
         if grad_norms is None:
             # the gradient as the optimizer got it: mu after one step is (1 - b1) * g
             share = 1.0 - mix["optimizer"]["b1"]
-            grad_norms = {k: n / share for k, n in reference_bert.leaf_norms(_adam_mu(optimizer.opt_state)).items()}
+            grad_norms = {k: n / share for k, n in compare.leaf_norms(_adam_mu(optimizer.opt_state)).items()}
     # params after these steps live until the next step donates them
-    start = weights.bert_params(cfg, ctx.seed)
-    change = reference_bert.leaf_norms(jax.jit(lambda a, b: jax.tree.map(jnp.subtract, a, b))(prepared.params, start))
+    start = ctx.family.params(cfg, ctx.seed, MASTERS)
+    change = compare.leaf_norms(jax.jit(lambda a, b: jax.tree.map(jnp.subtract, a, b))(prepared.params, start))
     del start
     return {"losses": losses, "grad_norms": grad_norms, "change_norms": change}
 
@@ -98,7 +97,7 @@ def run(ctx) -> dict:
 
     from accelerate_tpu.telemetry import CompileTracker
 
-    cfg, mix = ctx.config, ctx.mix
+    cfg, mix, family = ctx.config, ctx.mix, ctx.family
     spans = Spans()
     accelerator, prepared, optimizer, step, batches, feed = build(ctx)
     built = now()
@@ -108,6 +107,7 @@ def run(ctx) -> dict:
     window = {"tokens_per_step": tokens_per_step, "seq_len": mix["seq_len"]}
 
     setup_s = now() - ctx.started
+    opened = family_counters(family, accelerator)
     with CompileTracker() as compiles:
         if ctx.trace:
             with traced_window(spans, ctx.trace_dir):
@@ -124,6 +124,7 @@ def run(ctx) -> dict:
         else:
             losses, elapsed = run_steps(step, feed, spans, done, ctx.seconds, mix["fence_every"])
         window["compiles"] = compiles.compile_count
+    window["family"] = family_counters(family, accelerator, since=opened)
     values = np.asarray(jnp.stack(losses), np.float64)
     steps = len(losses) - len(window.get("fenced_step_ms", ()))
     window.update(steps=steps, elapsed_s=elapsed, tokens_per_s=steps * tokens_per_step / elapsed)
@@ -136,9 +137,9 @@ def run(ctx) -> dict:
     gc.collect()
     closed = now()
     follow = (cfg, ctx.seed, batches[: mix["check_steps"]], mix["optimizer"], mix["reference_row_block"])
-    reference = reference_bert.first_steps(*follow)
+    reference = family.first_steps(*follow)
     if ctx.control:  # the reference one precision down, in the program's place
-        program = reference_bert.first_steps(*follow, control=True)
+        program = family.first_steps(*follow, control=True)
     notes = [
         f"note: {ctx.before_device_s:.1f} s to import jax and start the device, not counted; set-up {setup_s:.1f} s (weights and "
         f"the built step {built - ctx.started:.1f} s, first steps {setup_s - (built - ctx.started):.1f} s); "

@@ -1,21 +1,25 @@
 """The serving steps' share of the chip's peak: the forward operations the
-slice's work needs (2 per matmul parameter and token, plus attention from the
-requests' own lengths; the prompts of the requests submitted in the slice, the
-tokens decoded in it at their live context lengths) per second, over chips
-times the bf16 peak. Padding to prefill buckets is not counted: it is not
-needed work."""
+slice's work needs per second, over chips times the bf16 peak. The work is
+counted from what ran in the slice, by the family's ``forward_flops``: every
+prefill program it dispatched (the program's ``engine.prefill_dispatch``
+spans: ``tokens`` real tokens after ``position`` cached ones; padding to the
+bucket is not needed work and is not counted) and every token decoded in it at
+its live context length. Nothing to read where the program keeps no step
+spans: what it prefilled is then not known."""
 
-from benchmark.lib import work
+import numpy as np
+
+from benchmark.lib import program_spans
 
 
 def read(reading):
     window = reading["window"]
-    if reading["peaks"] is None or not window.get("decode_tokens"):
+    steps = program_spans.slice_steps("engine.step")
+    if reading["peaks"] is None or not window.get("decode_tokens") or not steps:
         return None
-    cfg = reading["config"]
-    heads_dim = cfg["num_attention_heads"] * cfg["head_dim"] * cfg["num_hidden_layers"]
-    decode = 2.0 * work.llama_matmul_params(cfg) * window["decode_tokens"] + 4.0 * heads_dim * (
-        window["decode_context_sum"] + window["decode_tokens"]
-    )
-    per_second = (window["prefill_flops"] + decode) / window["elapsed_s"]
+    cfg, flops = reading["config"], reading["family"].forward_flops
+    prefill = sum(flops(cfg, ids["position"], ids["tokens"]) for ids in program_spans.prefill_programs(steps))
+    lengths, counts = np.unique(window["contexts"], return_counts=True)
+    decode = sum(int(n) * flops(cfg, int(length), 1) for length, n in zip(lengths, counts))
+    per_second = (prefill + decode) / window["elapsed_s"]
     return 100.0 * per_second / (reading["cell"]["chips"] * reading["peaks"]["bf16_flops_per_s"])

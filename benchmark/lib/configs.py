@@ -1,11 +1,14 @@
-"""From a configuration's file (the source's own ``config.json`` keys) to the
-arguments of the program's ``TransformerConfig``. The mapping is the
-benchmark's own, so the program's ``config_from_hf_json`` may change."""
+"""Files found by name alone (data files, readers, family files), and from a
+configuration's file (the source's own ``config.json`` keys) to the arguments
+of the program's ``TransformerConfig``. The mapping is the benchmark's own,
+so the program's ``config_from_hf_json`` may change."""
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
+import re
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -17,11 +20,31 @@ def load_json(kind: str, name: str) -> dict:
         return json.load(f)
 
 
+def load_module(kind: str, name: str):
+    """``benchmark/<kind>/<name>.py`` as a module of its own. A name may hold
+    dots and dashes, so the file is loaded by its path; one that is not there
+    is an error that names it."""
+    path = os.path.join(BENCH_DIR, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"benchmark/{kind}/{name}.py is missing (looked for {path})")
+    module_spec = importlib.util.spec_from_file_location(f"benchmark.{kind}." + re.sub(r"[.\-]", "_", name), path)
+    module = importlib.util.module_from_spec(module_spec)
+    module_spec.loader.exec_module(module)
+    return module
+
+
 def model_config(name: str, rehearse: bool = False) -> dict:
     cfg = load_json("configs", name)
     if rehearse:
         cfg = {**cfg, **cfg["rehearse"]}
     return cfg
+
+
+def family(cfg: dict):
+    """``benchmark/families/<model_type>.py``: whatever the benchmark does per
+    model family, found by the source's own ``model_type`` (what a family's
+    file gives is listed in ``benchmark/families/__init__.py``)."""
+    return load_module("families", cfg["model_type"])
 
 
 def transformer_fields(cfg: dict) -> dict:

@@ -1,5 +1,5 @@
-"""What both drivers share: host spans, the profiler's start and stop and
-the device's memory peak."""
+"""What both drivers share: host spans, the profiler's start and stop, the
+device's memory peak and the family's counters."""
 
 from __future__ import annotations
 
@@ -52,6 +52,15 @@ def memory_peak_bytes() -> int:
 
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.local_devices()]
     return int(max(peaks))
+
+
+def family_counters(family, program, since: dict | None = None) -> dict:
+    """The always-on counters that the family's file reads from ``program``
+    (its optional ``counters``; nothing where it has none) or, with ``since``,
+    what each has grown by."""
+    read = getattr(family, "counters", None)
+    counted = dict(read(program)) if read is not None else {}
+    return counted if since is None else {name: value - since[name] for name, value in counted.items()}
 
 
 now = time.perf_counter

@@ -98,7 +98,13 @@ def fetch_ms(engine_steps, with_prefill: bool) -> list[float]:
     ]
 
 
+def prefill_programs(engine_steps) -> list[dict]:
+    """The ids of each prefill program the slice dispatched: ``tokens`` real
+    tokens after ``position`` cached ones, in a bucket of ``span`` positions."""
+    return [s[IDS] for step in engine_steps for s in step["under"].get("engine.prefill_dispatch", ())]
+
+
 def prefill_positions(engine_steps) -> tuple[int, int]:
     """(prompt tokens, bucket positions) over the slice's prefill programs."""
-    programs = [s for step in engine_steps for s in step["under"].get("engine.prefill_dispatch", ())]
-    return sum(s[IDS]["tokens"] for s in programs), sum(s[IDS]["span"] for s in programs)
+    programs = prefill_programs(engine_steps)
+    return sum(ids["tokens"] for ids in programs), sum(ids["span"] for ids in programs)

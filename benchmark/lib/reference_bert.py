@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from . import weights
+from .compare import leaf_norms
 
 PRECISION = "highest"
 
@@ -74,15 +75,6 @@ def learning_rate(opt: dict, count: int) -> float:
     then constant; ``count`` is 0 at the first step."""
     share = min(count / opt["warmup_steps"], 1.0)
     return opt["lr_init"] + (opt["lr_peak"] - opt["lr_init"]) * share
-
-
-def leaf_norms(tree) -> dict[str, float]:
-    """L2 norm of every leaf, by its path."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    norms = jax.jit(lambda leaves: [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))) for x in leaves])(
-        [leaf for _, leaf in flat]
-    )
-    return {jax.tree_util.keystr(path): float(n) for (path, _), n in zip(flat, norms)}
 
 
 def first_steps(cfg: dict, seed: int, batches: list[dict], opt: dict, row_block: int,

@@ -91,41 +91,3 @@ def logits_at(cfg: dict, seed: int, ids: np.ndarray, positions: np.ndarray, dtyp
         for index in range(cfg["num_hidden_layers"]):
             h = layer(key, h, jnp.int32(index))
         return np.asarray(head(key, h, jnp.asarray(positions)))
-
-
-def served_gaps(cfg: dict, seed: int, rows: list[tuple[np.ndarray, np.ndarray]], dtype, row_block: int,
-                pad_to: int, pad_outputs: int, control: bool = False) -> dict:
-    """For each (prompt, served tokens) row, at each served position, the gap
-    by which the served token's reference logit lies below the reference's
-    best. With ``control``, the token judged at each position is the one the
-    int8 control puts first there, not the served one. Returns the widest gap
-    and where it is, the mean gap, the share of tokens that are the
-    reference's own first choice, and how many tokens were compared. Every
-    block has the one shape [row_block, pad_to] with ``pad_outputs`` positions
-    read, so that the reference's programs compile once per checkout."""
-    gaps, widest, where = [], 0.0, None
-    for lo in range(0, len(rows), row_block):
-        block = rows[lo:lo + row_block]
-        members = list(range(lo, lo + len(block)))
-        ids = np.zeros((row_block, pad_to), np.int32)
-        positions = np.zeros((row_block, pad_outputs), np.int32)
-        for r, (prompt, generated) in enumerate(block):
-            ids[r, : prompt.size] = prompt
-            ids[r, prompt.size : prompt.size + generated.size - 1] = generated[:-1]
-            positions[r, : generated.size] = prompt.size - 1 + np.arange(generated.size)
-        reference = logits_at(cfg, seed, ids, positions, dtype)
-        judged = logits_at(cfg, seed, ids, positions, dtype, control=True).argmax(-1) if control else None
-        for r, (prompt, generated) in enumerate(block):
-            tokens = judged[r, : generated.size] if control else generated
-            steps = np.arange(generated.size)
-            row_gaps = reference[r, steps].max(-1) - reference[r, steps, tokens]
-            gaps.extend(row_gaps.tolist())
-            if row_gaps.size and not row_gaps.max() <= widest:
-                at = int(row_gaps.argmax())
-                widest, where = float(row_gaps.max()), {"row": members[r], "token": at, "prompt_len": int(prompt.size)}
-    if not gaps:
-        return {"logit_gap_max": float("nan"), "logit_gap_mean": float("nan"), "where": None, "tokens_compared": 0, "agree": 0.0}
-    return {
-        "logit_gap_max": widest, "logit_gap_mean": float(np.mean(gaps)), "where": where,
-        "tokens_compared": len(gaps), "agree": float(np.mean(np.asarray(gaps) == 0.0)),
-    }

@@ -3,10 +3,15 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One process, one cell, one run. Everything that belongs to a cell is found by
-the names in ``BENCHMARK.json``: ``configs/<config>.json``,
-``traffic/<mix>.json`` (whose ``driver`` names ``drivers/<driver>.py``),
-``workloads/<cell>.json`` (the limits of ``correct``) and
-``layer_metrics/<metric>.py``. The last line of standard output is the result.
+the names in ``BENCHMARK.json``: ``configs/<config>.json`` (whose
+``model_type`` names ``families/<model_type>.py``: the program's model and its
+weights from the seed, the plain reference with its control, the operations
+and bytes the work needs, and optional counters; ``families/__init__.py`` lists
+what a family's file gives), ``traffic/<mix>.json`` (whose ``driver`` names
+``drivers/<driver>.py``), ``workloads/<cell>.json`` (the limits of
+``correct``) and ``layer_metrics/<metric>.py``. Nothing here, in the drivers
+or in the readers names a model: a configuration of a new family comes in
+with files alone. The last line of standard output is the result.
 
 ``--rehearse`` runs the same path at tiny widths on whatever backend there is
 and names it in the result: a rehearsal of the control flow, never a
@@ -21,7 +26,6 @@ STARTED = time.perf_counter()
 
 import argparse
 import importlib
-import importlib.util
 import json
 import os
 import shutil
@@ -53,11 +57,9 @@ def metrics_of(spec: dict, group: str, cell: str) -> list[dict]:
 def read_layer_metric(name: str, reading: dict):
     """``layer_metrics/<name>.py``'s ``read``: the metric's value, or None
     where it finds nothing to read."""
-    path = os.path.join(BENCH_DIR, "layer_metrics", f"{name}.py")
-    module_spec = importlib.util.spec_from_file_location("benchmark.layer_metrics." + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(module_spec)
-    module_spec.loader.exec_module(module)
-    return module.read(reading)
+    from benchmark.lib import configs
+
+    return configs.load_module("layer_metrics", name).read(reading)
 
 
 def main(argv=None) -> int:
@@ -98,8 +100,9 @@ def main(argv=None) -> int:
     limits = configs.load_json("workloads", args.workload)["rehearse_limits" if args.rehearse else "limits"]
     if args.rehearse:
         mix = {**mix, **mix["rehearse"]}
+    config = configs.model_config(cell["config"], args.rehearse)
     ctx = types.SimpleNamespace(
-        cell=cell, config=configs.model_config(cell["config"], args.rehearse), mix=mix, seed=args.seed,
+        cell=cell, config=config, family=configs.family(config), mix=mix, seed=args.seed,
         seconds=args.seconds, trace=bool(args.trace), rehearse=args.rehearse, control=args.control,
         started=device_up, before_device_s=device_up - STARTED, trace_dir=os.path.join(ROOT, ".bench_trace", args.workload),
         on_chip=device.platform == "tpu",
@@ -120,7 +123,7 @@ def main(argv=None) -> int:
             shutil.rmtree(ctx.trace_dir, ignore_errors=True)
         device_line.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         reading = {
-            "cell": cell, "config": ctx.config, "mix": mix, "window": out["window"], "trace": reduced,
+            "cell": cell, "config": config, "family": ctx.family, "mix": mix, "window": out["window"], "trace": reduced,
             "peaks": peaks.peaks_for(device.device_kind) if ctx.on_chip else None,
         }
         metrics = {}

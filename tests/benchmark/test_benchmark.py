@@ -19,16 +19,19 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark.lib import configs, stats, trace, traffic, work  # noqa: E402
+from benchmark import run  # noqa: E402
+from benchmark.lib import configs, peaks, stats, trace, traffic, work  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-CELLS = ["bert-large.finetune", "mistral-7b.serve-chat"]
 
 
-def spec() -> dict:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def spec(root=ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+CELLS = [cell["name"] for cell in spec()["workloads"]]  # every cell that is there is rehearsed
 
 
 def run_py(args, cwd=ROOT, script=("benchmark/run.py",)):
@@ -47,8 +50,9 @@ def rehearse(cell, *extra, **kwargs):
     return run_py(["--workload", cell, "--seed", str(2**31 + 11), "--seconds", "1", "--rehearse", *extra], **kwargs)
 
 
-def test_benchmark_json_keeps_to_the_contract_and_every_file_is_found_by_name():
-    b = spec()
+def contract_holds(root):
+    """``root``'s ``BENCHMARK.json`` and the files under its ``benchmark/``, with ``configs.BENCH_DIR`` there."""
+    b = spec(root)
     assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
     names = [x["name"] for group in ("configs", "workloads", "end_to_end", "per_layer") for x in b[group]]
     assert all(NAME.match(n) for n in names) and len(set(names)) == len(names)
@@ -62,15 +66,20 @@ def test_benchmark_json_keeps_to_the_contract_and_every_file_is_found_by_name():
     for cell in b["workloads"]:
         assert cell["chips"] in (1, 4) and len(cell["why"]) <= 200
         mix = configs.load_json("traffic", cell["traffic"])
-        assert os.path.exists(os.path.join(ROOT, "benchmark", "drivers", mix["driver"] + ".py"))
+        assert os.path.exists(os.path.join(root, "benchmark", "drivers", mix["driver"] + ".py"))
         assert set(configs.load_json("workloads", cell["name"])["limits"])
-        assert configs.transformer_fields(configs.model_config(cell["config"]))["hidden_size"] >= 1024
+        config = configs.model_config(cell["config"])
+        assert configs.family(config).widths(config)["hidden_size"] >= 1024
     for metric in b["per_layer"]:
-        assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics", metric["name"] + ".py"))
+        assert os.path.exists(os.path.join(root, "benchmark", "layer_metrics", metric["name"] + ".py"))
         # every cell that reports the metric reports the end-to-end metric it moves
         moved = end_to_end[metric["moves"]]
         assert set(metric["workloads"]) <= set(moved.get("workloads", [c["name"] for c in b["workloads"]]))
         assert "mfu" not in metric["name"] and "roofline" not in metric["name"] or metric["unit"] == "%"
+
+
+def test_benchmark_json_keeps_to_the_contract_and_every_file_is_found_by_name():
+    contract_holds(ROOT)
 
 
 def test_trace_reduction_on_hand_made_intervals():
@@ -185,8 +194,9 @@ def test_the_low_precision_control_comes_out_not_correct(cell):
     assert any(v["value"] > v["limit"] for v in result["compared"].values())
 
 
-@pytest.mark.parametrize("fault,cell", [
-    ("state_unchanged", CELLS[0]), ("half_batch", CELLS[0]), ("altered_token", CELLS[1]),
+@pytest.mark.parametrize("fault,cell", [  # each fault patches one family's program: tied to the cell it names
+    ("state_unchanged", "bert-large.finetune"), ("half_batch", "bert-large.finetune"),
+    ("altered_token", "mistral-7b.serve-chat"),
 ])
 def test_a_fault_under_the_timed_path_comes_out_not_correct(fault, cell):
     code, result, err = rehearse(cell, "--trace", "0", script=("tests/benchmark/faults.py", fault, "--"))
@@ -218,3 +228,117 @@ def test_a_cell_is_added_with_files_alone_and_the_bare_benchmark_refuses_to_run(
     code, result, err = rehearse("bert-large.b2", "--trace", "1", cwd=tmp_path)
     assert code == 0, err
     assert result["correct"] is True and result["metrics"]["steps.train"]["value"] > 0
+
+
+def hand_made_reading(family, config, contexts):
+    """What a reader gets: a traced slice of 1 us in which ``paged_attention`` ran for 250 ns, on a v5e."""
+    E = trace.Event
+    events = [E("bench.window", 0, 1000, trace.HOST_PLANE, "python3"), E("paged_attention", 150, 250, "/device:TPU:0", "XLA Ops")]
+    contexts = np.asarray(contexts)
+    window = {"contexts": contexts, "decode_tokens": int(contexts.size), "decode_context_sum": int(contexts.sum()), "elapsed_s": 1e-6}
+    return {"cell": {"chips": 1}, "config": config, "family": family, "window": window,
+            "trace": trace.reduce_trace(events), "peaks": peaks.peaks_for("TPU v5 lite")}
+
+
+def test_the_families_count_what_the_formulas_they_replaced_counted():
+    mistral, bert = configs.model_config("mistral-7b-v0.3"), configs.model_config("bert-large")
+    family = configs.family(mistral)
+    assert family.widths(mistral) == {"hidden_size": 4096, "intermediate_size": 14336, "head_dim": 128}
+    assert work.decode_attention_bytes(mistral, 1000) == 65_536_000
+    # without windows the bytes follow from the sum alone, however it is made up
+    assert all(family.decode_attention_bytes(mistral, c) == 65_536_000 for c in ([1000], [400, 350, 250], np.full(8, 125)))
+    assert family.forward_flops(mistral, 10, 3) == work.llama_forward_flops(mistral, 10, 3)
+    reading = hand_made_reading(family, mistral, [400, 350, 250])
+    assert run.read_layer_metric("paged_attention_roofline.serve", reading) == 100.0 * (65_536_000 / 819e9) / 250e-9
+    trained = configs.family(bert)
+    assert trained.widths(bert)["hidden_size"] == 1024
+    assert trained.train_flops_per_token(bert, 128) == work.bert_train_flops_per_token(bert, 128)
+    reading = {**reading, "config": bert, "family": trained, "window": {"seq_len": 128, "tokens_per_s": 50_000.0}}
+    assert run.read_layer_metric("step_mfu.train", reading) == 100.0 * 50_000.0 * (6 * 335_143_938 + 12 * 24 * 1024 * 128) / 197e12
+
+
+def test_an_unknown_model_type_fails_by_naming_the_file_that_is_missing():
+    with pytest.raises(FileNotFoundError, match=r"benchmark/families/no-such_family\.py is missing"):
+        configs.family({"model_type": "no-such_family"})
+
+
+THROWAWAY_FAMILY = '''"""The mistral glue under another model_type, with half the bytes and one counter."""
+from benchmark.lib import configs
+
+mistral = configs.family({"model_type": "mistral"})
+widths, params, logits_at, forward_flops = mistral.widths, mistral.params, mistral.logits_at, mistral.forward_flops
+
+
+def build(cfg):
+    return mistral.build({**cfg, "model_type": "mistral"})
+
+
+def decode_attention_bytes(cfg, contexts):
+    return mistral.decode_attention_bytes(cfg, contexts) // 2
+
+
+def counters(engine):
+    return {"engine_steps": engine.stats.steps}
+'''
+# throwaway readers, each one number of what a reader is handed
+THROWAWAY_READERS = {
+    "family_bytes.serve": "reading['family'].decode_attention_bytes(reading['config'], reading['window']['contexts'])",
+    "whole_bytes.serve": "work.decode_attention_bytes(reading['config'], reading['window']['decode_context_sum'])",
+    "contexts_sum.serve": "int(reading['window']['contexts'].sum())",
+    "context_sum.serve": "reading['window']['decode_context_sum']",
+    "contexts_count.serve": "int(reading['window']['contexts'].size)",
+    "decode_tokens.serve": "reading['window']['decode_tokens']",
+    "steps_counted.serve": "reading['window']['family']['engine_steps']",
+    "decode_steps.serve": "reading['window']['decode_steps']",
+}
+
+
+@pytest.fixture(scope="module")
+def throwaway_family(tmp_path_factory):
+    """A copy of the benchmark with a family, a configuration, a mix, a cell and readers of its own: new files
+    and new entries, no edit to a file that is there. Returns (the copy's root, its cell's traced rehearsal)."""
+    root = tmp_path_factory.mktemp("family")
+    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark", ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(ROOT, "accelerate_tpu"), root / "accelerate_tpu")
+    b = spec()
+    (root / "benchmark/families/halfway.py").write_text(THROWAWAY_FAMILY)
+    config = {**configs.load_json("configs", "mistral-7b-v0.3"), "name": "halfway-7b", "model_type": "halfway"}
+    (root / "benchmark/configs/halfway-7b.json").write_text(json.dumps(config))
+    shutil.copy(root / "benchmark/traffic/chat-closed-32.json", root / "benchmark/traffic/chat-halfway.json")
+    shutil.copy(root / "benchmark/workloads/mistral-7b.serve-chat.json", root / "benchmark/workloads/halfway-7b.serve-chat.json")
+    b["configs"].append({"name": "halfway-7b", "source": config["source"], "file": "benchmark/configs/halfway-7b.json",
+                         "reduced": config["reduced"], "why": "throwaway"})
+    b["workloads"].append({"name": "halfway-7b.serve-chat", "config": "halfway-7b", "traffic": "chat-halfway", "chips": 1, "why": "throwaway"})
+    for metric in b["end_to_end"]:
+        if metric["name"] in ("serve_tokens_per_s", "tpot_p95_ms"):
+            metric["workloads"].append("halfway-7b.serve-chat")
+    for name, expression in THROWAWAY_READERS.items():
+        (root / f"benchmark/layer_metrics/{name}.py").write_text(
+            f"from benchmark.lib import work\n\n\ndef read(reading):\n    return {expression}\n")
+        b["per_layer"].append({"name": name, "unit": "count", "better": "higher", "source": "program_counter",
+                               "layer": "serving engine", "moves": "serve_tokens_per_s", "workloads": ["halfway-7b.serve-chat"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    code, result, err = rehearse("halfway-7b.serve-chat", "--trace", "1", cwd=root)
+    assert code == 0, err
+    return root, result
+
+
+def test_a_family_is_added_with_files_alone(throwaway_family, monkeypatch):
+    root, result = throwaway_family
+    assert result["correct"] is True and result["attempted"] > 0 and result["failed"] == 0
+    read = {name: result["metrics"][name]["value"] for name in THROWAWAY_READERS}
+    # the reader's input is the family's: half of what the lengths' sum gives for full attention
+    assert read["whole_bytes.serve"] > 0 and read["family_bytes.serve"] == read["whole_bytes.serve"] // 2
+    # the counter's difference over the window, under window["family"]
+    assert read["steps_counted.serve"] == read["decode_steps.serve"] > 0
+    # the contract test's loop, on the copy, and the roofline reader with the copy's family in its hand
+    monkeypatch.setattr(configs, "BENCH_DIR", str(root / "benchmark"))
+    contract_holds(str(root))
+    config = configs.model_config("halfway-7b")
+    halved = run.read_layer_metric("paged_attention_roofline.serve", hand_made_reading(configs.family(config), config, [400, 350, 250]))
+    assert halved == 100.0 * (32_768_000 / 819e9) / 250e-9
+
+
+def test_the_contexts_of_a_rehearsed_window_sum_to_its_decode_context_sum(throwaway_family):
+    read = {name: m["value"] for name, m in throwaway_family[1]["metrics"].items()}
+    assert read["contexts_sum.serve"] == read["context_sum.serve"] > read["contexts_count.serve"] == read["decode_tokens.serve"] > 0

@@ -14,8 +14,10 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+import numpy as np  # noqa: E402
+
 from benchmark import run  # noqa: E402
-from benchmark.lib import program_spans  # noqa: E402
+from benchmark.lib import configs, peaks, program_spans, work  # noqa: E402
 
 NEW = {
     "mistral-7b.serve-chat": ["dispatch_gap_ms_p50.serve", "decode_fetch_ms_p50.serve", "prefill_extra_ms_p50.serve",
@@ -81,7 +83,7 @@ def fresh_slice():
         program_spans.slice_steps.cache_clear()
 
     forget()
-    yield
+    yield forget
     forget()
 
 
@@ -96,6 +98,38 @@ def test_the_readers_read_the_programs_ring_and_say_what_they_found(monkeypatch,
     }
     notes = [line for line in capsys.readouterr().err.splitlines() if line.startswith("note: program spans")]
     assert len(notes) == 1 and "engine.step 3, 0.1040, 0.0070" in notes[0] and "train.dispatch 1, 0.0030, 0.0030" in notes[0]
+
+
+def test_the_steps_share_counts_the_prefills_the_slice_dispatched_and_its_decodes_as_the_old_formula_did(monkeypatch, fresh_slice):
+    """``step_mfu.serve``: prefill work from the slice's ``engine.prefill_dispatch`` spans, decode work from the
+    contexts, both through the family; the decode part is the formula it replaced, to the last digit."""
+    from accelerate_tpu.telemetry import profiler
+
+    cfg = configs.model_config("mistral-7b-v0.3")
+    contexts = np.array([40, 32, 41, 33, 1279, 33])
+    window = {"contexts": contexts, "decode_tokens": 6, "decode_context_sum": int(contexts.sum()), "elapsed_s": 0.107}
+    reading = {"cell": {"chips": 1}, "config": cfg, "family": configs.family(cfg), "window": window,
+               "peaks": peaks.peaks_for("TPU v5 lite")}
+    heads_dim = cfg["num_attention_heads"] * cfg["head_dim"] * cfg["num_hidden_layers"]
+    old_decode = 2.0 * work.llama_matmul_params(cfg) * 6 + 4.0 * heads_dim * (window["decode_context_sum"] + 6)
+    share = lambda flops: 100.0 * (flops / 0.107) / (1 * 197e12)
+    # steps 2 and 3 alone dispatched no prefill: the decode part and nothing else
+    monkeypatch.setattr(profiler, "recorded", lambda: [profiler.Span(*s) for s in hand_made_slice()[7:13]])
+    assert run.read_layer_metric("step_mfu.serve", reading) == share(old_decode)
+    # the whole slice: 40 and 32 real tokens prefilled from position 0, the buckets' padding not counted
+    fresh_slice()
+    monkeypatch.setattr(profiler, "recorded", lambda: [profiler.Span(*s) for s in hand_made_slice()])
+    prefill = work.llama_forward_flops(cfg, 0, 40) + work.llama_forward_flops(cfg, 0, 32)
+    assert run.read_layer_metric("step_mfu.serve", reading) == share(prefill + old_decode)
+    # a chunk that follows 128 cached tokens attends to them
+    chunk = (13, 10, "engine.prefill_dispatch", 79 * MS, 79 * MS + 1, {"request": 9, "span": 64, "tokens": 50, "position": 128})
+    fresh_slice()
+    monkeypatch.setattr(profiler, "recorded", lambda: [profiler.Span(*s) for s in hand_made_slice() + [chunk]])
+    assert run.read_layer_metric("step_mfu.serve", reading) == share(prefill + work.llama_forward_flops(cfg, 128, 50) + old_decode)
+    # a program that keeps no spans: what it prefilled is not known, and the reader says nothing
+    fresh_slice()
+    monkeypatch.setattr(profiler, "recorded", lambda: [])
+    assert run.read_layer_metric("step_mfu.serve", reading) is None
 
 
 @pytest.mark.parametrize("program", ["keeps_no_spans", "ring_is_empty", "slice_without_plain_steps"])
