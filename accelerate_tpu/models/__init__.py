@@ -1,6 +1,7 @@
 from .attention import dot_product_attention, rotary_embedding
 from .bert import Bert
 from .config import TransformerConfig, get_config, list_models, param_count, register_config
+from .exaone_moe import ExaoneMoe
 from .generation import generate
 from .gpt2 import GPT2
 from .llama import Llama
@@ -8,7 +9,7 @@ from .moe import MoEBlock
 from .t5 import T5
 
 
-_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5}
+_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5, "exaone_moe": ExaoneMoe}
 
 
 def build_model(name: str):

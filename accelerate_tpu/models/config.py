@@ -16,7 +16,7 @@ from typing import Optional
 class TransformerConfig:
     """One config for both decoder (llama-style) and encoder (bert-style) stacks."""
 
-    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5"
+    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5" | "exaone_moe"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -37,6 +37,20 @@ class TransformerConfig:
     num_experts: int = 1
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # per-layer pattern (arch "exaone_moe", models/exaone_moe.py): the kind of
+    # attention ("sliding_attention" | "full_attention") and of MLP ("dense" |
+    # "sparse") of every layer, the window of the sliding layers, the routed
+    # experts' width beside the dense layers' ``intermediate_size``, and the
+    # share of the ``num_experts`` routed experts that this chip holds as
+    # (first expert, count); None = all of them. ``num_experts`` stays the
+    # router's width and ``moe_top_k`` the experts a token
+    layer_types: tuple = ()
+    mlp_layer_types: tuple = ()
+    sliding_window: Optional[int] = None
+    moe_intermediate_size: Optional[int] = None
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[tuple] = None
     # encoder-decoder (t5) extras: relative-position bias bucketing and the
     # decoder's BOS (t5 starts generation from the pad token)
     rel_buckets: int = 32

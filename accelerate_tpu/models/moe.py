@@ -19,10 +19,22 @@ Design notes (MXU/ICI-first):
 - Tokens over capacity are *dropped* (their combine weight is zero) exactly
   as in Switch/GShard; the auxiliary load-balance loss keeps the router from
   collapsing onto few experts.
+
+Two expert paths live here. ``routed_mlp`` (above: capacity dispatch, softmax
+scores, ungated GELU experts; callers ``MoEBlock`` and ``models/llama.py``'s
+MoE decoder, the training path). ``dropless_experts`` (below: sigmoid scores,
+top-k of all the router's experts, gated SiLU experts as one grouped matrix
+product over the assignments sorted by expert, no token ever dropped, and a
+*share*: the layer is told which experts it holds as (first, count), routes
+over all of them and computes the part of the result its own give; caller
+``models/exaone_moe.py``, the serving path, a prefill span's many tokens and a
+decode step's few alike). On one chip the share runs without its exchange:
+what the absent experts would add is left out.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -182,3 +194,121 @@ def _constrain_expert(value: jax.Array) -> jax.Array:
         return value
     sharding = NamedSharding(mesh, P(MESH_AXIS_EXPERT, *([None] * (value.ndim - 1))))
     return jax.lax.with_sharding_constraint(value, sharding)
+
+
+# -- dropless routed experts over a held share ---------------------------------
+
+
+def sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, scaling: float):
+    """Route ``x`` [T, H] over ALL of the router's experts: scores
+    ``sigmoid(x . W_r)`` in float32, the chosen set the ``top_k`` of ``score +
+    bias`` (the bias picks, it does not weigh), weights the chosen scores
+    normalised to one and scaled. Returns (experts [T, k] int32, weights
+    [T, k] float32)."""
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(x.astype(jnp.float32) @ router.astype(jnp.float32))
+        _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weights
+
+
+# rows a grouped product takes at a time: the sorted assignments go through the
+# held experts in chunks of this many, and only the chunks that hold a held
+# expert's assignment run. On a v5e a chunk's products are near the ridge (an
+# expert's 75 MB of weights stream in the time 256 rows take on the MXU), and
+# the cost of a launch grows with its rows whether or not they are filled:
+# 16 experts of 3 x 6144 x 2048 read 2.48 ms over 128 filled rows of a chunk,
+# 3.93 ms over all 1,024 rows of a decode step at once (PERF.md, PR 30)
+CHUNK_ROWS = 256
+
+
+def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int):
+    """The held experts' part of the routed result. ``chosen``/``weights``
+    [T, k] over all experts; ``w_*`` hold experts ``first .. first + count``.
+    Every assignment on a held expert is computed, whatever the imbalance: the
+    T*k assignments are sorted by held expert (the others behind them), and
+    the gated SiLU MLP runs over them, ``CHUNK_ROWS`` at a time, as three
+    grouped matrix products whose group sizes are the held experts' loads
+    within the chunk; as many chunks run as the held assignments fill. Returns
+    (y [T, H], held [T, count] int32: 1 where the token chose that held expert)."""
+    t, k = chosen.shape
+    count = w_gate.shape[0]
+    local = chosen - first
+    on_held = (local >= 0) & (local < count)
+    group = jnp.where(on_held, local, count).reshape(-1)  # the absent experts' assignments sort last
+    order = jnp.argsort(group, stable=True)
+    held = jnp.sum(jax.nn.one_hot(local, count, dtype=jnp.int32), axis=1)  # an expert held elsewhere: a row of noughts
+    loads = held.sum(0)
+    ends = jnp.cumsum(loads)
+    starts, total = ends - loads, ends[-1]
+    chunk = min(t * k, CHUNK_ROWS)
+    pad = -(t * k) % chunk
+    token = jnp.pad(order // k, (0, pad))
+    weight = jnp.pad(jnp.where(on_held, weights, 0.0).reshape(-1)[order], (0, pad))
+
+    def one_chunk(i, y):
+        lo = i * chunk
+        rows_of = jax.lax.dynamic_slice_in_dim(token, lo, chunk)
+        sizes = jnp.clip(ends - lo, 0, chunk) - jnp.clip(starts - lo, 0, chunk)
+        with jax.named_scope("moe.experts"):
+            rows = jnp.take(x, rows_of, axis=0)
+            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) * jax.lax.ragged_dot(rows, w_up, sizes)
+            out = jax.lax.ragged_dot(hidden.astype(x.dtype), w_down, sizes)
+        # rows past the held assignments belong to no group: whatever the
+        # grouped product left there must not reach a token, not even times zero
+        live = lo + jnp.arange(chunk) < total
+        scale = jax.lax.dynamic_slice_in_dim(weight, lo, chunk)
+        return y.at[rows_of].add(jnp.where(live[:, None], out.astype(jnp.float32) * scale[:, None], 0.0))
+
+    y = jax.lax.fori_loop(0, (total + chunk - 1) // chunk, one_chunk, jnp.zeros((t, x.shape[-1]), jnp.float32))
+    return y.astype(x.dtype), held
+
+
+@functools.lru_cache(maxsize=None)
+def _dropless(top_k: int, scaling: float, first: int):
+    """``dropless_experts`` for one (top_k, scaling, first expert), as a
+    function whose batching rule folds a mapped axis into the tokens: the
+    serving engine maps its decode step over slots (one token a slot), and
+    under that ``vmap`` the experts must see the step's tokens of all slots as
+    ONE batch, each held expert's weights read once, not gathered a slot."""
+
+    @jax.custom_batching.custom_vmap
+    def experts(x, router, bias, w_gate, w_up, w_down):
+        chosen, weights = sigmoid_topk(x, router, bias, top_k, scaling)
+        return _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first)
+
+    @experts.def_vmap
+    def experts_over_slots(axis_size, in_batched, x, router, bias, w_gate, w_up, w_down):
+        if any(in_batched[1:]):
+            raise NotImplementedError("dropless experts batch tokens over ONE set of weights")
+        t = x.shape[1]
+        y, held = experts(x.reshape(axis_size * t, x.shape[-1]), router, bias, w_gate, w_up, w_down)
+        return (y.reshape(axis_size, t, -1), held.reshape(axis_size, t, -1)), (True, True)
+
+    return experts
+
+
+def dropless_experts(
+    x: jax.Array,  # [T, H]
+    router: jax.Array,  # [H, E]: every expert of the layer, held here or not
+    bias: jax.Array,  # [E]: per-expert selection bias
+    w_gate: jax.Array,  # [count, H, F]: the held experts
+    w_up: jax.Array,  # [count, H, F]
+    w_down: jax.Array,  # [count, F, H]
+    top_k: int,
+    scaling: float = 1.0,
+    first: int = 0,
+) -> tuple[jax.Array, jax.Array]:
+    """Dropless routed experts over a held share: routes over all ``E``
+    experts and returns what the ``count`` held ones (``first .. first +
+    count``) add, ``sum over e in chosen and held of weight_e * E_e(x)``, and
+    ``held`` [T, count] int32, 1 where a token chose that held expert (the
+    loads and the counters are sums of it)."""
+    if top_k > router.shape[-1]:
+        raise ValueError(f"top_k={top_k} > num_experts={router.shape[-1]}")
+    if first < 0 or first + w_gate.shape[0] > router.shape[-1]:
+        raise ValueError(
+            f"held experts {first}..{first + w_gate.shape[0]} lie outside the router's {router.shape[-1]}"
+        )
+    return _dropless(int(top_k), float(scaling), int(first))(x, router, bias, w_gate, w_up, w_down)

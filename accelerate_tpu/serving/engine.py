@@ -13,7 +13,16 @@ multiplex through it via the slot cache —
   prompt's pages are prefilled once and reference-counted (COW) across every
   concurrent request, and admission is gated on free pages. ``paged=False``
   keeps the original per-slot slab (``kv_cache.py``) as the bit-equal
-  comparison baseline;
+  comparison baseline. A model whose layers are not all of one kind
+  (``models/exaone_moe.py``: it gives ``init_window_cache``) gets TWO kinds
+  of cached layer in the one manager: its full layers in the page pool, its
+  sliding-window layers in one ring a slot that holds the window and no
+  more, whatever the length. The paged programs then carry the rings beside
+  the pool, prefill is told how many of a bucket's tokens are real (padding
+  must not enter a ring), and the decode step's fetch brings the routed
+  experts' counters back with the tokens. What a ring cannot do (be shared
+  as a prefix, parked, handed off, rolled back after a rejected speculative
+  window) the engine refuses for such a model, by name;
 - **decode** is the models' own ``forward_with_cache`` protocol ``vmap``-ed
   over the slot axis with per-slot lengths: the protocol is reused
   *unchanged* (each slot sees a batch-of-1 cache view — gathered through its
@@ -273,7 +282,7 @@ class ServingEngine:
         page_size: int = 16,
         num_pages: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
-        prefix_sharing: bool = True,
+        prefix_sharing: Optional[bool] = None,
         prefix_cache_entries: int = 256,
         use_kernels: Optional[bool] = None,
         speculative: Optional[Any] = None,
@@ -289,11 +298,24 @@ class ServingEngine:
         self._init_cache, self._fwc = resolve_decode_protocol(model)
         dtype = dtype if dtype is not None else params["embed_tokens"].dtype
         self.paged = paged
+        # two kinds of cached layer (module docstring): the model says so by
+        # giving the window layers' rings; its full layers alone are paged
+        init_window = getattr(model, "init_window_cache", None)
+        if init_window is not None:
+            self._refuse_for_window_layers(
+                not paged and "the dense slot cache (paged=False): it has one shape for every layer",
+                speculative is not None and "speculative decoding: a rejected window cannot be rolled back out of a ring",
+                prefix_sharing and "prefix sharing: a shared page holds the full layers' K/V, and no ring to resume from",
+            )
+            self._init_cache = model.init_kv_pool
+        if prefix_sharing is None:
+            prefix_sharing = init_window is None
         base_buckets = tuple(buckets) if buckets is not None else prefill_buckets(max_len - 1)
         if paged:
             self.cache = PagedKVCache(
                 self._init_cache, num_slots, max_len, page_size=page_size,
                 num_pages=num_pages, dtype=dtype, prefix_entries=prefix_cache_entries,
+                init_window=init_window,
             )
             if prefill_chunk is not None:
                 if prefill_chunk < page_size or prefill_chunk % page_size:
@@ -439,6 +461,33 @@ class ServingEngine:
         # (pages still refcounted in the pool; lane already freed). The router
         # acks adoption with release_parked(), or re-seats via resume_parked()
         self._parked: dict[int, dict] = {}
+        # routed experts over a held share (models/moe.py:dropless_experts):
+        # [sparse layers, held experts], the shape of what the model's decode
+        # protocol counts under ``moe_held`` and the two-kind programs bring
+        # home; None for a model without sparse layers
+        sparse = len(getattr(model, "sparse_layers", ())) if self.windowed else 0
+        self._experts_shape = (sparse, model.experts_here) if sparse else None
+        self._held_counts = sparse * model.experts_here if sparse else 0
+        if self._experts_shape is not None:
+            self.stats.moe_tokens_by_held_expert = np.zeros((model.experts_here,), np.int64)
+        if self.windowed:
+            # what the prefill programs count of the routed experts, passed
+            # from one to the next on the device until a decode step fetches it
+            self._prefill_counts = jnp.zeros((self._held_counts + 1,), jnp.int32)
+
+    @property
+    def windowed(self) -> bool:
+        """Whether the model has sliding-window layers, kept in rings beside
+        the page pool (``serving/paging.py``)."""
+        return self.paged and self.cache.windowed
+
+    @staticmethod
+    def _refuse_for_window_layers(*reasons) -> None:
+        """Raise for the first thing asked of the engine that a model with
+        window layers cannot be given; each ``reason`` is falsy or names it."""
+        for reason in reasons:
+            if reason:
+                raise NotImplementedError(f"a model with sliding-window layers cannot be served with {reason}")
 
     # -- jitted programs (dot-keyed: shared cache with generate()) ----------
 
@@ -566,6 +615,8 @@ class ServingEngine:
         return {"k": taken_k.reshape(shape), "v": taken_v.reshape(shape), "length": length}
 
     def _paged_decode_program(self):
+        if self.windowed:
+            return self._two_kind_decode_program()
         fwc, sample = self._fwc, self._sample
         ps = self.cache.page_size
         gathered = self._gathered_view
@@ -627,6 +678,91 @@ class ServingEngine:
 
         return self._jit(
             ("serve_paged_decode", self.cache.num_slots, self.cache.view_len, ps,
+             self.temperature, self._donate, use_kernel),
+            build,
+        )
+
+    def _two_kind_decode_program(self):
+        """The paged decode step of a model with two kinds of cached layer:
+        ``_paged_decode_program`` with the window layers' rings ``wk``/``wv``
+        ``[Lw, S, KV, R, D]`` beside the pool. Each lane attends its own ring
+        (mapped over the slot axis); the new token's K/V of the window layers
+        come back as deltas like the full layers' and are written at entry
+        ``length % R`` of the lane's ring. The routed experts see all lanes'
+        tokens as one batch (``models/moe.py``'s batching rule), and the
+        tokens each held expert was chosen by, a sparse layer, over the ACTIVE
+        lanes, ride home behind the tokens in the one fetched vector, and
+        behind them what the prefill programs since the last step counted
+        (``counts``, which they pass from one to the next on the device):
+        ``[S + sparse layers * held experts + that and one more]`` int32."""
+        fwc, sample = self._fwc, self._sample
+        ps = self.cache.page_size
+        gathered = self._gathered_view
+        use_kernel = self._use_decode_kernel
+
+        def build():
+            def decode_step(params, pk, pv, wk, wv, counts, tokens, lengths, active, tables, keys):
+                ring = wk.shape[3]
+                if use_kernel:
+                    from ..ops.paged_attention import paged_decode_attention
+
+                    def attend(q, kn, vn, c):
+                        return paged_decode_attention(
+                            q, kn, vn, c["k"], c["v"], c["table"], c["length"], c["layer"]
+                        )
+
+                    def one_slot(token, row, length, key, wk1, wv1):
+                        cache = {"k": pk, "v": pv, "length": length, "table": row, "attend": attend,
+                                 "wk": wk1[:, None], "wv": wv1[:, None]}
+                        logits, nc = fwc(params, token[None, None], cache)
+                        ok = jnp.all(jnp.isfinite(logits))
+                        return (sample(logits, key)[0], ok, nc["k"][:, 0, 0], nc["v"][:, 0, 0],
+                                nc["wk"][:, 0, 0], nc["wv"][:, 0, 0], nc["moe_held"])
+                else:
+                    def one_slot(token, row, length, key, wk1, wv1):
+                        cache = {**gathered(pk, pv, row, length), "wk": wk1[:, None], "wv": wv1[:, None]}
+                        logits, nc = fwc(params, token[None, None], cache)
+                        ok = jnp.all(jnp.isfinite(logits))
+                        # only position `length` changed in either kind: extract
+                        # it for the write-backs below
+                        fk = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], length, 1, axis=1)[:, 0]
+                        fv = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], length, 1, axis=1)[:, 0]
+                        rk = jax.lax.dynamic_slice_in_dim(nc["wk"][:, 0], length % ring, 1, axis=2)[:, :, 0]
+                        rv = jax.lax.dynamic_slice_in_dim(nc["wv"][:, 0], length % ring, 1, axis=2)[:, :, 0]
+                        return sample(logits, key)[0], ok, fk, fv, rk, rv, nc["moe_held"]
+
+                nxt, ok, fk, fv, rk, rv, held = jax.vmap(one_slot, in_axes=(0, 0, 0, 0, 1, 1))(
+                    tokens, tables, lengths, keys, wk, wv
+                )
+                # write-backs: inactive and probe lanes write ZEROS to the null
+                # page for the full layers, and for the window layers leave
+                # their ring as it was: a lane in the middle of a chunked
+                # prefill is inactive at length 0, and its ring holds the
+                # chunks' live K/V (scrubbed of poison where a lane is quarantined)
+                lane = active.reshape((-1,) + (1,) * (fk.ndim - 1))
+                wpage = jnp.take_along_axis(tables, (lengths // ps)[:, None], axis=1)[:, 0]
+                wpage = jnp.where(active, wpage, 0)
+                woff = jnp.where(active, lengths % ps, 0)
+                fk = jnp.where(lane, fk.astype(pk.dtype), jnp.zeros((), pk.dtype))
+                fv = jnp.where(lane, fv.astype(pv.dtype), jnp.zeros((), pv.dtype))
+                pk = pk.at[:, wpage, woff].set(jnp.moveaxis(fk, 0, 1))
+                pv = pv.at[:, wpage, woff].set(jnp.moveaxis(fv, 0, 1))
+                lanes, entry = jnp.arange(wk.shape[1]), lengths % ring
+                rk = jnp.where(lane, rk.astype(wk.dtype), wk[:, lanes, :, entry])  # the indexed axes lead: [S, Lw, KV, D]
+                rv = jnp.where(lane, rv.astype(wv.dtype), wv[:, lanes, :, entry])
+                wk = wk.at[:, lanes, :, entry].set(rk)
+                wv = wv.at[:, lanes, :, entry].set(rv)
+                counted = jnp.sum(jnp.where(active[:, None, None], held, 0), axis=0)
+                fetched = jnp.concatenate(
+                    [jnp.where(active, nxt, jnp.int32(0)), counted.reshape(-1).astype(jnp.int32), counts]
+                )
+                return fetched, ok, pk, pv, wk, wv, jnp.zeros_like(counts)
+
+            donate = (1, 2, 3, 4, 5) if self._donate else ()
+            return jax.jit(decode_step, donate_argnums=donate)
+
+        return self._jit(
+            ("serve_two_kind_decode", self.cache.num_slots, self.cache.view_len, ps,
              self.temperature, self._donate, use_kernel),
             build,
         )
@@ -753,6 +889,42 @@ class ServingEngine:
         n_pages = span // ps
         gathered = self._gathered_view
 
+        def build_two_kinds():
+            # the same span with the lane's rings beside its gathered pages:
+            # the model attends the ring (what the window layers kept before
+            # `start`) and returns it holding the last of the span's `real`
+            # tokens; a bucket's padding never enters it. `counts` adds up, from
+            # program to program, the real tokens each held expert was chosen
+            # by a layer, then the (layer, held expert) pairs a program hit:
+            # the next decode step's fetch brings them home
+            def prefill(params, ids, pk, pv, wk, wv, counts, row, start, real, slot):
+                cache = gathered(pk, pv, row, start)
+                cache.update(
+                    wk=jax.lax.dynamic_index_in_dim(wk, slot, axis=1), wv=jax.lax.dynamic_index_in_dim(wv, slot, axis=1),
+                    real=real,
+                )
+                _, nc = fwc(params, ids, cache)
+                new_k = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], start, span, axis=1)
+                new_v = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], start, span, axis=1)
+                shape = (new_k.shape[0], n_pages, ps) + new_k.shape[2:]
+                wids = jax.lax.dynamic_slice_in_dim(row, start // ps, n_pages)
+                pk = pk.at[:, wids].set(new_k.reshape(shape).astype(pk.dtype))
+                pv = pv.at[:, wids].set(new_v.reshape(shape).astype(pv.dtype))
+                wk = jax.lax.dynamic_update_index_in_dim(wk, nc["wk"][:, 0].astype(wk.dtype), slot, axis=1)
+                wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)
+                held = nc["moe_held"].reshape(-1).astype(jnp.int32)
+                counts = counts + jnp.concatenate([held, jnp.count_nonzero(held).astype(jnp.int32)[None]])
+                return pk, pv, wk, wv, counts
+
+            donate = (2, 3, 4, 5, 6) if self._donate else ()
+            return jax.jit(prefill, donate_argnums=donate)
+
+        if self.windowed:
+            return self._jit(
+                ("serve_two_kind_prefill", span, self.cache.num_slots, self.cache.view_len, ps, self._donate),
+                build_two_kinds,
+            )
+
         def build():
             def prefill(params, ids, pk, pv, row, start):
                 _, nc = fwc(params, ids, gathered(pk, pv, row, start))
@@ -772,6 +944,25 @@ class ServingEngine:
              ps, self._donate),
             build,
         )
+
+    def _run_prefill_span(self, span: int, ids, row, start: int, real: int, slot: int) -> None:
+        """Dispatch one prefill span program into the cache: ``real`` of the
+        ``span`` ids are tokens, at positions ``start ..`` of ``slot``, whose
+        table row is ``row`` (a copy)."""
+        cache, program = self.cache, self._paged_prefill_program(span)
+        if self.windowed:
+            cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts = program(
+                self.params, ids, cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts, row,
+                np.int32(start), np.int32(real), np.int32(slot),
+            )
+        else:
+            cache.k, cache.v = program(self.params, ids, cache.k, cache.v, row, np.int32(start))
+
+    def _decode_arguments(self, keys) -> tuple:
+        """What the paged decode program is called with, after the weights."""
+        cache = self.cache
+        rings = (cache.wk, cache.wv, self._prefill_counts) if self.windowed else ()
+        return (cache.k, cache.v, *rings, self._pending, cache.lengths, cache.active, cache.tables, keys)
 
     def _page_copy_program(self):
         """Copy one page ``src → dst``: the on-device half of copy-on-write
@@ -863,6 +1054,23 @@ class ServingEngine:
             build,
         )
 
+    def _ring_scrub_program(self):
+        """Zero one lane's rings (a model with window layers): quarantine's
+        scrub of the second kind of cache. Compiled on the first quarantine."""
+
+        def build():
+            def scrub(wk, wv, slot):
+                zeros = jnp.zeros((wk.shape[0], 1) + wk.shape[2:], wk.dtype)
+                return (
+                    jax.lax.dynamic_update_slice_in_dim(wk, zeros, slot, axis=1),
+                    jax.lax.dynamic_update_slice_in_dim(wv, zeros.astype(wv.dtype), slot, axis=1),
+                )
+
+            donate = (0, 1) if self._donate else ()
+            return jax.jit(scrub, donate_argnums=donate)
+
+        return self._jit(("serve_ring_scrub", self.cache.num_slots, self._donate), build)
+
     # -- request intake ----------------------------------------------------
 
     def warmup(self) -> None:
@@ -905,10 +1113,8 @@ class ServingEngine:
                 row = np.zeros((self.cache.pages_per_slot,), np.int32)
                 for span in sorted(spans):
                     ids = np.zeros((1, span), np.int32)
-                    self.cache.k, self.cache.v = self._paged_prefill_program(span)(
-                        self.params, ids, self.cache.k, self.cache.v, row,
-                        np.int32(0),
-                    )
+                    # no real token: a window layer's ring stays as it was
+                    self._run_prefill_span(span, ids, row, 0, 0, 0)
                     if self.spec is not None:
                         # every span program has a draft-pool mirror that
                         # traffic (or catch-up) can select
@@ -917,10 +1123,11 @@ class ServingEngine:
                 # state whenever this engine is a disaggregated pool member:
                 # compile both now against the null page (reading it is free,
                 # and re-inserting its own zeros changes nothing)
-                kb, vb = self.extract_pages([0])
-                self.cache.k, self.cache.v = self._page_insert_program()(
-                    self.cache.k, self.cache.v, kb[0], vb[0], np.int32(0)
-                )
+                if not self.windowed:  # no handoff of a ring: adopt_kv refuses
+                    kb, vb = self.extract_pages([0])
+                    self.cache.k, self.cache.v = self._page_insert_program()(
+                        self.cache.k, self.cache.v, kb[0], vb[0], np.int32(0)
+                    )
                 if self.spec is not None:
                     # the synthetic requests above never draft (1-token
                     # budgets), so the draft decode launch — and tree mode's
@@ -1008,8 +1215,14 @@ class ServingEngine:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
         if prefill_only and not self.paged:
             raise ValueError("prefill_only serving needs a paged engine (paged=True)")
+        self._refuse_for_window_layers(
+            prefill_only and self.windowed and "prefill_only: parking frees the lane, and the rings go with the lane"
+        )
         prefill_len = prompt.size - 1
-        if prefill_len > max(self.buckets):
+        # longer than the largest bucket is served only in chunks, where the
+        # chunk cadence's last (bucket-padded) span still fits the page table
+        chunked = self.prefill_chunk is not None and self._chunk_cadence_fits(prefill_len, 0)
+        if prefill_len > max(self.buckets) and not chunked:
             raise ValueError(
                 f"prompt length {prompt.size} exceeds the largest prefill bucket "
                 f"{max(self.buckets)} + 1"
@@ -1407,9 +1620,8 @@ class ServingEngine:
                 # with no same-step decode fence in between) against XLA's read —
                 # the prefill would scatter into the null page and silently lose
                 # the request's KV
-                self.cache.k, self.cache.v = self._paged_prefill_program(span)(
-                    self.params, ids, self.cache.k, self.cache.v,
-                    self.cache.tables[slot].copy(), np.int32(request.prefilled),
+                self._run_prefill_span(
+                    span, ids, self.cache.tables[slot].copy(), request.prefilled, take, slot
                 )
             if self.spec is not None and self.spec.enabled:
                 # mirror the span into the draft pool (same ids, same row,
@@ -2170,16 +2382,14 @@ class ServingEngine:
                 # just its pending token — emit 1, the plain-decode token), and
                 # the quarantine probe rides the target's finite verdict as usual
                 tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
+            elif self.windowed:
+                cache = self.cache
+                nxt, ok, cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts = self._paged_decode_program()(
+                    self.params, *self._decode_arguments(keys)
+                )
             elif self.paged:
                 nxt, ok, self.cache.k, self.cache.v = self._paged_decode_program()(
-                    self.params,
-                    self.cache.k,
-                    self.cache.v,
-                    self._pending,
-                    self.cache.lengths,
-                    self.cache.active,
-                    self.cache.tables,
-                    keys,
+                    self.params, *self._decode_arguments(keys)
                 )
             else:
                 nxt, ok, self.cache.k, self.cache.v = self._decode_program()(
@@ -2193,38 +2403,57 @@ class ServingEngine:
                 )
         before, stamp = stamp, time.perf_counter()
         phases[device_phase] = stamp - before
+        held = None
         if not spec_on:
             with mark("engine.fetch"):
-                tokens_mat = np.asarray(nxt)[:, None]  # host fetch = per-step fence
+                fetched = np.asarray(nxt)  # host fetch = per-step fence
                 finite = np.asarray(ok)
+            lanes = self.cache.num_slots
+            tokens_mat = fetched[:lanes, None]
+            if self._experts_shape is not None:
+                # the routed experts' counters came home behind the tokens:
+                # [sparse layers, held experts] tokens by held expert, this
+                # step's and the prefill programs' since the last, and the
+                # (layer, held expert) pairs those programs hit
+                held = fetched[lanes : lanes + self._held_counts].reshape(self._experts_shape)
+                *by_expert, prefill_hit = fetched[lanes + self._held_counts :].tolist()
         with mark("engine.deliver") as live:
             # `now` closes the fetch phase (empty after a speculative step)
             # and is the decode fence's stamp
             now = time.perf_counter()
             phases["fetch"] = now - stamp
             retired = len(finished)
+            decoding = int(np.count_nonzero(self.cache.active)) if held is not None else 0
             delivered, context = self._deliver(
                 t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
                 compiles_before, finished,
             )
+            experts = {}
+            if held is not None:
+                experts = {"assignments_held": int(held.sum()), "experts_hit": int(np.count_nonzero(held))}
+                self.stats.record_experts(
+                    decoding * self.model.config.moe_top_k * held.shape[0], held,
+                    prefill_held=sum(by_expert), prefill_hit=prefill_hit,
+                )
             if live is not None:
                 live.set_metadata(retired=len(finished) - retired)
         stamp = time.perf_counter()
         phases["deliver"] = stamp - now
-        self._close_step(root, number, phases, stamp - t0, delivered, context, decoded=1)
+        self._close_step(root, number, phases, stamp - t0, delivered, context, decoded=1, **experts)
         return finished
 
     def _close_step(
         self, root, number: int, phases: dict, seconds: float,
-        tokens: int = 0, context: int = 0, decoded: int = 0,
+        tokens: int = 0, context: int = 0, decoded: int = 0, **experts: int,
     ) -> None:
         """The step's books: its phase split into the always-on counters
         (warm-up's compiles are not a serving step's time) and what only the
-        end of a step knows onto its ``engine.step`` span."""
+        end of a step knows onto its ``engine.step`` span (``experts``: a
+        model with routed experts adds ``assignments_held``, ``experts_hit``)."""
         if not self._warming:
             self.stats.record_phases(number, phases, seconds)
         if root is not None:
-            root.set_metadata(tokens=tokens, context=context, decoded=decoded)
+            root.set_metadata(tokens=tokens, context=context, decoded=decoded, **experts)
 
     def _deliver(
         self, t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
@@ -2270,6 +2499,15 @@ class ServingEngine:
                         self.tracer.mark_decode(marked.id, self._steps, now)
 
         delivered = context = 0
+        if self.windowed:
+            # what this step's tokens attended of each kind of cache, from the
+            # host's lengths: every cached token a full layer, the window's a
+            # window layer (before the loop below moves the lengths)
+            live = self.cache.lengths[self.cache.active].astype(np.int64)
+            self.stats.record_attended(
+                window=int(np.minimum(live, self.cache.window_tokens_per_slot - 1).sum()) * int(self.cache.wk.shape[0]),
+                full=int(live.sum()) * int(self.cache.k.shape[0]),
+            )
         for slot in active_idx:
             request = self.scheduler.slots[slot]
             if request is None or not self.cache.active[slot]:
@@ -2321,6 +2559,12 @@ class ServingEngine:
                             self.spec.scrub_pages(freed)
                     if self.spec is not None:
                         self.spec.draft_len[slot] = 0
+                    if self.windowed:
+                        # the lane's rings hold the poison too, and a masked
+                        # entry's 0 x NaN would fail every probe
+                        self.cache.wk, self.cache.wv = self._ring_scrub_program()(
+                            self.cache.wk, self.cache.wv, np.int32(slot)
+                        )
                 else:
                     self.cache.quarantine(slot)
                     self.cache.k, self.cache.v = self._scrub_program()(
@@ -2452,16 +2696,7 @@ class ServingEngine:
         baked-constant scan proves no table ever froze into the program."""
         keys = jax.random.split(self._rng, self.cache.num_slots)
         if self.paged:
-            return self._paged_decode_program().lower(
-                self.params,
-                self.cache.k,
-                self.cache.v,
-                self._pending,
-                self.cache.lengths,
-                self.cache.active,
-                self.cache.tables,
-                keys,
-            )
+            return self._paged_decode_program().lower(self.params, *self._decode_arguments(keys))
         return self._decode_program().lower(
             self.params,
             self.cache.k,
@@ -2525,6 +2760,9 @@ class ServingEngine:
         never compiles in steady state whatever set of pages moves. All n
         reads dispatch before the first host copy blocks, so the transfers
         pipeline instead of paying n serialized round-trips."""
+        self._refuse_for_window_layers(
+            self.windowed and "extract_pages: a handoff moves pages, and the window layers' rings are in none"
+        )
         program = self._page_extract_program()
         out = [program(self.cache.k, self.cache.v, np.int32(page)) for page in pages]
         return (
@@ -2560,6 +2798,9 @@ class ServingEngine:
         re-prefill). Returns the adopted request id."""
         if not self.paged:
             raise ValueError("adopt_kv needs a paged engine (paged=True)")
+        self._refuse_for_window_layers(
+            self.windowed and "adopt_kv: a handoff moves pages, and the window layers' rings are in none"
+        )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         length = int(layout["length"])
         n = len(k_blocks)
@@ -2936,6 +3177,8 @@ class ServingEngine:
             "use_kernels": self.use_kernels,
             "paged": self.paged,
             "decode_attention": "pallas" if self._use_decode_kernel else "gather_reference",
+            # a model with sliding-window layers: they attend a ring a slot, under XLA
+            "window_attention": "xla_ring" if self.windowed else None,
             "decode_fallback_reason": self._kernel_fallback_reason,
             "quant_matmul": quant_mode,
             "quant_fallback_reason": fallbacks[0] if fallbacks else None,

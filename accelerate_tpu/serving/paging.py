@@ -32,6 +32,16 @@ Three pieces, all pure host bookkeeping (device programs live in
   active mirrors + lane (slot) allocator, with the same retire/quarantine
   surface the engine drove on :class:`~.kv_cache.SlotKVCache`.
 
+Not every cached layer has the pool's shape. A model with sliding-window
+layers beside full ones (``models/exaone_moe.py``) gives the manager two kinds:
+the FULL layers live in the page pool above, ``[Lf, num_pages, ...]``, every
+token kept, pages growing with the context; the WINDOW layers live in one ring
+a slot, ``wk``/``wv`` ``[Lw, num_slots, KV, window, D]`` (position ``p`` at
+entry ``p % window``), allocated once and the same size whatever the length:
+a window layer never keeps or reads more than its window. A ring belongs to
+its lane, not to pages, so nothing of it can be shared, parked or handed off:
+the engine refuses those for such a model, by name.
+
 Copy-on-write: sharing is page-aligned (full pages only — the unaligned tail
 of a shared prefix is recomputed, never half-shared), so in steady state a
 slot's write position always lands in a private page. ``prepare_write`` is
@@ -255,7 +265,9 @@ class PagedKVCache:
     — pages ride the protocol's batch axis, so any decode-protocol model
     pages without changes. ``tables``/``lengths``/``active`` are HOST arrays
     shipped into the jitted programs per step; all device shapes are fixed at
-    construction."""
+    construction. ``init_window(num_slots)`` (a model with window layers)
+    adds the second kind of cached layer: ``wk``/``wv``, one ring a slot (see
+    the module docstring); ``windowed`` says whether there is one."""
 
     def __init__(
         self,
@@ -266,6 +278,7 @@ class PagedKVCache:
         num_pages: Optional[int] = None,
         dtype=None,
         prefix_entries: int = 256,
+        init_window=None,
     ):
         import jax.numpy as jnp
 
@@ -282,6 +295,11 @@ class PagedKVCache:
         dtype = dtype if dtype is not None else jnp.bfloat16
         cache = init_cache(num_pages, page_size, dtype=dtype)
         self.k, self.v = cache["k"], cache["v"]
+        self.windowed = init_window is not None
+        self.wk = self.wv = None
+        if self.windowed:
+            rings = init_window(num_slots, dtype=dtype)
+            self.wk, self.wv = rings["wk"], rings["wv"]
         self.num_pages = num_pages
         self.num_slots = num_slots
         self.max_len = max_len
@@ -298,12 +316,19 @@ class PagedKVCache:
 
     @property
     def nbytes(self) -> int:
-        return int(self.k.nbytes + self.v.nbytes)
+        rings = int(self.wk.nbytes + self.wv.nbytes) if self.windowed else 0
+        return int(self.k.nbytes + self.v.nbytes) + rings
 
     @property
     def page_bytes(self) -> int:
         """Device bytes of one (k + v) page."""
-        return self.nbytes // self.num_pages
+        return int(self.k.nbytes + self.v.nbytes) // self.num_pages
+
+    @property
+    def window_tokens_per_slot(self) -> int:
+        """Tokens a slot's window layers keep, each: the ring's length, whatever
+        the context (0 without window layers)."""
+        return int(self.wk.shape[3]) if self.windowed else 0
 
     @property
     def pages_in_use(self) -> int:

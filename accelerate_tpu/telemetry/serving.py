@@ -135,6 +135,18 @@ class ServingStats:
         self.spec_accepted_tokens = 0
         self.spec_fallbacks = 0
         self.spec_accepted_lengths: list[int] = []
+        # routed experts over a held share, and two kinds of cached layer
+        # (models/exaone_moe.py): decode steps only, from what came home with
+        # the step's tokens and from the host's lengths; all zero for a model
+        # with neither. ``moe_tokens_by_held_expert`` sums over the layers.
+        self.moe_assignments = 0  # (token, sparse layer, chosen expert), held here or not
+        self.moe_assignments_held = 0  # those on an expert this chip holds
+        self.moe_experts_hit = 0  # (sparse layer, held expert) pairs some token chose, a step
+        self.moe_prefill_assignments_held = 0  # the same two of the prefill programs' real tokens,
+        self.moe_prefill_experts_hit = 0  # a program (fetched with the next decode step's tokens)
+        self.moe_tokens_by_held_expert: Optional[np.ndarray] = None
+        self.attended_window_tokens = 0  # cached tokens the decoded tokens attended, window layers
+        self.attended_full_tokens = 0  # and full layers
 
     # -- intake ------------------------------------------------------------
 
@@ -257,6 +269,30 @@ class ServingStats:
     def record_page_pressure(self) -> None:
         self.page_pressure_events += 1
 
+    def record_experts(
+        self, assignments: int, held: np.ndarray, prefill_held: int = 0, prefill_hit: int = 0,
+    ) -> None:
+        """One decode step's routing: ``assignments`` made in all, and
+        ``held`` [sparse layers, held experts], the tokens each held expert
+        was chosen by; of the prefill programs since the last step, the
+        assignments on held experts and the (layer, held expert) pairs hit."""
+        self.moe_prefill_assignments_held += prefill_held
+        self.moe_prefill_experts_hit += prefill_hit
+        self.moe_assignments += assignments
+        self.moe_assignments_held += int(held.sum())
+        self.moe_experts_hit += int(np.count_nonzero(held))
+        by_expert = held.sum(axis=0).astype(np.int64)
+        if self.moe_tokens_by_held_expert is None:
+            self.moe_tokens_by_held_expert = by_expert
+        else:
+            self.moe_tokens_by_held_expert += by_expert
+
+    def record_attended(self, window: int, full: int) -> None:
+        """Cached tokens one decode step's tokens attended, summed over the
+        layers of each kind."""
+        self.attended_window_tokens += window
+        self.attended_full_tokens += full
+
     def record_step(
         self,
         duration_s: float,
@@ -373,6 +409,14 @@ class ServingStats:
         out["trace_spans"] = self.trace_spans
         out["slo_good_events"] = self.slo_good_events
         out["slo_bad_events"] = self.slo_bad_events
+        if self.moe_tokens_by_held_expert is not None:
+            out["moe_assignments"] = self.moe_assignments
+            out["moe_assignments_held"] = self.moe_assignments_held
+            out["moe_experts_hit"] = self.moe_experts_hit
+            out["moe_prefill_assignments_held"] = self.moe_prefill_assignments_held
+            out["moe_prefill_experts_hit"] = self.moe_prefill_experts_hit
+            out["attended_window_tokens"] = self.attended_window_tokens
+            out["attended_full_tokens"] = self.attended_full_tokens
         out["spec_steps"] = self.spec_steps
         out["spec_proposed_tokens"] = self.spec_proposed_tokens
         out["spec_accepted_tokens"] = self.spec_accepted_tokens

@@ -55,3 +55,45 @@ def test_paged_attention_addresses_the_stacked_pool_under_mosaic(one_chip, monke
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert not re.search(rf"= bf16\[{pages},{ps},{kv},{d}\]", text), "a layer of the pool is copied out"
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * pages * ps * kv * d  # under one layer's pool
+
+
+def test_the_two_kind_cells_kernels_compile_at_its_geometry(one_chip, monkeypatch):
+    """``k-exaone.serve-mixed``: 128 slots on ONE full layer's pool of 20,481
+    pages, eight query heads a KV head (mistral has four), and the held experts'
+    grouped products (XLA's own Mosaic grouped product, a chunk of 256 rows at
+    a time) for a prefill chunk's many tokens and for a decode step's few."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.models.moe import CHUNK_ROWS, dropless_experts
+    from accelerate_tpu.ops.paged_attention import paged_decode_attention
+
+    slots, pages, ps, kv, nh, d, pps = 128, 20481, 16, 8, 64, 128, 160
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q, kn, vn, tables, lengths, pool_k, pool_v, layer):
+        one = lambda q, kn, vn, row, n: paged_decode_attention(q, kn, vn, pool_k, pool_v, row, n, layer)
+        return jax.vmap(one)(q, kn, vn, tables, lengths)
+
+    pool = shape((1, pages, ps, kv, d))
+    compiled = jax.jit(attend).lower(
+        shape((slots, 1, 1, nh, d)), shape((slots, 1, 1, kv, d)), shape((slots, 1, 1, kv, d)),
+        shape((slots, pps), jnp.int32), shape((slots,), jnp.int32), pool, pool, shape((), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    hidden, width, held, experts = 6144, 2048, 16, 128
+    weights = (
+        shape((hidden, experts)), shape((experts,), jnp.float32),
+        shape((held, hidden, width)), shape((held, hidden, width)), shape((held, width, hidden)),
+    )
+    routed = lambda x, router, bias, gate, up, down: dropless_experts(x, router, bias, gate, up, down, top_k=8, scaling=2.5, first=0)
+    # a prefill chunk's 512 tokens: 4,096 assignments sorted, a chunk of rows at a time through the grouped products, in a loop
+    text = jax.jit(routed).lower(shape((512, hidden)), *weights).compile().as_text()
+    assert len(re.findall(rf"= bf16\[{CHUNK_ROWS},\d+\]\S* custom-call\(", text)) == 3
+    assert text.count("ragged-dot-metadata = ") == 1 and " while(" in text
+    # a decode step's 128 tokens, one a slot under the engine's vmap: ONE batch (the batching rule) of 1,024
+    # assignments through the same three products, not 128 batched ones
+    text = jax.jit(jax.vmap(routed, in_axes=(0, None, None, None, None, None))).lower(shape((slots, 1, hidden)), *weights).compile().as_text()
+    assert len(re.findall(rf"= bf16\[{CHUNK_ROWS},\d+\]\S* custom-call\(", text)) == 3
+    assert text.count("ragged-dot-metadata = ") == 1 and " while(" in text

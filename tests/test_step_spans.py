@@ -360,3 +360,84 @@ def test_the_compiled_step_draws_three_spans_in_a_session_and_none_outside(tmp_p
     host = next(p for p in jax.profiler.ProfileData.from_file(path).planes if p.name == "/host:CPU")
     names = [e.name for line in host.lines for e in line.events]
     assert names.count("train.step") == names.count("train.dispatch") == names.count("train.host") == 3
+
+
+# -- a model with two kinds of cached layer and routed experts (models/exaone_moe.py) ---------
+
+
+@pytest.fixture(scope="module")
+def exaone():
+    """The benchmark's ``exaone_moe`` family at its rehearsal widths: five layers (four with a window of 8, one
+    full; one dense MLP, four sparse), 8 routed experts of which experts 4..7 are held, 2 a token."""
+    from benchmark.lib import configs
+
+    cfg = configs.model_config("k-exaone-236b-a23b", rehearse=True)
+    family = configs.family(cfg)
+    return cfg, family, family.build(cfg), family.params(cfg, 3, jnp.float32)
+
+
+EXAONE_ENGINE = dict(max_len=80, page_size=8, buckets=(8, 16), prefill_chunk=16)
+
+
+def test_a_two_kind_steps_children_still_account_for_it_and_its_root_carries_the_experts_ids(exaone, tmp_path):
+    cfg, _, model, params = exaone
+    engine = ServingEngine(model, params, num_slots=3, **EXAONE_ENGINE)
+    engine.warmup()
+    held_before, hit_before = engine.stats.moe_assignments_held, engine.stats.moe_experts_hit
+    rng = np.random.default_rng(0)
+    for n in (5, 30, 12, 19):
+        engine.submit(rng.integers(1, cfg["vocab_size"], (n,)).astype(np.int32), max_new_tokens=9)
+    with session(tmp_path):
+        while engine.busy:
+            engine.step()
+    recorded = profiler.recorded()
+    decoded = [s for s in recorded if s.name == "engine.step" and s.ids["decoded"]]
+    assert len(decoded) >= 9
+    inside = total = 0
+    for root in decoded:
+        children = _under(recorded, root)
+        assert [c.name for c in children] == CHILDREN  # the rings and the counters add no host phase of their own
+        assert all(a.end_ns <= b.start_ns for a, b in zip(children, children[1:]))
+        assert root.start_ns <= children[0].start_ns and children[-1].end_ns <= root.end_ns
+        assert 0 <= root.ids["assignments_held"] <= root.ids["tokens"] * cfg["num_experts_per_tok"] * 4
+        assert 0 <= root.ids["experts_hit"] <= min(root.ids["assignments_held"], 4 * cfg["num_experts"])
+        inside, total = inside + sum(c.end_ns - c.start_ns for c in children), total + root.end_ns - root.start_ns
+    assert inside > 0.8 * total  # the step's self time: what lies between the children, the counters' split among it
+    assert sum(r.ids["assignments_held"] for r in decoded) == engine.stats.moe_assignments_held - held_before > 0
+    assert sum(r.ids["experts_hit"] for r in decoded) == engine.stats.moe_experts_hit - hit_before > 0
+    assert all(type(v) in (int, float, str) for s in recorded for v in s.ids.values())
+
+
+def test_the_experts_and_the_attended_counters_equal_a_hand_count(exaone):
+    cfg, family, model, params = exaone
+    top_k, window, first = cfg["num_experts_per_tok"], cfg["sliding_window"], cfg["held"]["first_expert"]
+    sparse, sliding, full = 4, 4, 1
+    # a router that sends every token to the first two held experts: every assignment is on a held one
+    favoured = jnp.zeros((cfg["held"]["router_experts"],), jnp.float32).at[first : first + top_k].set(100.0)
+    biased = {**params, "layers": [{**lp, "router_bias": favoured} if "router" in lp else lp for lp in params["layers"]]}
+    engine = ServingEngine(model, biased, num_slots=2, **EXAONE_ENGINE)  # no warm-up: its own requests would be counted
+    lengths, new = (5, 20, 11), 6
+    rng = np.random.default_rng(1)
+    for n in lengths:
+        engine.submit(rng.integers(1, cfg["vocab_size"], (n,)).astype(np.int32), max_new_tokens=new)
+    results = engine.run()
+    stats, decoded = engine.stats, len(lengths) * new
+    assert len(results) == len(lengths) and stats.tokens_generated == decoded
+    assert stats.moe_assignments == stats.moe_assignments_held == decoded * top_k * sparse
+    assert stats.moe_tokens_by_held_expert.tolist() == [decoded * sparse] * top_k + [0] * (cfg["num_experts"] - top_k)
+    assert stats.moe_experts_hit == stats.steps * top_k * sparse  # two experts a layer, every decode step
+    # prefill: every prompt token but the last, and 5 -> one span; 20 -> a chunk and a bucket; 11 -> one span
+    assert stats.moe_prefill_assignments_held == sum(n - 1 for n in lengths) * top_k * sparse
+    assert stats.moe_prefill_experts_hit == 4 * top_k * sparse
+    # a token decoded after n cached ones attends n of them in a full layer and the window's in a window layer
+    contexts = [n - 1 + j for n in lengths for j in range(new)]
+    assert stats.attended_full_tokens == full * sum(contexts) == full * stats.decode_context_tokens
+    assert stats.attended_window_tokens == sliding * sum(min(c, window - 1) for c in contexts)
+    # the roofline reader's rows and pairs: the decode steps' and the prefill programs', as the family hands them on
+    counted = family.counters(engine)
+    assert counted["assignments_held"] + counted["prefill_assignments_held"] == (decoded + sum(n - 1 for n in lengths)) * top_k * sparse
+    assert counted["experts_hit"] + counted["prefill_experts_hit"] == (stats.steps + 4) * top_k * sparse
+    assert [counted[f"tokens_by_held_expert.{e}"] for e in range(cfg["num_experts"])] == stats.moe_tokens_by_held_expert.tolist()
+    snapshot = engine.metrics()
+    assert snapshot["moe_assignments_held"] == stats.moe_assignments_held and snapshot["attended_window_tokens"] > 0
+    assert "moe_assignments" not in ServingStats(2).snapshot()  # a model with neither keeps the keys it had
