@@ -10,14 +10,49 @@ regardless of how few positions are valid.
 
 This kernel attends the page pool DIRECTLY: one program per slot walks that
 slot's int32 page-table row (scalar-prefetched into SMEM) up to its dynamic
-``length`` bound, DMAs one whole ``[page_size, KV, D]`` page — contiguous in
-the pool — from HBM into VMEM at a time, and folds it into an online softmax
-for every head at once. The gathered view is never materialized, invalid
-pages are never read (a fresh request touches one page, not ``view_len``),
-and the candidate window's own K/V — not yet scattered into the pool — joins
-the softmax as a final block under a causal in-window mask, so the engine's
+``length`` bound and folds the pages it names into an online softmax for
+every head at once. The gathered view is never materialized, invalid pages
+are never read (a fresh request touches one page, not ``view_len``), and the
+candidate window's own K/V — not yet scattered into the pool — joins the
+softmax as a final block under a causal in-window mask, so the engine's
 write-back stays a separate scatter exactly as in the reference program.
 Plain decode is the window of one token.
+
+The page walk is a software pipeline: copies are in flight while the VPU
+folds. A page's 64 KB need 0.08 us of HBM's bandwidth but ~0.5 us from the
+DMA's issue to its landing (PERF.md §6, PR 31), so the wait is latency, and
+it is paid once for many pages:
+
+- A **block** is B consecutive entries of the slot's table row. The K and V
+  copies of all its pages (each ``[page_size, KV, D]``, contiguous in the
+  pool) are started together into one ``[B, page_size, KV, D]`` buffer and
+  waited for together. B is derived, not set (:func:`_pages_per_block`):
+  ``_BLOCK_TOKENS`` cached tokens' worth of pages (8 pages of 16), at most a
+  table row's entries, at most what fits two buffers of K and two of V in
+  ``_SCRATCH_BYTES`` of VMEM (1 MB of it at 8 KV heads of 128 in bf16).
+- **Two buffers**: before a block's copies are waited for and folded, the
+  next block's are started into the other buffer.
+- **Carried from slot to slot**: under a slot's LAST block the first block
+  of the next slot that holds anything is started (empty lanes are stepped
+  over), so the pipeline drains once a launch, not once a slot. The buffers,
+  the semaphores and the pipeline's state — which buffer holds the next
+  block, and whether it is in flight — are scratch that outlives a grid
+  step; the slot axis therefore runs in order (``"arbitrary"``), and the
+  first slot resets the state.
+- The fold stays at the grain of one page, read from the buffer in an inner
+  loop: a block of 128 tokens in fp32 would be 128 vregs for K alone.
+
+Three invariants, each held by a test in tests/test_paged_attention.py:
+(1) a page past the length bound is never read — copies start only for a
+block's entries below the slot's page count, a zero-length lane starts none
+and is stepped over by its predecessor's prefetch; (2) rows of a block that
+no copy filled hold whatever VMEM held (NaN bits, possibly) and are never
+folded — the inner loop stops at the page count — so they contribute exactly
+nothing, while the tail of a partial PAGE holds stale finite pool data that
+the mask turns into exact zeros; (3) every copy started is waited for
+exactly once, the last slot's included, through one list of descriptors
+under one predicate for both — a copy left in flight at the kernel's end is
+a hang or a corrupted buffer on the chip and invisible in interpret mode.
 
 The kernel takes the whole STACKED pool ``[L, P, page_size, KV, D]`` where it
 lies in HBM and addresses it by (layer, page); the layer index is a third
@@ -41,7 +76,8 @@ rule turns that into ONE slot-batched ``pallas_call`` per layer per step
 with the slot axis as the grid.
 
 Numerics: the running max starts at the flash kernel's ``M_INIT`` so padded
-tail positions of a partial page underflow ``exp`` to exactly 0. A window
+tail positions of a partial page underflow ``exp`` to exactly 0. Pages are
+folded one by one in table order, whatever B is. A window
 row's own key is always valid, so a row can never be fully masked. At
 temperature 0 the engine's kernel path emits the same tokens as the
 gather-reference path in fp32 (pinned by tests/test_paged_attention.py over
@@ -105,6 +141,23 @@ def _fold(carry, q, k, v, valid):
     )
 
 
+# a block aims at this many cached tokens: the fixed cost of issuing a DMA
+# and seeing it land (~0.5 us, six times what HBM needs for a 64 KB page) is
+# then paid once for the block's pages, not once a page
+_BLOCK_TOKENS = 128
+# the two buffers of K and of V may take this much VMEM between them
+_SCRATCH_BYTES = 4 << 20
+
+
+def _pages_per_block(page_size: int, kv_heads: int, head_dim: int, dtype, pages_per_slot: int) -> int:
+    """B, the pages whose copies are started and waited for together: as
+    many as make ``_BLOCK_TOKENS`` cached tokens, no more than a table row
+    holds, and no more than fit the VMEM budget twice over for K and V."""
+    page_bytes = page_size * kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    fit = _SCRATCH_BYTES // (4 * page_bytes)
+    return max(1, min(_BLOCK_TOKENS // page_size, pages_per_slot, fit))
+
+
 def _paged_kernel(
     tables_ref,  # SMEM [S, pps] int32 (scalar prefetch): page-table rows
     lengths_ref,  # SMEM [S] int32 (scalar prefetch): committed positions
@@ -115,16 +168,18 @@ def _paged_kernel(
     pool_k_ref,  # ANY (HBM) [L, P, ps, KV, D]: the stacked pool, in place
     pool_v_ref,  # ANY (HBM) [L, P, ps, KV, D]
     o_ref,  # VMEM [1, W*group, KV, D] out
-    k_scratch,  # VMEM [ps, KV, D] pool dtype
-    v_scratch,  # VMEM [ps, KV, D]
-    sems,  # DMA semaphores (2,)
+    k_buf,  # VMEM [2, B, ps, KV, D] pool dtype: two buffers of one block
+    v_buf,  # VMEM [2, B, ps, KV, D]
+    sems,  # DMA semaphores [2, 2]: (K | V, buffer)
+    pipe,  # SMEM [2] int32: (buffer of the next block to fold, is it in flight)
     *,
     page_size: int,
+    block_pages: int,
     window: int,
     group: int,
 ):
     slot = pl.program_id(0)
-    length = lengths_ref[slot]
+    slots = pl.num_programs(0)
     layer = layer_ref[0]
     kv, d = q_ref.shape[-2:]
     f32 = jnp.float32
@@ -135,27 +190,89 @@ def _paged_kernel(
         jnp.zeros((kv, d), f32),
     )
 
-    # committed pages (positions 0..length-1; zero-trip for a fresh/idle
-    # lane): every window row attends all of them
-    npages = jax.lax.div(length + jnp.int32(page_size - 1), jnp.int32(page_size))
+    def ceil_div(n, m):
+        return jax.lax.div(n + jnp.int32(m - 1), jnp.int32(m))
+
+    def pages_of(s):
+        return ceil_div(lengths_ref[s], page_size)
+
+    def copies(s, b, buf, act):
+        """``act`` ("start" | "wait") on the copies of block ``b`` of slot
+        ``s`` into buffer ``buf``: one K and one V descriptor per table
+        entry, under the predicate that the entry is below the slot's page
+        count. Starting and waiting go through this one list, so what was
+        started is what is waited for."""
+        present = pages_of(s) - b * block_pages
+        for p in range(block_pages):
+            @pl.when(p < present)
+            def _():
+                page = tables_ref[s, b * block_pages + p]
+                for kind, (pool, dst) in enumerate(((pool_k_ref, k_buf), (pool_v_ref, v_buf))):
+                    dma = pltpu.make_async_copy(pool.at[layer, page], dst.at[buf, p], sems.at[kind, buf])
+                    getattr(dma, act)()
+
+    # scratch outlives a grid step (and a launch): the first slot resets the
+    # pipeline's state, so nothing of an earlier launch reaches this one
+    @pl.when(slot == 0)
+    def _():
+        pipe[0] = 0
+        pipe[1] = 0
+
+    # committed pages (positions 0..length-1; none for a fresh/idle lane,
+    # which then touches neither the pool nor the pipeline): every window
+    # row attends all of them, a block of table entries at a time
+    length = lengths_ref[slot]
+    npages = pages_of(slot)
+    nblocks = ceil_div(npages, block_pages)
+    first = pipe[0]
     pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 0)
 
-    def body(j, carry):
-        page = tables_ref[slot, j]
-        k_dma = pltpu.make_async_copy(pool_k_ref.at[layer, page], k_scratch, sems.at[0])
-        v_dma = pltpu.make_async_copy(pool_v_ref.at[layer, page], v_scratch, sems.at[1])
-        k_dma.start()
-        v_dma.start()
-        k_dma.wait()
-        v_dma.wait()
-        k = k_scratch[...].astype(f32)
-        v = v_scratch[...].astype(f32)
-        # mask the partial last page: positions >= length hold stale pool
-        # data (or the unwritten tail) and must underflow exp to exactly 0
-        valid = j * page_size + pos_in_page < length
-        return tuple(_fold(c, q, k, v, valid) for c, q in zip(carry, queries))
+    # the launch's first non-empty slot has no predecessor to have started
+    # its first block
+    @pl.when(jnp.logical_and(nblocks > 0, pipe[1] == 0))
+    def _():
+        copies(slot, 0, first, "start")
 
-    carry = jax.lax.fori_loop(0, npages, body, (init,) * len(queries))
+    def block(b, carry):
+        buf = jax.lax.rem(first + b, 2)
+        last = b + 1 == nblocks
+
+        # keep copies in flight under this block's arithmetic: the slot's
+        # next block or, under its last, the first block of the next slot
+        # that holds anything (empty lanes are stepped over)
+        @pl.when(jnp.logical_not(last))
+        def _():
+            copies(slot, b + 1, 1 - buf, "start")
+
+        @pl.when(last)
+        def _():
+            following = jax.lax.while_loop(
+                lambda s: jnp.logical_and(s < slots, lengths_ref[jnp.minimum(s, slots - 1)] == 0),
+                lambda s: s + 1,
+                slot + 1,
+            )
+            pipe[0] = 1 - buf
+            pipe[1] = (following < slots).astype(jnp.int32)
+
+            @pl.when(following < slots)
+            def _():
+                copies(following, 0, 1 - buf, "start")
+
+        copies(slot, b, buf, "wait")
+
+        # fold at the grain of one page, and only the pages that were
+        # fetched: the rest of a partial block holds whatever VMEM held
+        def page(p, carry):
+            k = k_buf[buf, p].astype(f32)
+            v = v_buf[buf, p].astype(f32)
+            # mask the partial last page: positions >= length hold stale pool
+            # data (or the unwritten tail) and must underflow exp to exactly 0
+            valid = (b * block_pages + p) * page_size + pos_in_page < length
+            return tuple(_fold(c, q, k, v, valid) for c, q in zip(carry, queries))
+
+        return jax.lax.fori_loop(0, jnp.minimum(npages - b * block_pages, block_pages), page, carry)
+
+    carry = jax.lax.fori_loop(0, nblocks, block, (init,) * len(queries))
 
     # the candidate window (positions length..length+W-1) is not in the pool
     # yet — the engine's write-back is a separate masked scatter — so it folds
@@ -179,6 +296,7 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
     ps = pool_k.shape[-3]
     group = nh // kv
     rows = w * group
+    block_pages = _pages_per_block(ps, kv, d, pool_k.dtype, tables.shape[1])
     # head h = g*group + gi reads kv head g (the zoo's GQA convention): lay
     # the queries out as rows of one-query-per-kv-head, row = wi*group + gi
     q_rows = q.reshape(s, w, kv, group, d).transpose(0, 1, 3, 2, 4).reshape(s, rows, kv, d)
@@ -187,7 +305,9 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
         return pl.BlockSpec((1, n, kv, d), lambda i, *_: (i, 0, 0, 0), memory_space=pltpu.VMEM)
 
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=ps, window=w, group=group),
+        functools.partial(
+            _paged_kernel, page_size=ps, block_pages=block_pages, window=w, group=group
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s,),
@@ -200,12 +320,15 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
             ],
             out_specs=per_slot(rows),
             scratch_shapes=[
-                pltpu.VMEM((ps, kv, d), pool_k.dtype),
-                pltpu.VMEM((ps, kv, d), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, block_pages, ps, kv, d), pool_k.dtype),
+                pltpu.VMEM((2, block_pages, ps, kv, d), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, rows, kv, d), q.dtype),
+        # a slot's last block starts the next slot's first: slots run in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(),
         name="paged_attention",
     )(

@@ -13,9 +13,11 @@ import jax.numpy as jnp
 
 from accelerate_tpu.models import GPT2, Llama
 from accelerate_tpu.ops.paged_attention import (
+    _pages_per_block,
     _reference,
     paged_decode_attention,
     paged_kernel_fallback_reason,
+    paged_verify_attention,
 )
 from accelerate_tpu.serving import ServingEngine
 
@@ -70,38 +72,173 @@ def test_kernel_matches_gather_reference_op_level(num_layers, layer):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
 
 
-def test_kernel_never_reads_unwalked_pages_and_masks_stale_tails():
-    """Two tiers of the paged safety invariant, kernel edition: pages the
-    length bound never reaches are NOT read at all (NaN there is invisible
-    — the page loop stops, no DMA happens), and the masked tail of the
-    partial last page contributes exactly-zero softmax weight, so stale
-    FINITE values there cannot move the output (the pool-stays-finite
-    contract, identical to the gather reference's 0 x value semantics)."""
-    rng = np.random.default_rng(1)
-    P, ps, kv, d, nh = 6, 8, 2, 32, 2
-    layer = 1  # of 2: layer 0 is never addressed, so it may hold anything
-    pool_k = rng.normal(size=(2, P, ps, kv, d)).astype(np.float32)
-    pool_v = rng.normal(size=(2, P, ps, kv, d)).astype(np.float32)
-    q = jnp.asarray(rng.normal(size=(1, 1, nh, d)).astype(np.float32))
-    kn = jnp.asarray(rng.normal(size=(1, 1, kv, d)).astype(np.float32))
-    vn = jnp.asarray(rng.normal(size=(1, 1, kv, d)).astype(np.float32))
-    table = jnp.asarray([2, 4, 3], jnp.int32)
-    length = jnp.int32(11)  # page 2 full, page 4 holds 3 valid positions
-    clean = paged_decode_attention(
-        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), table, length, layer
+# the op-level geometry of the pipeline's tests: pages of 8 make blocks of
+# B = 16 table entries (128 cached tokens), and a table row of 2B + 2 entries
+# holds two whole blocks and a partial third
+PS, KV, D = 8, 2, 32
+PPS = 34
+B = _pages_per_block(PS, KV, D, jnp.float32, PPS)
+POOL_PAGES = 80
+
+
+def test_block_size_follows_the_shapes():
+    """B is derived from what the kernel sees, under a VMEM budget: 128
+    cached tokens a block at both serving cells' geometry (8 pages of 16,
+    two buffers of K and V = 1 MB), never more entries than a table row has,
+    never more than the budget holds, and at least one page."""
+    assert B == 16 and PPS == 2 * B + 2
+    assert _pages_per_block(16, 8, 128, jnp.bfloat16, 80) == 8  # mistral-7b.serve-chat
+    assert _pages_per_block(16, 8, 128, jnp.bfloat16, 160) == 8  # k-exaone.serve-mixed
+    assert _pages_per_block(16, 8, 128, jnp.bfloat16, 3) == 3
+    assert _pages_per_block(256, 8, 128, jnp.float32, 80) == 1  # a page larger than a block
+    assert _pages_per_block(8, 16, 512, jnp.float32, 80) == 4  # pages of 256 KB: the budget binds
+
+
+def _pool(rng, layers=1):
+    shape = (layers, POOL_PAGES, PS, KV, D)
+    return rng.normal(size=shape).astype(np.float32), rng.normal(size=shape).astype(np.float32)
+
+
+def _poisoned(pool, layer, keep):
+    """``pool`` with NaN everywhere but layer ``layer``'s pages ``keep``."""
+    out = np.full_like(pool, np.nan)
+    out[layer, keep] = pool[layer, keep]
+    return jnp.asarray(out)
+
+
+def _window(rng, w, nh):
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    return draw(1, w, nh, D), draw(1, w, KV, D), draw(1, w, KV, D)
+
+
+_attend = jax.jit(paged_verify_attention)
+_attend_slots = jax.jit(
+    lambda q, kn, vn, pool_k, pool_v, tables, lengths: jax.vmap(
+        lambda q, kn, vn, row, n: paged_verify_attention(q, kn, vn, pool_k, pool_v, row, n, jnp.int32(0))
+    )(q, kn, vn, tables, lengths)
+)
+
+EDGES = {
+    "0": 0, "1": 1, "ps-1": PS - 1, "ps": PS, "B.ps-1": B * PS - 1, "B.ps": B * PS,
+    "B.ps+1": B * PS + 1, "2.B.ps": 2 * B * PS, "table": PPS * PS,
+}
+
+
+@pytest.mark.parametrize("window", [1, 3])
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("edge", list(EDGES))
+def test_kernel_matches_reference_at_every_edge_of_the_block_loop(edge, group, window):
+    """The page walk is a pipeline of blocks of B table entries: lengths on
+    both sides of a page's, a block's and the table's end, at one, four and
+    eight query heads a KV head and windows of one and three, against the
+    gather reference. Every page past the length bound holds NaN, whether
+    inside the last block or in a later one, so a copy or a fold too many
+    cannot stay finite."""
+    length = EDGES[edge]
+    rng = np.random.default_rng(length + 1000 * group + window)
+    pool_k, pool_v = _pool(rng)
+    table = rng.permutation(POOL_PAGES)[:PPS].astype(np.int32)
+    q, kn, vn = _window(rng, window, KV * group)
+    held = table[: -(-length // PS)]
+    got = _attend(
+        q, kn, vn, _poisoned(pool_k, 0, held), _poisoned(pool_v, 0, held),
+        jnp.asarray(table), jnp.int32(length), jnp.int32(0),
     )
-    pool_k[0] = np.nan  # the other layer, whole
-    pool_v[0] = np.nan
-    pool_k[layer, 3] = np.nan  # in the table row, but past the length bound
-    pool_v[layer, 3] = np.nan
-    pool_k[layer, 1] = np.nan  # not referenced by this slot at all
-    pool_v[layer, 5] = np.nan
-    pool_k[layer, 4, 3:] = 1e6  # stale-but-finite tail of the partial page
-    pool_v[layer, 4, 3:] = -1e6
-    poisoned = paged_decode_attention(
-        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), table, length, layer
+    want = _reference(
+        q, kn, vn, jnp.asarray(pool_k[0]), jnp.asarray(pool_v[0]), jnp.asarray(table),
+        jnp.int32(length), scale=1.0 / D**0.5,
+    )
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
+def test_kernel_never_reads_unwalked_pages_and_masks_stale_tails():
+    """Three tiers of the paged safety invariant, kernel edition. Pages the
+    length bound never reaches are NOT read at all (NaN there is invisible),
+    whether their table entries lie inside the last BLOCK the walk fetches
+    (no copy is started for them) or in a later one (the block loop stops).
+    The rows of a partial block that no copy filled hold whatever VMEM held
+    and are never folded, so they contribute exactly nothing. And the masked
+    tail of the partial last page contributes exactly-zero softmax weight,
+    so stale FINITE values there cannot move the output (the
+    pool-stays-finite contract, identical to the gather reference's
+    0 x value semantics)."""
+    rng = np.random.default_rng(1)
+    layer = 1  # of 2: layer 0 is never addressed, so it may hold anything
+    pool_k, pool_v = _pool(rng, layers=2)
+    q, kn, vn = _window(rng, 1, 2 * KV)
+    table = rng.permutation(POOL_PAGES)[:PPS].astype(np.int32)
+    length = B * PS + PS + 3  # one whole block, then one page and 3 positions of the next
+    walked = -(-length // PS)
+    assert B < walked < 2 * B < PPS  # poison lies inside the last block AND in a later one
+    clean = _attend(
+        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(table),
+        jnp.int32(length), jnp.int32(layer),
+    )
+    partial = table[walked - 1]
+    kept_k, kept_v = pool_k[layer, table[:walked]].copy(), pool_v[layer, table[:walked]].copy()
+    pool_k[:], pool_v[:] = np.nan, np.nan  # the other layer, unreferenced pages, every entry past the bound
+    pool_k[layer, table[:walked]], pool_v[layer, table[:walked]] = kept_k, kept_v
+    pool_k[layer, partial, 3:] = 1e6  # stale-but-finite tail of the partial page
+    pool_v[layer, partial, 3:] = -1e6
+    poisoned = _attend(
+        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(table),
+        jnp.int32(length), jnp.int32(layer),
     )
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
+
+
+def _lanes(rng, lengths):
+    """A launch of ``len(lengths)`` slots on one pool: each lane's pages
+    are its own, and every page no lane holds is NaN."""
+    slots = len(lengths)
+    pool_k, pool_v = _pool(rng)
+    free = iter(rng.permutation(POOL_PAGES))
+    tables = np.zeros((slots, PPS), np.int32)
+    for lane, n in enumerate(lengths):
+        for j in range(-(-n // PS)):
+            tables[lane, j] = next(free)
+    held = np.unique(tables[np.arange(PPS)[None] < -(-np.asarray(lengths)[:, None] // PS)])
+    q, kn, vn = (jnp.stack(x) for x in zip(*(_window(rng, 1, 4 * KV) for _ in range(slots))))
+    return (
+        q, kn, vn, _poisoned(pool_k, 0, held), _poisoned(pool_v, 0, held),
+        jnp.asarray(tables), jnp.asarray(lengths, jnp.int32),
+    )
+
+
+# empty lanes before, between and after long ones, and an empty last lane
+LANE_LENGTHS = (0, 0, B * PS + 5, 0, 3, 2 * B * PS, 0, 0, PS, B * PS, 0)
+
+
+def test_slot_batched_launch_carries_the_pipeline_over_empty_lanes():
+    """Through ``jax.vmap``, the path the engine takes: a lane's last block
+    starts the first block of the next lane that holds anything, stepping
+    over empty lanes. Compared lane by lane with single-slot launches (which
+    carry nothing): no lane's first block is skipped or fetched twice into
+    the wrong buffer, an empty lane attends its own token alone, and a lane
+    whose table row is all page 0 past its bound never reads page 0."""
+    q, kn, vn, pool_k, pool_v, tables, lengths = _lanes(np.random.default_rng(5), LANE_LENGTHS)
+    got = np.asarray(_attend_slots(q, kn, vn, pool_k, pool_v, tables, lengths))
+    assert np.all(np.isfinite(got))
+    for lane, n in enumerate(LANE_LENGTHS):
+        alone = _attend(q[lane], kn[lane], vn[lane], pool_k, pool_v, tables[lane], lengths[lane], jnp.int32(0))
+        np.testing.assert_array_equal(got[lane], np.asarray(alone), err_msg=f"lane {lane}, length {n}")
+        if n == 0:
+            np.testing.assert_allclose(got[lane, 0, 0], np.repeat(np.asarray(vn[lane, 0, 0]), 4, axis=0), rtol=1e-6)
+
+
+def test_a_launch_leaves_nothing_behind_for_the_next():
+    """The same launch twice on one pool, then lanes in reverse order: the
+    buffers, the semaphores and the pipeline's state in SMEM outlive a
+    launch, and the first slot resets what the next launch reads of them."""
+    q, kn, vn, pool_k, pool_v, tables, lengths = _lanes(np.random.default_rng(6), LANE_LENGTHS)
+    first = np.asarray(_attend_slots(q, kn, vn, pool_k, pool_v, tables, lengths))
+    again = np.asarray(_attend_slots(q, kn, vn, pool_k, pool_v, tables, lengths))
+    np.testing.assert_array_equal(first, again)
+    flipped = np.asarray(
+        _attend_slots(q[::-1], kn[::-1], vn[::-1], pool_k, pool_v, tables[::-1], lengths[::-1])
+    )
+    np.testing.assert_array_equal(first, flipped[::-1])
 
 
 def test_zero_length_attends_only_new_token():
