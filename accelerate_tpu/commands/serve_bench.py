@@ -122,11 +122,11 @@ def register_subcommand(subparsers):
     )
     parser.add_argument(
         "--shared-prefix", type=int, default=0,
-        help="Prepend a common N-token system prompt to every request — a "
-        "paged engine prefills it once and COW-shares its pages",
+        help="Prepend a common N-token system prompt to every request — the "
+        "engine prefills it once and COW-shares its pages",
     )
     parser.add_argument(
-        "--page-size", type=int, default=16, help="Tokens per KV page (paged layout)"
+        "--page-size", type=int, default=16, help="Tokens per KV page"
     )
     parser.add_argument(
         "--prefill-chunk", type=int, default=None,
@@ -134,15 +134,10 @@ def register_subcommand(subparsers):
         "interleaved into the decode cadence (must be a multiple of --page-size)",
     )
     parser.add_argument(
-        "--no-paged", action="store_true",
-        help="Serve from the dense per-slot slab instead of the paged pool "
-        "(the comparison baseline)",
-    )
-    parser.add_argument(
         "--no-kernels", action="store_true",
         help="Disable the Pallas kernel layer (paged decode attention + "
         "fused dequant-matmul; docs/performance.md) — the gather/dequant "
-        "reference programs, mirroring --no-paged as the A/B baseline. "
+        "reference programs the kernels are tested against. "
         "Default: kernels ON (interpret mode off-TPU)",
     )
     parser.add_argument(
@@ -234,9 +229,6 @@ def run(args) -> int:
     if args.chaos is not None and n_replicas < 2:
         print(f"--chaos {args.chaos} needs >= 2 replicas (a 1-replica fleet has no failover)")
         return 1
-    if disagg and args.no_paged:
-        print("disaggregated serving relays page-granular KV — drop --no-paged")
-        return 1
     if args.autoscale and not disagg:
         print("--autoscale rebalances between pools — set --prefill-replicas "
               "and --decode-replicas")
@@ -255,9 +247,6 @@ def run(args) -> int:
 
     spec_cfg = None
     if args.speculative:
-        if args.no_paged:
-            print("--speculative verifies against the paged pool — drop --no-paged")
-            return 1
         if args.temperature != 0.0:
             print("--speculative is temperature-0 only (greedy verify)")
             return 1
@@ -358,7 +347,7 @@ def run(args) -> int:
         engine = ServingEngine(
             model, params, num_slots=args.num_slots, max_len=max_len,
             eos_token_id=args.eos_token_id, temperature=args.temperature,
-            paged=not args.no_paged, page_size=args.page_size,
+            page_size=args.page_size,
             prefill_chunk=args.prefill_chunk, tracer=tracer,
             use_kernels=use_kernels, speculative=spec_cfg,
         )
@@ -531,8 +520,7 @@ def run(args) -> int:
             if hasattr(warm_engine, "kernel_summary")
             else warm_engine.replicas[0].engine.kernel_summary()
         ),
-        "paged": not args.no_paged,
-        "page_size": args.page_size if not args.no_paged else None,
+        "page_size": args.page_size,
         "prefill_chunk": args.prefill_chunk,
         "speculative": (
             {
@@ -589,8 +577,6 @@ def run(args) -> int:
         f"paged(page_size={args.page_size}"
         + (f", chunk={args.prefill_chunk}" if args.prefill_chunk else "")
         + ")"
-        if not args.no_paged
-        else "dense slots"
     )
     ks = payload["kernels"]
     layout += (
@@ -644,7 +630,7 @@ def run(args) -> int:
             f"{point['slot_occupancy']:>9.2f}"
         )
     sat = points[-1]
-    if not args.no_paged and "page_occupancy" in sat:
+    if "page_occupancy" in sat:
         print(
             f"page economy (saturation): occupancy {sat['page_occupancy']:.2f}, "
             f"peak {sat['peak_pages_in_use']}/{sat['num_pages'] - 1} pages, "

@@ -1,4 +1,4 @@
-"""Continuous-batching inference: slot KV cache, scheduler, engine, fleet.
+"""Continuous-batching inference: paged KV cache, scheduler, engine, fleet.
 
 The inference side of the stack (see docs/serving.md): one fixed-shape
 jitted decode step stays hot while requests of any prompt length multiplex
@@ -42,7 +42,6 @@ from .fleet import (
 )
 from .kv_cache import (
     SlotAllocator,
-    SlotKVCache,
     bucket_for,
     kv_cache_bytes,
     paged_kv_cache_bytes,
@@ -80,7 +79,6 @@ __all__ = [
     "ServingResult",
     "ServingRouter",
     "SlotAllocator",
-    "SlotKVCache",
     "SpeculativeConfig",
     "StepWatchdog",
     "bucket_for",

@@ -141,11 +141,7 @@ def fleet_signals(router: Any) -> dict:
             pending += pending_prefill
         if role in ("decode", "mixed"):
             pending += pending_decode
-        paged = [
-            m.engine.cache.page_occupancy
-            for m in pool
-            if getattr(m.engine, "paged", False)
-        ]
+        page_occupancy = max((m.engine.cache.page_occupancy for m in pool), default=0.0)
         by_phase = getattr(router, "sheds_by_phase", {})
         sheds = 0
         if role in ("prefill", "mixed"):
@@ -159,7 +155,7 @@ def fleet_signals(router: Any) -> dict:
             "waiting": waiting,
             "pending": pending,
             "slot_occupancy": round(active / max(slots, 1), 4),
-            "page_occupancy": round(max(paged), 4) if paged else 0.0,
+            "page_occupancy": round(page_occupancy, 4),
             "pressure": round((active + waiting + pending) / max(slots, 1), 4),
             "sheds": sheds,
         }

@@ -6,30 +6,31 @@ FLOPs until the whole batch hits ``max_new_tokens``. The engine inverts
 this: ONE fixed-shape decode program stays hot forever and requests
 multiplex through it via the slot cache —
 
-- **memory** is PAGED by default (``serving/paging.py``, docs/serving.md): a
+- **memory** is PAGED (``serving/paging.py``, docs/serving.md): a
   fixed block pool ``[L, num_pages, page_size, KV, D]`` plus fixed-shape
   int32 page tables that ride into the decode step like ``lengths`` — a
   request holds pages for the tokens it actually produced, a shared system
   prompt's pages are prefilled once and reference-counted (COW) across every
-  concurrent request, and admission is gated on free pages. ``paged=False``
-  keeps the original per-slot slab (``kv_cache.py``) as the bit-equal
-  comparison baseline. A model whose layers are not all of one kind
-  (``models/exaone_moe.py``: it gives ``init_window_cache``) gets TWO kinds
-  of cached layer in the one manager: its full layers in the page pool, its
-  sliding-window layers in one ring a slot that holds the window and no
-  more, whatever the length. The paged programs then carry the rings beside
-  the pool, prefill is told how many of a bucket's tokens are real (padding
-  must not enter a ring), and the decode step's fetch brings the routed
-  experts' counters back with the tokens. What a ring cannot do (be shared
-  as a prefix, parked, handed off, rolled back after a rejected speculative
-  window) the engine refuses for such a model, by name;
+  concurrent request, and admission is gated on free pages. A model whose
+  layers are not all of one kind (``models/exaone_moe.py``: it gives
+  ``init_window_cache``) gets TWO kinds of cached layer in the one manager:
+  its full layers in the page pool, its sliding-window layers in one ring a
+  slot that holds the window and no more, whatever the length. What a lane
+  carries beside its pages is the cache's ``extras``, a pytree the decode and
+  prefill programs take after the pool and hand back: empty for a model of
+  one kind; for this one the rings and the routed experts' counters, so that
+  prefill is told how many of a bucket's tokens are real (padding must not
+  enter a ring) and the decode step's fetch brings the counters back with
+  the tokens. What a ring cannot do (be shared as a prefix, parked, handed
+  off, rolled back after a rejected speculative window) the engine refuses
+  for such a model, by name;
 - **decode** is the models' own ``forward_with_cache`` protocol ``vmap``-ed
   over the slot axis with per-slot lengths: the protocol is reused
-  *unchanged* (each slot sees a batch-of-1 cache view — gathered through its
-  page table when paged — and a scalar length), and the program's shapes
+  *unchanged* (each slot sees a batch-of-1 cache view, gathered through its
+  page table, and a scalar length), and the program's shapes
   never depend on which requests are in flight;
 - **prefill** runs the same protocol over a prompt padded to a power-of-two
-  bucket. Paged, the written pages scatter straight into the pool, and a
+  bucket. The written pages scatter straight into the pool, and a
   ``prefill_chunk`` setting splits long prompts into page-aligned chunks
   interleaved one-per-step into the decode cadence, so an already-admitted
   request's token stream never stalls behind a monolithic 4k-token prefill.
@@ -41,7 +42,7 @@ multiplex through it via the slot cache —
   frees slot and pages for the very next step, and recompute-style
   preemption of the youngest request under page pressure.
 
-After warmup (one prefill+insert program per bucket + one decode program),
+After warmup (one prefill program per span + one decode program),
 steady state compiles NOTHING — the acceptance invariant
 ``tests/test_serving.py`` pins with ``CompileTracker``.
 
@@ -77,7 +78,7 @@ from ..ops.runtime import kernels_default
 from ..telemetry import profiler
 from ..telemetry.serving import ServingStats
 from ..utils.jit_cache import dot_keyed_jit
-from .kv_cache import SlotKVCache, bucket_for, prefill_buckets
+from .kv_cache import bucket_for, prefill_buckets
 from .paging import PagedKVCache, paged_buckets, pages_for
 from .scheduler import ContinuousBatchingScheduler, QueueFull, Request  # noqa: F401 (re-export)
 
@@ -278,7 +279,6 @@ class ServingEngine:
         max_request_requeues: int = 2,
         name: Optional[str] = None,
         tracer: Any = None,
-        paged: bool = True,
         page_size: int = 16,
         num_pages: Optional[int] = None,
         prefill_chunk: Optional[int] = None,
@@ -297,45 +297,44 @@ class ServingEngine:
         self._sample = make_sampler(temperature)
         self._init_cache, self._fwc = resolve_decode_protocol(model)
         dtype = dtype if dtype is not None else params["embed_tokens"].dtype
-        self.paged = paged
         # two kinds of cached layer (module docstring): the model says so by
         # giving the window layers' rings; its full layers alone are paged
         init_window = getattr(model, "init_window_cache", None)
         if init_window is not None:
             self._refuse_for_window_layers(
-                not paged and "the dense slot cache (paged=False): it has one shape for every layer",
                 speculative is not None and "speculative decoding: a rejected window cannot be rolled back out of a ring",
                 prefix_sharing and "prefix sharing: a shared page holds the full layers' K/V, and no ring to resume from",
             )
             self._init_cache = model.init_kv_pool
         if prefix_sharing is None:
             prefix_sharing = init_window is None
+        # routed experts over a held share (models/moe.py:dropless_experts):
+        # [sparse layers, held experts], the shape of what the model's decode
+        # protocol counts under ``moe_held`` and the decode step's fetch brings
+        # home; None for a model without sparse layers
+        sparse = len(getattr(model, "sparse_layers", ())) if init_window is not None else 0
+        self._experts_shape = (sparse, model.experts_here) if sparse else None
+        self._held_counts = sparse * model.experts_here if sparse else 0
+        self.cache = PagedKVCache(
+            self._init_cache, num_slots, max_len, page_size=page_size,
+            num_pages=num_pages, dtype=dtype, prefix_entries=prefix_cache_entries,
+            # beside the rings: the tokens each held expert was chosen by, a
+            # sparse layer, and the (layer, held expert) pairs hit
+            init_window=init_window, counters=self._held_counts + 1,
+        )
         base_buckets = tuple(buckets) if buckets is not None else prefill_buckets(max_len - 1)
-        if paged:
-            self.cache = PagedKVCache(
-                self._init_cache, num_slots, max_len, page_size=page_size,
-                num_pages=num_pages, dtype=dtype, prefix_entries=prefix_cache_entries,
-                init_window=init_window,
-            )
-            if prefill_chunk is not None:
-                if prefill_chunk < page_size or prefill_chunk % page_size:
-                    raise ValueError(
-                        f"prefill_chunk {prefill_chunk} must be a multiple of "
-                        f"page_size {page_size}"
-                    )
-                base_buckets = base_buckets + (prefill_chunk,)
-            # prefill spans scatter whole pages, so buckets round to page
-            # multiples (capped at the pool-backed view length)
-            self.buckets = paged_buckets(base_buckets, page_size, self.cache.view_len)
-            self.prefill_chunk = prefill_chunk
-            self.prefix_sharing = prefix_sharing
-        else:
-            self.cache = SlotKVCache(self._init_cache, num_slots, max_len, dtype=dtype)
-            self.buckets = base_buckets
-            if max(self.buckets) > max_len:
-                raise ValueError(f"largest bucket {max(self.buckets)} exceeds max_len {max_len}")
-            self.prefill_chunk = None
-            self.prefix_sharing = False
+        if prefill_chunk is not None:
+            if prefill_chunk < page_size or prefill_chunk % page_size:
+                raise ValueError(
+                    f"prefill_chunk {prefill_chunk} must be a multiple of "
+                    f"page_size {page_size}"
+                )
+            base_buckets = base_buckets + (prefill_chunk,)
+        # prefill spans scatter whole pages, so buckets round to page
+        # multiples (capped at the pool-backed view length)
+        self.buckets = paged_buckets(base_buckets, page_size, self.cache.view_len)
+        self.prefill_chunk = prefill_chunk
+        self.prefix_sharing = prefix_sharing
         # -- kernel layer (ops/: docs/performance.md "Kernel layer") --------
         # None resolves by backend: on for real TPUs (the kernels are the
         # fast path), off for CPU/GPU meshes so every pre-kernel program —
@@ -346,20 +345,17 @@ class ServingEngine:
         self._kernel_fallback_reason: Optional[str] = None
         self._use_decode_kernel = False
         if self.use_kernels:
-            if not paged:
-                self._kernel_fallback_reason = "dense slot cache (paged=False)"
-            else:
-                from ..ops.paged_attention import paged_kernel_fallback_reason
+            from ..ops.paged_attention import paged_kernel_fallback_reason
 
-                cfg = getattr(model, "config", None)
-                nh = getattr(cfg, "num_heads", None)
-                kv = self.cache.k.shape[-2]
-                if nh is None:
-                    self._kernel_fallback_reason = "model exposes no head-count config"
-                else:
-                    self._kernel_fallback_reason = paged_kernel_fallback_reason(
-                        self.cache.k.shape[1:], nh, kv
-                    )
+            cfg = getattr(model, "config", None)
+            nh = getattr(cfg, "num_heads", None)
+            kv = self.cache.k.shape[-2]
+            if nh is None:
+                self._kernel_fallback_reason = "model exposes no head-count config"
+            else:
+                self._kernel_fallback_reason = paged_kernel_fallback_reason(
+                    self.cache.k.shape[1:], nh, kv
+                )
             self._use_decode_kernel = self._kernel_fallback_reason is None
             if not self._use_decode_kernel:
                 from ..logging import get_logger
@@ -372,7 +368,6 @@ class ServingEngine:
         self.scheduler = ContinuousBatchingScheduler(num_slots, max_queue=max_queue)
         self._pending = np.zeros((num_slots,), np.int32)  # next input token per slot
         self._rng = rng if rng is not None else jax.random.key(0)
-        self._prefill_caches: dict[int, dict] = {}  # zero cache template per bucket
         # cache donation halves decode HBM traffic; unsupported on CPU (warns)
         self._donate = jax.default_backend() in ("tpu", "gpu")
         # -- speculative decoding (serving/speculative.py) ------------------
@@ -387,11 +382,6 @@ class ServingEngine:
             from ..models.generation import resolve_window_protocol
             from .speculative import SpeculativeState
 
-            if not paged:
-                raise ValueError(
-                    "speculative decoding needs the paged engine (paged=True): "
-                    "the draft pool shares the page tables"
-                )
             if self.temperature != 0.0:
                 raise ValueError(
                     "speculative decoding is temperature-0 only (acceptance is "
@@ -410,11 +400,7 @@ class ServingEngine:
             self.spec = SpeculativeState(speculative, self.cache, donate=self._donate)
             self._fwd_window = resolve_window_protocol(model)
         self.telemetry = telemetry
-        self.stats = ServingStats(
-            num_slots,
-            num_pages=self.cache.num_pages if paged else None,
-            page_size=page_size if paged else None,
-        )
+        self.stats = ServingStats(num_slots, self.cache.num_pages, page_size)
         # wait-quote baseline (reset_service_estimate): quotes price from
         # stats deltas past this snapshot, so a role flip can discard the
         # old role's service rates without touching the telemetry counters
@@ -461,25 +447,14 @@ class ServingEngine:
         # (pages still refcounted in the pool; lane already freed). The router
         # acks adoption with release_parked(), or re-seats via resume_parked()
         self._parked: dict[int, dict] = {}
-        # routed experts over a held share (models/moe.py:dropless_experts):
-        # [sparse layers, held experts], the shape of what the model's decode
-        # protocol counts under ``moe_held`` and the two-kind programs bring
-        # home; None for a model without sparse layers
-        sparse = len(getattr(model, "sparse_layers", ())) if self.windowed else 0
-        self._experts_shape = (sparse, model.experts_here) if sparse else None
-        self._held_counts = sparse * model.experts_here if sparse else 0
         if self._experts_shape is not None:
             self.stats.moe_tokens_by_held_expert = np.zeros((model.experts_here,), np.int64)
-        if self.windowed:
-            # what the prefill programs count of the routed experts, passed
-            # from one to the next on the device until a decode step fetches it
-            self._prefill_counts = jnp.zeros((self._held_counts + 1,), jnp.int32)
 
     @property
     def windowed(self) -> bool:
         """Whether the model has sliding-window layers, kept in rings beside
         the page pool (``serving/paging.py``)."""
-        return self.paged and self.cache.windowed
+        return self.cache.windowed
 
     @staticmethod
     def _refuse_for_window_layers(*reasons) -> None:
@@ -494,94 +469,6 @@ class ServingEngine:
     def _jit(self, key, build):
         return dot_keyed_jit(self.model, "_jit_cache", key, build)
 
-    def _decode_program(self):
-        fwc, sample = self._fwc, self._sample
-
-        def build():
-            def decode_step(params, k, v, tokens, lengths, active, keys):
-                def one_slot(token, k1, v1, length, key):
-                    # a batch-of-1 view of the slot: the decode protocol runs
-                    # UNCHANGED — vmap supplies the per-slot length, which
-                    # drives positions and the causal-over-cache mask inside
-                    cache = {"k": k1[:, None], "v": v1[:, None], "length": length}
-                    logits, nc = fwc(params, token[None, None], cache)
-                    # per-slot finite verdict: the quarantine trigger AND the
-                    # quarantined slot's probe, computed where the logits are
-                    ok = jnp.all(jnp.isfinite(logits))
-                    return sample(logits, key)[0], ok, nc["k"][:, 0], nc["v"][:, 0]
-
-                nxt, ok, k2, v2 = jax.vmap(
-                    one_slot, in_axes=(0, 1, 1, 0, 0), out_axes=(0, 0, 1, 1)
-                )(tokens, k, v, lengths, keys)
-                return jnp.where(active, nxt, jnp.int32(0)), ok, k2, v2
-
-            donate = (1, 2) if self._donate else ()
-            return jax.jit(decode_step, donate_argnums=donate)
-
-        # _donate is part of the key: engines sharing one model (same program
-        # cache) may differ on backend donation, and a donating program served
-        # where donation was off (or vice versa) is silently wrong
-        return self._jit(
-            ("serve_decode", self.cache.num_slots, self.cache.max_len, self.temperature,
-             self._donate),
-            build,
-        )
-
-    def _prefill_program(self, bucket: int):
-        fwc = self._fwc
-
-        def build():
-            def prefill(params, ids, cache):
-                _, nc = fwc(params, ids, cache)  # logits dropped by design
-                return nc["k"], nc["v"]  # [L, 1, bucket, KV, D]
-
-            return jax.jit(prefill)
-
-        return self._jit(("serve_prefill", bucket), build)
-
-    def _scrub_program(self):
-        """Zero one slot's K/V. Quarantine needs it: non-finite values left in
-        a slot poison every later decode of that slot through the attention
-        matmul — a masked position's softmax weight is exactly 0.0, but
-        0 × NaN is still NaN, so masking alone cannot contain the damage.
-        Compiled lazily on the first quarantine (never in a healthy run)."""
-
-        def build():
-            def scrub(k, v, slot):
-                zeros = jnp.zeros((k.shape[0], 1) + k.shape[2:], k.dtype)
-                k = jax.lax.dynamic_update_slice(k, zeros, (0, slot, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(v, zeros.astype(v.dtype), (0, slot, 0, 0, 0))
-                return k, v
-
-            donate = (0, 1) if self._donate else ()
-            return jax.jit(scrub, donate_argnums=donate)
-
-        return self._jit(
-            ("serve_scrub", self.cache.num_slots, self.cache.max_len, self._donate), build
-        )
-
-    def _insert_program(self, bucket: int):
-        def build():
-            def insert(k, v, slot_k, slot_v, slot):
-                k = jax.lax.dynamic_update_slice(k, slot_k.astype(k.dtype), (0, slot, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(v, slot_v.astype(v.dtype), (0, slot, 0, 0, 0))
-                return k, v
-
-            donate = (0, 1) if self._donate else ()
-            return jax.jit(insert, donate_argnums=donate)
-
-        return self._jit(
-            ("serve_insert", bucket, self.cache.num_slots, self.cache.max_len, self._donate),
-            build,
-        )
-
-    def _prefill_cache(self, bucket: int) -> dict:
-        """Zero cache template per bucket — jax arrays are immutable, so one
-        template serves every admission at that bucket."""
-        if bucket not in self._prefill_caches:
-            self._prefill_caches[bucket] = self._init_cache(1, bucket, dtype=self.cache.dtype)
-        return self._prefill_caches[bucket]
-
     # -- paged programs (serving/paging.py; docs/serving.md) ----------------
     #
     # Every paged program takes the page tables as a fixed-shape int32 ARG
@@ -589,10 +476,11 @@ class ServingEngine:
     # scan would flag it), gathers a slot's pages into a contiguous view, and
     # runs the models' decode protocol UNCHANGED over that view. Masked
     # positions beyond a slot's length read whatever the gathered pages hold,
-    # but contribute exactly-zero softmax weight, so paged and slot decode
-    # are bit-equal at temperature 0 — provided every reachable page stays
-    # FINITE (0 × NaN = NaN): inactive/probe lanes therefore write sanitized
-    # zeros to the null page, and quarantine scrubs freed pages on device.
+    # but contribute exactly-zero softmax weight, so paged decode and the
+    # sequential ``generate()`` are bit-equal at temperature 0 — provided
+    # every reachable page stays FINITE (0 × NaN = NaN): inactive/probe lanes
+    # therefore write sanitized zeros to the null page, and quarantine scrubs
+    # freed pages on device.
 
     @staticmethod
     def _gathered_view(pool_k, pool_v, row, length, layer=None):
@@ -615,15 +503,39 @@ class ServingEngine:
         return {"k": taken_k.reshape(shape), "v": taken_v.reshape(shape), "length": length}
 
     def _paged_decode_program(self):
-        if self.windowed:
-            return self._two_kind_decode_program()
+        """The decode step over every lane: ``decode_step(params, pk, pv,
+        extras, tokens, lengths, active, tables, keys) -> (fetched, ok, pk,
+        pv, *extras)``, with ``fetched`` the lanes' tokens ``[S]``.
+        ``extras`` is what a lane carries beside its pages
+        (``PagedKVCache.extras``): one body serves whatever it holds, and an
+        empty one is no argument of the compiled program. Where it holds the
+        window layers' rings ``wk``/``wv`` ``[Lw, S, KV, R, D]`` and
+        ``counts``, each lane attends its own ring (mapped over the slot
+        axis); the new token's K/V of the window layers come back as deltas
+        like the full layers' and are written at entry ``length % R`` of the
+        lane's ring. The routed experts see all lanes' tokens as one batch
+        (``models/moe.py``'s batching rule), and the tokens each held expert
+        was chosen by, a sparse layer, over the ACTIVE lanes, ride home behind
+        the tokens in the one fetched vector, and behind them what the prefill
+        programs since the last step counted (``counts``, which they pass from
+        one to the next on the device): ``[S + sparse layers * held experts +
+        that and one more]`` int32."""
         fwc, sample = self._fwc, self._sample
         ps = self.cache.page_size
         gathered = self._gathered_view
         use_kernel = self._use_decode_kernel
 
         def build():
-            def decode_step(params, pk, pv, tokens, lengths, active, tables, keys):
+            def decode_step(params, pk, pv, extras, tokens, lengths, active, tables, keys):
+                rings = extras[:2]  # (wk, wv), or nothing
+                kinds = ("k", "v", "wk", "wv")[: 2 + len(rings)]
+
+                def beside(ring):  # this lane's rings, as the protocol takes them
+                    return {kind: r[:, None] for kind, r in zip(kinds[2:], ring)}
+
+                def counted(nc):  # what a model with rings counts of its routed experts
+                    return (nc["moe_held"],) if rings else ()
+
                 if use_kernel:
                     # the Pallas path (ops/paged_attention.py): attention
                     # reads the stacked pool IN PLACE, by (the protocol's
@@ -641,24 +553,29 @@ class ServingEngine:
                             q, kn, vn, c["k"], c["v"], c["table"], c["length"], c["layer"]
                         )
 
-                    def one_slot(token, row, length, key):
+                    def one_slot(token, row, length, key, *ring):
                         cache = {"k": pk, "v": pv, "length": length,
-                                 "table": row, "attend": attend}
+                                 "table": row, "attend": attend, **beside(ring)}
                         logits, nc = fwc(params, token[None, None], cache)
                         ok = jnp.all(jnp.isfinite(logits))
-                        return sample(logits, key)[0], ok, nc["k"][:, 0, 0], nc["v"][:, 0, 0]
+                        return sample(logits, key)[0], ok, *(nc[kind][:, 0, 0] for kind in kinds), *counted(nc)
                 else:
-                    def one_slot(token, row, length, key):
-                        cache = gathered(pk, pv, row, length)
+                    def one_slot(token, row, length, key, *ring):
+                        cache = {**gathered(pk, pv, row, length), **beside(ring)}
                         logits, nc = fwc(params, token[None, None], cache)
                         ok = jnp.all(jnp.isfinite(logits))
-                        # only position `length` changed: extract it for the
-                        # write-back scatter instead of re-scattering the view
-                        wk = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], length, 1, axis=1)[:, 0]
-                        wv = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], length, 1, axis=1)[:, 0]
-                        return sample(logits, key)[0], ok, wk, wv
+                        # only position `length` changed (entry `length % R`
+                        # of a ring): extract it for the write-backs below
+                        # instead of re-scattering the view
+                        new = [jax.lax.dynamic_slice_in_dim(nc[kind][:, 0], length, 1, axis=1)[:, 0]
+                               for kind in kinds[:2]]
+                        new += [jax.lax.dynamic_slice_in_dim(nc[kind][:, 0], length % r.shape[2], 1, axis=2)[:, :, 0]
+                                for kind, r in zip(kinds[2:], ring)]
+                        return sample(logits, key)[0], ok, *new, *counted(nc)
 
-                nxt, ok, wk, wv = jax.vmap(one_slot)(tokens, tables, lengths, keys)
+                nxt, ok, fk, fv, *of_rings = jax.vmap(one_slot, in_axes=(0, 0, 0, 0) + (1,) * len(rings))(
+                    tokens, tables, lengths, keys, *rings
+                )
                 # write-back: active slots append at (table[length // ps],
                 # length % ps); inactive and probe lanes redirect to the null
                 # page — with ZEROED values, so the shared null page stays
@@ -666,103 +583,34 @@ class ServingEngine:
                 wpage = jnp.take_along_axis(tables, (lengths // ps)[:, None], axis=1)[:, 0]
                 wpage = jnp.where(active, wpage, 0)
                 woff = jnp.where(active, lengths % ps, 0)
-                lane = active.reshape((-1,) + (1,) * (wk.ndim - 1))
-                wk = jnp.where(lane, wk.astype(pk.dtype), jnp.zeros((), pk.dtype))
-                wv = jnp.where(lane, wv.astype(pv.dtype), jnp.zeros((), pv.dtype))
-                pk = pk.at[:, wpage, woff].set(jnp.moveaxis(wk, 0, 1))
-                pv = pv.at[:, wpage, woff].set(jnp.moveaxis(wv, 0, 1))
-                return jnp.where(active, nxt, jnp.int32(0)), ok, pk, pv
-
-            donate = (1, 2) if self._donate else ()
-            return jax.jit(decode_step, donate_argnums=donate)
-
-        return self._jit(
-            ("serve_paged_decode", self.cache.num_slots, self.cache.view_len, ps,
-             self.temperature, self._donate, use_kernel),
-            build,
-        )
-
-    def _two_kind_decode_program(self):
-        """The paged decode step of a model with two kinds of cached layer:
-        ``_paged_decode_program`` with the window layers' rings ``wk``/``wv``
-        ``[Lw, S, KV, R, D]`` beside the pool. Each lane attends its own ring
-        (mapped over the slot axis); the new token's K/V of the window layers
-        come back as deltas like the full layers' and are written at entry
-        ``length % R`` of the lane's ring. The routed experts see all lanes'
-        tokens as one batch (``models/moe.py``'s batching rule), and the
-        tokens each held expert was chosen by, a sparse layer, over the ACTIVE
-        lanes, ride home behind the tokens in the one fetched vector, and
-        behind them what the prefill programs since the last step counted
-        (``counts``, which they pass from one to the next on the device):
-        ``[S + sparse layers * held experts + that and one more]`` int32."""
-        fwc, sample = self._fwc, self._sample
-        ps = self.cache.page_size
-        gathered = self._gathered_view
-        use_kernel = self._use_decode_kernel
-
-        def build():
-            def decode_step(params, pk, pv, wk, wv, counts, tokens, lengths, active, tables, keys):
-                ring = wk.shape[3]
-                if use_kernel:
-                    from ..ops.paged_attention import paged_decode_attention
-
-                    def attend(q, kn, vn, c):
-                        return paged_decode_attention(
-                            q, kn, vn, c["k"], c["v"], c["table"], c["length"], c["layer"]
-                        )
-
-                    def one_slot(token, row, length, key, wk1, wv1):
-                        cache = {"k": pk, "v": pv, "length": length, "table": row, "attend": attend,
-                                 "wk": wk1[:, None], "wv": wv1[:, None]}
-                        logits, nc = fwc(params, token[None, None], cache)
-                        ok = jnp.all(jnp.isfinite(logits))
-                        return (sample(logits, key)[0], ok, nc["k"][:, 0, 0], nc["v"][:, 0, 0],
-                                nc["wk"][:, 0, 0], nc["wv"][:, 0, 0], nc["moe_held"])
-                else:
-                    def one_slot(token, row, length, key, wk1, wv1):
-                        cache = {**gathered(pk, pv, row, length), "wk": wk1[:, None], "wv": wv1[:, None]}
-                        logits, nc = fwc(params, token[None, None], cache)
-                        ok = jnp.all(jnp.isfinite(logits))
-                        # only position `length` changed in either kind: extract
-                        # it for the write-backs below
-                        fk = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], length, 1, axis=1)[:, 0]
-                        fv = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], length, 1, axis=1)[:, 0]
-                        rk = jax.lax.dynamic_slice_in_dim(nc["wk"][:, 0], length % ring, 1, axis=2)[:, :, 0]
-                        rv = jax.lax.dynamic_slice_in_dim(nc["wv"][:, 0], length % ring, 1, axis=2)[:, :, 0]
-                        return sample(logits, key)[0], ok, fk, fv, rk, rv, nc["moe_held"]
-
-                nxt, ok, fk, fv, rk, rv, held = jax.vmap(one_slot, in_axes=(0, 0, 0, 0, 1, 1))(
-                    tokens, tables, lengths, keys, wk, wv
-                )
-                # write-backs: inactive and probe lanes write ZEROS to the null
-                # page for the full layers, and for the window layers leave
-                # their ring as it was: a lane in the middle of a chunked
-                # prefill is inactive at length 0, and its ring holds the
-                # chunks' live K/V (scrubbed of poison where a lane is quarantined)
                 lane = active.reshape((-1,) + (1,) * (fk.ndim - 1))
-                wpage = jnp.take_along_axis(tables, (lengths // ps)[:, None], axis=1)[:, 0]
-                wpage = jnp.where(active, wpage, 0)
-                woff = jnp.where(active, lengths % ps, 0)
                 fk = jnp.where(lane, fk.astype(pk.dtype), jnp.zeros((), pk.dtype))
                 fv = jnp.where(lane, fv.astype(pv.dtype), jnp.zeros((), pv.dtype))
                 pk = pk.at[:, wpage, woff].set(jnp.moveaxis(fk, 0, 1))
                 pv = pv.at[:, wpage, woff].set(jnp.moveaxis(fv, 0, 1))
-                lanes, entry = jnp.arange(wk.shape[1]), lengths % ring
+                if not rings:
+                    return jnp.where(active, nxt, jnp.int32(0)), ok, pk, pv
+                # the rings' write-back: inactive and probe lanes leave their
+                # ring as it was: a lane in the middle of a chunked prefill is
+                # inactive at length 0, and its ring holds the chunks' live
+                # K/V (scrubbed of poison where a lane is quarantined)
+                (wk, wv, counts), (rk, rv, held) = extras, of_rings
+                lanes, entry = jnp.arange(wk.shape[1]), lengths % wk.shape[3]
                 rk = jnp.where(lane, rk.astype(wk.dtype), wk[:, lanes, :, entry])  # the indexed axes lead: [S, Lw, KV, D]
                 rv = jnp.where(lane, rv.astype(wv.dtype), wv[:, lanes, :, entry])
                 wk = wk.at[:, lanes, :, entry].set(rk)
                 wv = wv.at[:, lanes, :, entry].set(rv)
-                counted = jnp.sum(jnp.where(active[:, None, None], held, 0), axis=0)
+                held = jnp.sum(jnp.where(active[:, None, None], held, 0), axis=0)
                 fetched = jnp.concatenate(
-                    [jnp.where(active, nxt, jnp.int32(0)), counted.reshape(-1).astype(jnp.int32), counts]
+                    [jnp.where(active, nxt, jnp.int32(0)), held.reshape(-1).astype(jnp.int32), counts]
                 )
                 return fetched, ok, pk, pv, wk, wv, jnp.zeros_like(counts)
 
-            donate = (1, 2, 3, 4, 5) if self._donate else ()
+            donate = (1, 2, 3) if self._donate else ()
             return jax.jit(decode_step, donate_argnums=donate)
 
         return self._jit(
-            ("serve_two_kind_decode", self.cache.num_slots, self.cache.view_len, ps,
+            ("serve_paged_decode", self.cache.num_slots, self.cache.view_len, ps,
              self.temperature, self._donate, use_kernel),
             build,
         )
@@ -883,26 +731,32 @@ class ServingEngine:
         ``span // page_size`` written pages back into the pool. The cache
         view is the full gathered table, so a shared/chunked prefix is
         attended exactly as a monolithic prefill would — split points change
-        nothing but which pages get written."""
+        nothing but which pages get written. ``prefill(params, ids, pk, pv,
+        extras, row, start, real, slot) -> (pk, pv, *extras)``, with
+        ``extras`` as in the decode step. Where it holds rings, lane ``slot``'s
+        stand beside its gathered pages: the model attends the ring (what the
+        window layers kept before ``start``) and returns it holding the last
+        of the span's ``real`` tokens; a bucket's padding never enters it.
+        ``counts`` adds up, from program to program, the real tokens each held
+        expert was chosen by a layer, then the (layer, held expert) pairs a
+        program hit: the next decode step's fetch brings them home. Where it
+        is empty, ``real`` and ``slot`` are not read, and ``jit`` leaves them
+        out of the program."""
         fwc = self._fwc
         ps = self.cache.page_size
         n_pages = span // ps
         gathered = self._gathered_view
 
-        def build_two_kinds():
-            # the same span with the lane's rings beside its gathered pages:
-            # the model attends the ring (what the window layers kept before
-            # `start`) and returns it holding the last of the span's `real`
-            # tokens; a bucket's padding never enters it. `counts` adds up, from
-            # program to program, the real tokens each held expert was chosen
-            # by a layer, then the (layer, held expert) pairs a program hit:
-            # the next decode step's fetch brings them home
-            def prefill(params, ids, pk, pv, wk, wv, counts, row, start, real, slot):
+        def build():
+            def prefill(params, ids, pk, pv, extras, row, start, real, slot):
                 cache = gathered(pk, pv, row, start)
-                cache.update(
-                    wk=jax.lax.dynamic_index_in_dim(wk, slot, axis=1), wv=jax.lax.dynamic_index_in_dim(wv, slot, axis=1),
-                    real=real,
-                )
+                windowed = len(extras) > 0  # the pytree's structure: known as the program is traced
+                if windowed:
+                    wk, wv, counts = extras
+                    cache.update(
+                        wk=jax.lax.dynamic_index_in_dim(wk, slot, axis=1), wv=jax.lax.dynamic_index_in_dim(wv, slot, axis=1),
+                        real=real,
+                    )
                 _, nc = fwc(params, ids, cache)
                 new_k = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], start, span, axis=1)
                 new_v = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], start, span, axis=1)
@@ -910,33 +764,15 @@ class ServingEngine:
                 wids = jax.lax.dynamic_slice_in_dim(row, start // ps, n_pages)
                 pk = pk.at[:, wids].set(new_k.reshape(shape).astype(pk.dtype))
                 pv = pv.at[:, wids].set(new_v.reshape(shape).astype(pv.dtype))
-                wk = jax.lax.dynamic_update_index_in_dim(wk, nc["wk"][:, 0].astype(wk.dtype), slot, axis=1)
-                wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)
-                held = nc["moe_held"].reshape(-1).astype(jnp.int32)
-                counts = counts + jnp.concatenate([held, jnp.count_nonzero(held).astype(jnp.int32)[None]])
-                return pk, pv, wk, wv, counts
+                if windowed:
+                    wk = jax.lax.dynamic_update_index_in_dim(wk, nc["wk"][:, 0].astype(wk.dtype), slot, axis=1)
+                    wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)
+                    held = nc["moe_held"].reshape(-1).astype(jnp.int32)
+                    counts = counts + jnp.concatenate([held, jnp.count_nonzero(held).astype(jnp.int32)[None]])
+                    extras = (wk, wv, counts)
+                return pk, pv, *extras
 
-            donate = (2, 3, 4, 5, 6) if self._donate else ()
-            return jax.jit(prefill, donate_argnums=donate)
-
-        if self.windowed:
-            return self._jit(
-                ("serve_two_kind_prefill", span, self.cache.num_slots, self.cache.view_len, ps, self._donate),
-                build_two_kinds,
-            )
-
-        def build():
-            def prefill(params, ids, pk, pv, row, start):
-                _, nc = fwc(params, ids, gathered(pk, pv, row, start))
-                new_k = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], start, span, axis=1)
-                new_v = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], start, span, axis=1)
-                shape = (new_k.shape[0], n_pages, ps) + new_k.shape[2:]
-                wids = jax.lax.dynamic_slice_in_dim(row, start // ps, n_pages)
-                pk = pk.at[:, wids].set(new_k.reshape(shape).astype(pk.dtype))
-                pv = pv.at[:, wids].set(new_v.reshape(shape).astype(pv.dtype))
-                return pk, pv
-
-            donate = (2, 3) if self._donate else ()
+            donate = (2, 3, 4) if self._donate else ()
             return jax.jit(prefill, donate_argnums=donate)
 
         return self._jit(
@@ -945,24 +781,23 @@ class ServingEngine:
             build,
         )
 
+    def _prefill_arguments(self, row, start: int, real: int, slot: int) -> tuple:
+        """What a prefill span program is called with, after the weights and
+        the ids: ``real`` of the ids are tokens, at positions ``start ..`` of
+        ``slot``, whose table row is ``row``."""
+        cache = self.cache
+        return (cache.k, cache.v, cache.extras, row, np.int32(start), np.int32(real), np.int32(slot))
+
     def _run_prefill_span(self, span: int, ids, row, start: int, real: int, slot: int) -> None:
-        """Dispatch one prefill span program into the cache: ``real`` of the
-        ``span`` ids are tokens, at positions ``start ..`` of ``slot``, whose
-        table row is ``row`` (a copy)."""
-        cache, program = self.cache, self._paged_prefill_program(span)
-        if self.windowed:
-            cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts = program(
-                self.params, ids, cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts, row,
-                np.int32(start), np.int32(real), np.int32(slot),
-            )
-        else:
-            cache.k, cache.v = program(self.params, ids, cache.k, cache.v, row, np.int32(start))
+        """Dispatch one prefill span program into the cache (``row`` a copy)."""
+        self.cache.put(*self._paged_prefill_program(span)(
+            self.params, ids, *self._prefill_arguments(row, start, real, slot)
+        ))
 
     def _decode_arguments(self, keys) -> tuple:
         """What the paged decode program is called with, after the weights."""
         cache = self.cache
-        rings = (cache.wk, cache.wv, self._prefill_counts) if self.windowed else ()
-        return (cache.k, cache.v, *rings, self._pending, cache.lengths, cache.active, cache.tables, keys)
+        return (cache.k, cache.v, cache.extras, self._pending, cache.lengths, cache.active, cache.tables, keys)
 
     def _page_copy_program(self):
         """Copy one page ``src → dst``: the on-device half of copy-on-write
@@ -1079,10 +914,10 @@ class ServingEngine:
         step). After this, steady state compiles nothing regardless of the
         traffic mix — benchmarks call it so no measurement window ever
         straddles a compile. Each bucket's prompt uses a DISTINCT token so
-        paged prefix sharing cannot short-circuit a larger bucket's prefill
+        prefix sharing cannot short-circuit a larger bucket's prefill
         into a cached smaller one (which would leave its program uncompiled);
-        a paged engine additionally compiles EVERY prefill span program
-        (all buckets plus the chunk) directly, because traffic's schedules
+        EVERY prefill span program (all buckets plus the chunk) is
+        additionally compiled directly, because traffic's schedules
         — a prefix-hit tail, or ``_next_span``'s monolithic fallback — can
         select spans the synthetic requests' own schedules skip. Warmup
         prompts stay
@@ -1100,63 +935,63 @@ class ServingEngine:
                 length = min(bucket + 1, self.cache.max_len)
                 self.submit(np.full((length,), i + 1, np.int32), max_new_tokens=1)
             self.run()
-            if self.paged:
-                # the synthetic requests above only compile the spans THEIR
-                # schedules select; traffic can reach others (a prefix hit
-                # or coarse buckets route _next_span to a monolithic span
-                # the chunk cadence skipped). Compile every span program
-                # directly, writing into the null page — the designated
-                # sink, left finite by the zero-id prefill.
-                spans = set(self.buckets)
-                if self.prefill_chunk is not None:
-                    spans.add(self.prefill_chunk)
-                row = np.zeros((self.cache.pages_per_slot,), np.int32)
-                for span in sorted(spans):
-                    ids = np.zeros((1, span), np.int32)
-                    # no real token: a window layer's ring stays as it was
-                    self._run_prefill_span(span, ids, row, 0, 0, 0)
-                    if self.spec is not None:
-                        # every span program has a draft-pool mirror that
-                        # traffic (or catch-up) can select
-                        self.spec.prefill(span, ids, row, 0)
-                # the handoff pair (extract + adopt-insert) fires in steady
-                # state whenever this engine is a disaggregated pool member:
-                # compile both now against the null page (reading it is free,
-                # and re-inserting its own zeros changes nothing)
-                if not self.windowed:  # no handoff of a ring: adopt_kv refuses
-                    kb, vb = self.extract_pages([0])
-                    self.cache.k, self.cache.v = self._page_insert_program()(
-                        self.cache.k, self.cache.v, kb[0], vb[0], np.int32(0)
-                    )
+            # the synthetic requests above only compile the spans THEIR
+            # schedules select; traffic can reach others (a prefix hit
+            # or coarse buckets route _next_span to a monolithic span
+            # the chunk cadence skipped). Compile every span program
+            # directly, writing into the null page — the designated
+            # sink, left finite by the zero-id prefill.
+            spans = set(self.buckets)
+            if self.prefill_chunk is not None:
+                spans.add(self.prefill_chunk)
+            row = np.zeros((self.cache.pages_per_slot,), np.int32)
+            for span in sorted(spans):
+                ids = np.zeros((1, span), np.int32)
+                # no real token: a window layer's ring stays as it was
+                self._run_prefill_span(span, ids, row, 0, 0, 0)
                 if self.spec is not None:
-                    # the synthetic requests above never draft (1-token
-                    # budgets), so the draft decode launch — and tree mode's
-                    # top-B seed variant — must compile explicitly, against
-                    # all-inactive lanes (writes land in the null page).
-                    # The plain paged decode compiles the same way: it is
-                    # the chaos/disable fallback and must engage mid-stream
-                    # without a compile stall.
-                    zeros = np.zeros((self.cache.num_slots,), np.int32)
-                    inactive = np.zeros((self.cache.num_slots,), bool)
-                    self.spec.decode(zeros, zeros, inactive, self.cache.tables)
-                    if self.spec.config.mode == "tree":
-                        self.spec.decode(
-                            zeros, zeros, inactive, self.cache.tables,
-                            top_b=self.spec.config.num_branches,
-                        )
-                        # branch forking COW-copies the boundary page in BOTH
-                        # pools on every tree step — compile both copy
-                        # programs now (null page onto itself: an identity
-                        # write, free to run)
-                        self.cache.k, self.cache.v = self._page_copy_program()(
-                            self.cache.k, self.cache.v, np.int32(0), np.int32(0)
-                        )
-                        self.spec.copy_page(0, 0)
-                    keys = jax.random.split(self._rng, self.cache.num_slots)
-                    _, _, self.cache.k, self.cache.v = self._paged_decode_program()(
-                        self.params, self.cache.k, self.cache.v, zeros,
-                        zeros, inactive, self.cache.tables, keys,
+                    # every span program has a draft-pool mirror that
+                    # traffic (or catch-up) can select
+                    self.spec.prefill(span, ids, row, 0)
+            # the handoff pair (extract + adopt-insert) fires in steady
+            # state whenever this engine is a disaggregated pool member:
+            # compile both now against the null page (reading it is free,
+            # and re-inserting its own zeros changes nothing)
+            if not self.windowed:  # no handoff of a ring: adopt_kv refuses
+                kb, vb = self.extract_pages([0])
+                self.cache.k, self.cache.v = self._page_insert_program()(
+                    self.cache.k, self.cache.v, kb[0], vb[0], np.int32(0)
+                )
+            if self.spec is not None:
+                # the synthetic requests above never draft (1-token
+                # budgets), so the draft decode launch — and tree mode's
+                # top-B seed variant — must compile explicitly, against
+                # all-inactive lanes (writes land in the null page).
+                # The plain paged decode compiles the same way: it is
+                # the chaos/disable fallback and must engage mid-stream
+                # without a compile stall.
+                zeros = np.zeros((self.cache.num_slots,), np.int32)
+                inactive = np.zeros((self.cache.num_slots,), bool)
+                self.spec.decode(zeros, zeros, inactive, self.cache.tables)
+                if self.spec.config.mode == "tree":
+                    self.spec.decode(
+                        zeros, zeros, inactive, self.cache.tables,
+                        top_b=self.spec.config.num_branches,
                     )
+                    # branch forking COW-copies the boundary page in BOTH
+                    # pools on every tree step — compile both copy
+                    # programs now (null page onto itself: an identity
+                    # write, free to run)
+                    self.cache.k, self.cache.v = self._page_copy_program()(
+                        self.cache.k, self.cache.v, np.int32(0), np.int32(0)
+                    )
+                    self.spec.copy_page(0, 0)
+                keys = jax.random.split(self._rng, self.cache.num_slots)
+                _, _, *handed_back = self._paged_decode_program()(
+                    self.params, self.cache.k, self.cache.v, self.cache.extras, zeros,
+                    zeros, inactive, self.cache.tables, keys,
+                )
+                self.cache.put(*handed_back)
         finally:
             self.scheduler.max_queue = cap
             self._warming = False
@@ -1194,8 +1029,7 @@ class ServingEngine:
         PARKS the finished KV — lane freed, pages refcounted — emitting a
         ``"prefilled"`` result instead of decoding. The router relays the
         parked pages to a decode-pool replica via ``adopt_kv`` and acks with
-        ``release_parked``. Paged engines only: the dense slab has no
-        page-granular layout to relay."""
+        ``release_parked``."""
         with profiler.span("engine.submit") as live:
             request = self._enqueue(
                 prompt, max_new_tokens, request_id, submitted_at, deadline_s, prefill_only
@@ -1213,8 +1047,6 @@ class ServingEngine:
             raise ValueError("prompt must hold at least one token")
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        if prefill_only and not self.paged:
-            raise ValueError("prefill_only serving needs a paged engine (paged=True)")
         self._refuse_for_window_layers(
             prefill_only and self.windowed and "prefill_only: parking frees the lane, and the rings go with the lane"
         )
@@ -1232,28 +1064,27 @@ class ServingEngine:
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new_tokens}) "
                 f"exceeds the slot capacity max_len={self.cache.max_len}"
             )
-        if self.paged:
-            # feasibility, not pressure: a request the POOL can never hold
-            # must shed here — queued, it would deadlock admission forever.
-            # Two bounds matter: the total tokens the request will ever pin,
-            # AND the peak page count across the prefill schedule — every
-            # span is BUCKETED (padded up), so the FINAL chunk's padding can
-            # push the table past the raw token count mid-flight (chunked
-            # prefill still shrinks the peak vs one monolithic bucket, which
-            # is itself a reason to chunk on small pools)
-            ps = self.cache.page_size
-            need = max(pages_for(prefill_len + max_new_tokens, ps), 1)
-            done = 0
-            while done < prefill_len:
-                span = self._next_span(prefill_len - done, done)
-                need = max(need, (done + span) // ps)
-                done += min(span, prefill_len - done)
-            if need > self.cache.num_pages - 1:
-                raise ValueError(
-                    f"prompt ({prompt.size}) + max_new_tokens ({max_new_tokens}) "
-                    f"needs {need} pages but the pool holds "
-                    f"{self.cache.num_pages - 1} × {ps} tokens"
-                )
+        # feasibility, not pressure: a request the POOL can never hold
+        # must shed here — queued, it would deadlock admission forever.
+        # Two bounds matter: the total tokens the request will ever pin,
+        # AND the peak page count across the prefill schedule — every
+        # span is BUCKETED (padded up), so the FINAL chunk's padding can
+        # push the table past the raw token count mid-flight (chunked
+        # prefill still shrinks the peak vs one monolithic bucket, which
+        # is itself a reason to chunk on small pools)
+        ps = self.cache.page_size
+        need = max(pages_for(prefill_len + max_new_tokens, ps), 1)
+        done = 0
+        while done < prefill_len:
+            span = self._next_span(prefill_len - done, done)
+            need = max(need, (done + span) // ps)
+            done += min(span, prefill_len - done)
+        if need > self.cache.num_pages - 1:
+            raise ValueError(
+                f"prompt ({prompt.size}) + max_new_tokens ({max_new_tokens}) "
+                f"needs {need} pages but the pool holds "
+                f"{self.cache.num_pages - 1} × {ps} tokens"
+            )
         if self._draining:
             self.stats.record_reject()
             hint = self.retry_after_hint()
@@ -1423,13 +1254,10 @@ class ServingEngine:
 
     def _free_slot(self, request: Request):
         """The ``admit_ready`` callback: claim capacity for one queued
-        request, or None to leave it waiting. Slot mode = a free slot; paged
-        mode = a free lane AND pages for the first prefill span (admission
-        gated on pages, with a prefix-cache lookup deciding how many the
-        request actually needs)."""
+        request, or None to leave it waiting: a free lane AND pages for the
+        first prefill span (admission gated on pages, with a prefix-cache
+        lookup deciding how many the request actually needs)."""
         prefill_len = request.prompt.size - 1
-        if not self.paged:
-            return self.cache.admit(prefill_len)
         if self.cache.lanes.free_count == 0:
             # saturation fast path: no lane means no admission — skip the
             # prefix hash walk (which would also LRU-touch entries for a
@@ -1506,50 +1334,6 @@ class ServingEngine:
         ):
             return True
         return position + bucket_for(remaining, self.buckets) <= self.cache.view_len
-
-    def _admit(self, slot: int, request: Request, mark) -> None:
-        if self.paged:
-            # prefill runs in _advance_prefills (chunked: one span per step;
-            # monolithic: the whole suffix this same step) — admission only
-            # claimed capacity
-            if self.spec is not None:
-                # fresh seat: draft health is per-REQUEST, and a prefix hit's
-                # shared pages already carry the original request's mirrored
-                # draft content (speculative.py), so drafting resumes from
-                # the hit rather than position 0
-                self.spec.draft_ok[slot] = True
-                self.spec.draft_len[slot] = request.prefilled
-            return
-        prefill_len = request.prompt.size - 1
-        if prefill_len > 0:
-            bucket = bucket_for(prefill_len, self.buckets)
-            request.prefill_bucket = bucket
-            with mark(
-                "engine.prefill_dispatch", request=request.id, span=bucket,
-                tokens=prefill_len, position=0,
-            ):
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :prefill_len] = request.prompt[:-1]
-                if self.tracer is not None:
-                    # closed at this step's decode fence, the first host stamp
-                    # sequenced after the dispatched prefill's device work
-                    self.tracer.span_start(
-                        request.id, "prefill", replica=self.name,
-                        tokens=prefill_len, bucket=bucket,
-                    )
-                    self._prefill_open.add(request.id)
-                slot_k, slot_v = self._prefill_program(bucket)(
-                    self.params, ids, self._prefill_cache(bucket)
-                )
-                self.cache.k, self.cache.v = self._insert_program(bucket)(
-                    self.cache.k, self.cache.v, slot_k, slot_v, np.int32(slot)
-                )
-            self.stats.record_prefill(bucket, prefill_len)
-        # the prompt's last token is the first decode input: its logits ARE
-        # the request's first token, so prefill logits are never consumed
-        self._pending[slot] = request.prompt[-1]
-        if self.tracer is not None:
-            self.tracer.span_start(request.id, "decode", replica=self.name, slot=slot)
 
     # -- paged prefill / page-pressure machinery ----------------------------
 
@@ -2305,7 +2089,16 @@ class ServingEngine:
                         request.id, "admitted", stamp=request.admitted_at,
                         replica=self.name, slot=slot, prefix_hit=request.prefix_hit,
                     )
-                self._admit(slot, request, mark)
+                # admission only claimed capacity: prefill runs in
+                # _advance_prefills (chunked: one span per step; monolithic:
+                # the whole suffix this same step)
+                if self.spec is not None:
+                    # fresh seat: draft health is per-REQUEST, and a prefix hit's
+                    # shared pages already carry the original request's mirrored
+                    # draft content (speculative.py), so drafting resumes from
+                    # the hit rather than position 0
+                    self.spec.draft_ok[slot] = True
+                    self.spec.draft_len[slot] = request.prefilled
                 if not self._warming:  # warm-up's synthetic requests wait through compiles
                     wait = request.admitted_at - request.submitted_at
                     self.stats.record_admission(wait)
@@ -2315,21 +2108,20 @@ class ServingEngine:
                 live.set_metadata(admitted=admitted, queue_wait_ms_max=longest_wait * 1e3)
         stamp = time.perf_counter()
         phases["admit"] = stamp - t0
-        if self.paged:
-            # one prefill span per still-prefilling slot (chunked prefill
-            # interleaves long prompts into the step cadence), then make
-            # every decode write position privately backed (grow / COW)
-            with mark("engine.prefill") as live:
-                failed, programs = self._advance_prefills(mark)
-                finished.extend(failed)
-                if live is not None:
-                    live.set_metadata(programs=programs)
-            before, stamp = stamp, time.perf_counter()
-            phases["prefill"] = stamp - before
-            with mark("engine.prepare_writes"):
-                finished.extend(self._prepare_decode_writes())
-            before, stamp = stamp, time.perf_counter()
-            phases["prepare_writes"] = stamp - before
+        # one prefill span per still-prefilling slot (chunked prefill
+        # interleaves long prompts into the step cadence), then make
+        # every decode write position privately backed (grow / COW)
+        with mark("engine.prefill") as live:
+            failed, programs = self._advance_prefills(mark)
+            finished.extend(failed)
+            if live is not None:
+                live.set_metadata(programs=programs)
+        before, stamp = stamp, time.perf_counter()
+        phases["prefill"] = stamp - before
+        with mark("engine.prepare_writes"):
+            finished.extend(self._prepare_decode_writes())
+        before, stamp = stamp, time.perf_counter()
+        phases["prepare_writes"] = stamp - before
 
         # whether any lane decodes this step: a few cheap statements outside
         # every child span (the root's self time); the decode_dispatch phase's
@@ -2339,9 +2131,7 @@ class ServingEngine:
         if (not active_idx and not quarantined) or (
             # every occupied slot is still prefilling: no lane would decode,
             # so skip the device step — the next step() runs their next chunk
-            self.paged and not quarantined and not any(
-                self.cache.active[s] for s in active_idx
-            )
+            not quarantined and not any(self.cache.active[s] for s in active_idx)
         ):
             self._close_step(root, number, phases, stamp - t0)
             return finished
@@ -2382,25 +2172,11 @@ class ServingEngine:
                 # just its pending token — emit 1, the plain-decode token), and
                 # the quarantine probe rides the target's finite verdict as usual
                 tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
-            elif self.windowed:
-                cache = self.cache
-                nxt, ok, cache.k, cache.v, cache.wk, cache.wv, self._prefill_counts = self._paged_decode_program()(
-                    self.params, *self._decode_arguments(keys)
-                )
-            elif self.paged:
-                nxt, ok, self.cache.k, self.cache.v = self._paged_decode_program()(
-                    self.params, *self._decode_arguments(keys)
-                )
             else:
-                nxt, ok, self.cache.k, self.cache.v = self._decode_program()(
-                    self.params,
-                    self.cache.k,
-                    self.cache.v,
-                    self._pending,
-                    self.cache.lengths,
-                    self.cache.active,
-                    keys,
+                nxt, ok, *handed_back = self._paged_decode_program()(
+                    self.params, *self._decode_arguments(keys)
                 )
+                self.cache.put(*handed_back)
         before, stamp = stamp, time.perf_counter()
         phases[device_phase] = stamp - before
         held = None
@@ -2511,7 +2287,7 @@ class ServingEngine:
         for slot in active_idx:
             request = self.scheduler.slots[slot]
             if request is None or not self.cache.active[slot]:
-                # a still-prefilling paged slot (or a page-pressure casualty):
+                # a still-prefilling slot (or a page-pressure casualty):
                 # its lane ran as inactive this step — no token to deliver,
                 # no verdict to act on
                 continue
@@ -2543,33 +2319,26 @@ class ServingEngine:
                     self._resilience(
                         {"event": "quarantine", "slot": slot, "request_id": request.id}
                     )
-                if self.paged:
-                    # releases the lane AND the pages; fully-freed pages must
-                    # scrub on device before the pool recycles them
-                    freed = self.cache.quarantine(slot)
-                    if freed:
-                        mask = np.zeros((self.cache.num_pages,), bool)
-                        mask[freed] = True
-                        self.cache.k, self.cache.v = self._page_scrub_program()(
-                            self.cache.k, self.cache.v, mask
-                        )
-                        if self.spec is not None:
-                            # the draft pool recycles the same page ids: its
-                            # copies of the freed pages scrub too (0 × NaN)
-                            self.spec.scrub_pages(freed)
-                    if self.spec is not None:
-                        self.spec.draft_len[slot] = 0
-                    if self.windowed:
-                        # the lane's rings hold the poison too, and a masked
-                        # entry's 0 x NaN would fail every probe
-                        self.cache.wk, self.cache.wv = self._ring_scrub_program()(
-                            self.cache.wk, self.cache.wv, np.int32(slot)
-                        )
-                else:
-                    self.cache.quarantine(slot)
-                    self.cache.k, self.cache.v = self._scrub_program()(
-                        self.cache.k, self.cache.v, np.int32(slot)
+                # releases the lane AND the pages; fully-freed pages must
+                # scrub on device before the pool recycles them
+                freed = self.cache.quarantine(slot)
+                if freed:
+                    mask = np.zeros((self.cache.num_pages,), bool)
+                    mask[freed] = True
+                    self.cache.k, self.cache.v = self._page_scrub_program()(
+                        self.cache.k, self.cache.v, mask
                     )
+                    if self.spec is not None:
+                        # the draft pool recycles the same page ids: its
+                        # copies of the freed pages scrub too (0 × NaN)
+                        self.spec.scrub_pages(freed)
+                if self.spec is not None:
+                    self.spec.draft_len[slot] = 0
+                if self.windowed:
+                    # the lane's rings hold the poison too, and a masked
+                    # entry's 0 x NaN would fail every probe
+                    wk, wv, counts = self.cache.extras
+                    self.cache.extras = (*self._ring_scrub_program()(wk, wv, np.int32(slot)), counts)
                 self._pending[slot] = 0
                 self._probe_failures[slot] = 0
                 self.stats.record_quarantine()
@@ -2656,7 +2425,7 @@ class ServingEngine:
         self.stats.record_step(
             now - t0, active=len(active_idx), waiting=self.scheduler.waiting,
             tokens=delivered,
-            pages_in_use=self.cache.pages_in_use if self.paged else None,
+            pages_in_use=self.cache.pages_in_use,
             context=context,
         )
         return delivered, context
@@ -2691,21 +2460,11 @@ class ServingEngine:
 
     def _lower_decode(self):
         """AOT-lower the decode program against the live cache — the audit's
-        view of exactly the program ``step()`` runs. For a paged engine the
-        page tables ride as an argument here just as in ``step()``, so the
-        baked-constant scan proves no table ever froze into the program."""
+        view of exactly the program ``step()`` runs. The page tables ride as
+        an argument here just as in ``step()``, so the baked-constant scan
+        proves no table ever froze into the program."""
         keys = jax.random.split(self._rng, self.cache.num_slots)
-        if self.paged:
-            return self._paged_decode_program().lower(self.params, *self._decode_arguments(keys))
-        return self._decode_program().lower(
-            self.params,
-            self.cache.k,
-            self.cache.v,
-            self._pending,
-            self.cache.lengths,
-            self.cache.active,
-            keys,
-        )
+        return self._paged_decode_program().lower(self.params, *self._decode_arguments(keys))
 
     def _page_shape(self) -> tuple:
         """One page's block shape ``[L, page_size, KV, D]`` — the fixed unit
@@ -2729,10 +2488,8 @@ class ServingEngine:
         what order, holding how many valid positions, in what per-page
         shape). A PARKED request (prefill finished, awaiting adoption) is
         the transferable case — its dict carries ``parked: True`` and the
-        ``last_token`` the destination decodes first. None when the engine
-        is unpaged or the request holds no pages here."""
-        if not self.paged:
-            return None
+        ``last_token`` the destination decodes first. None when the request
+        holds no pages here."""
         parked = self._parked.get(request_id)
         if parked is not None:
             return {"slot": None, "parked": True, **parked}
@@ -2796,8 +2553,6 @@ class ServingEngine:
         (fatal: a retry cannot fix it); exhausted lanes/pages raise
         :class:`QueueFull` (transient: the router retries or falls back to
         re-prefill). Returns the adopted request id."""
-        if not self.paged:
-            raise ValueError("adopt_kv needs a paged engine (paged=True)")
         self._refuse_for_window_layers(
             self.windowed and "adopt_kv: a handoff moves pages, and the window layers' rings are in none"
         )
@@ -2901,9 +2656,7 @@ class ServingEngine:
         the router DEFER the handoff — parked KV waits at the source for the
         next fleet step — instead of burning transfer work (or its retry
         budget) against a saturated pool."""
-        if self._draining or not self.paged:
-            return False
-        if self.cache.lanes.free_count == 0:
+        if self._draining or self.cache.lanes.free_count == 0:
             return False
         return self.cache.pages.free_count + len(self.cache.prefix) >= n_pages
 
@@ -3059,45 +2812,38 @@ class ServingEngine:
         if include_prefill:
             for bucket in self.buckets:
                 ids = jax.ShapeDtypeStruct((1, bucket), jnp.int32)
-                if self.paged:
-                    lowered = self._paged_prefill_program(bucket).lower(
-                        self.params, ids, self.cache.k, self.cache.v,
-                        self.cache.tables[0], np.int32(0),
-                    )
-                else:
-                    lowered = self._prefill_program(bucket).lower(
-                        self.params, ids, self._prefill_cache(bucket)
-                    )
+                lowered = self._paged_prefill_program(bucket).lower(
+                    self.params, ids, *self._prefill_arguments(self.cache.tables[0], 0, 0, 0)
+                )
                 sub = audit_lowered(
                     lowered,
                     compile=False,
                     label=f"serving_prefill_{bucket}",
-                    # the paged prefill donates the pools it scatters into
-                    expect_donation=self.paged and self._donate,
-                    **audit_kwargs,
-                )
-                report.merge(sub, prefix=f"prefill_{bucket}")
-            if self.paged:
-                # the adopt/copy program (disaggregated handoff destination):
-                # donation must stay intact and the page index must ride as
-                # an argument — a baked page-table constant here would both
-                # recompile per adoption and bloat the program
-                shape = self._page_shape()
-                lowered = self._page_insert_program().lower(
-                    self.cache.k,
-                    self.cache.v,
-                    jax.ShapeDtypeStruct(shape, self.cache.k.dtype),
-                    jax.ShapeDtypeStruct(shape, self.cache.v.dtype),
-                    np.int32(0),
-                )
-                sub = audit_lowered(
-                    lowered,
-                    compile=False,
-                    label="serving_adopt_kv",
+                    # the prefill donates the pools it scatters into
                     expect_donation=self._donate,
                     **audit_kwargs,
                 )
-                report.merge(sub, prefix="adopt_kv")
+                report.merge(sub, prefix=f"prefill_{bucket}")
+            # the adopt/copy program (disaggregated handoff destination):
+            # donation must stay intact and the page index must ride as
+            # an argument — a baked page-table constant here would both
+            # recompile per adoption and bloat the program
+            shape = self._page_shape()
+            lowered = self._page_insert_program().lower(
+                self.cache.k,
+                self.cache.v,
+                jax.ShapeDtypeStruct(shape, self.cache.k.dtype),
+                jax.ShapeDtypeStruct(shape, self.cache.v.dtype),
+                np.int32(0),
+            )
+            sub = audit_lowered(
+                lowered,
+                compile=False,
+                label="serving_adopt_kv",
+                expect_donation=self._donate,
+                **audit_kwargs,
+            )
+            report.merge(sub, prefix="adopt_kv")
             if self.spec is not None:
                 # the speculative verify program: donation must survive the
                 # window widening, and the page tables/limits must ride as
@@ -3175,7 +2921,6 @@ class ServingEngine:
                 quant_mode = "mixed"
         return {
             "use_kernels": self.use_kernels,
-            "paged": self.paged,
             "decode_attention": "pallas" if self._use_decode_kernel else "gather_reference",
             # a model with sliding-window layers: they attend a ring a slot, under XLA
             "window_attention": "xla_ring" if self.windowed else None,

@@ -160,8 +160,8 @@ class EngineReplica:
         """Live load from the engine's own books: waiting requests plus
         occupied slots, normalized by slot count so replicas of different
         sizes compare fairly. The queue term dominates once slots fill —
-        exactly the signal ``retry_after_hint`` prices. A paged engine adds
-        its page-pool occupancy: pages are the scarcer resource under mixed
+        exactly the signal ``retry_after_hint`` prices. Its page-pool
+        occupancy is added: pages are the scarcer resource under mixed
         long/short traffic (one 4k prompt can pin most of a pool while its
         lane count looks idle), and a replica near page exhaustion would
         preempt or shed whatever the router places there."""
@@ -169,9 +169,7 @@ class EngineReplica:
         score = (scheduler.waiting + len(scheduler.active_slots)) / max(
             self.engine.cache.num_slots, 1
         )
-        if getattr(self.engine, "paged", False):
-            score += self.engine.cache.page_occupancy
-        return score
+        return score + self.engine.cache.page_occupancy
 
     # -- observations --------------------------------------------------------
 

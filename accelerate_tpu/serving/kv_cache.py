@@ -1,19 +1,12 @@
-"""Slot-based KV cache for continuous-batching inference.
+"""Lanes, prefill buckets and KV sizing for continuous-batching inference.
 
-This is the DENSE layout — the engine now defaults to the paged layout
-(``serving/paging.py``: a block pool + fixed-shape page tables + COW prefix
-sharing), and keeps this slab as the ``paged=False`` comparison baseline:
-``tests/test_paging.py`` pins the two bit-equal at temperature 0. This
-module also holds the shared sizing formulas (dense and paged) that the
-estimate CLI and bench price serving with.
-
-The cache is ONE preallocated region per layer — ``[L, num_slots, max_len,
-KV, D]`` — plus per-slot ``lengths``/``active`` host mirrors. A request of
-any prompt length occupies one slot without reshaping anything, so the decode
-step stays a single fixed-shape XLA program for the life of the engine:
-recompilation (the silent TPU serving killer — a new ``[B, S]`` per prompt
-shape in the batch-synchronous path) structurally cannot happen in steady
-state.
+The engine's KV memory is paged (``serving/paging.py``: a block pool +
+fixed-shape page tables + COW prefix sharing). This module holds what the
+paged cache is built from and priced with: the lane allocator
+(:class:`SlotAllocator`: one lane a request in flight, with quarantine), the
+prefill buckets, and the shared sizing formulas that the estimate CLI and
+bench price serving with — the pool's, and a slab's of ``max_len`` tokens a
+slot for comparison.
 
 Prefill is *bucketed*: prompts pad up to a small set of power-of-two lengths,
 so prefill compiles O(log S) programs instead of O(distinct prompt lengths).
@@ -30,9 +23,6 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import numpy as np
-
-import jax.numpy as jnp
 
 
 def prefill_buckets(max_prefill: int, min_bucket: int = 16) -> tuple[int, ...]:
@@ -61,11 +51,11 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
 def kv_cache_bytes(
     config, batch: int, max_seq_len: Optional[int] = None, dtype_bytes: int = 2
 ) -> int:
-    """Device bytes of the DENSE (slot-slab) KV cache: ``2 (k+v) × layers ×
-    kv_heads × head_dim × max_len × batch × dtype_bytes``. Kept as the
-    comparison baseline now that the engine pages by default — the paged
-    sizing is :func:`paged_kv_cache_bytes`. Shared with
-    ``accelerate-tpu estimate-memory`` so serve sizing includes the cache."""
+    """Device bytes of a DENSE KV cache, a slab of ``max_len`` tokens a slot:
+    ``2 (k+v) × layers × kv_heads × head_dim × max_len × batch ×
+    dtype_bytes``. What ``generate()`` allocates, and the figure
+    ``accelerate-tpu estimate-memory`` sets the engine's pool against — the
+    pool's sizing is :func:`paged_kv_cache_bytes`."""
     seq = max_seq_len if max_seq_len is not None else config.max_seq_len
     return int(
         2 * config.num_layers * config.kv_heads * config.dim_per_head * seq * batch * dtype_bytes
@@ -167,72 +157,3 @@ class SlotAllocator:
 
     def __contains__(self, slot: int) -> bool:
         return slot in self._in_use
-
-
-class SlotKVCache:
-    """Device arrays + host mirrors of the slot state.
-
-    ``k``/``v`` are whatever the model's ``init_cache(num_slots, max_len)``
-    allocates (``[L, num_slots, max_len, KV, D]`` for the zoo families) —
-    slot ``i`` is index ``i`` of the batch axis. ``lengths``/``active`` are
-    HOST arrays: they change every step and ride into the jitted decode step
-    as small ``[num_slots]`` transfers, keeping every device program
-    fixed-shape.
-    """
-
-    def __init__(self, init_cache, num_slots: int, max_len: int, dtype=jnp.bfloat16):
-        if max_len < 2:
-            raise ValueError(f"max_len must be >= 2 (prompt + one token), got {max_len}")
-        cache = init_cache(num_slots, max_len, dtype=dtype)
-        self.k, self.v = cache["k"], cache["v"]
-        self.num_slots = num_slots
-        self.max_len = max_len
-        self.dtype = dtype
-        self.lengths = np.zeros((num_slots,), np.int32)
-        self.active = np.zeros((num_slots,), bool)
-        self.allocator = SlotAllocator(num_slots)
-
-    @property
-    def nbytes(self) -> int:
-        return int(self.k.nbytes + self.v.nbytes)
-
-    @property
-    def occupancy(self) -> float:
-        return self.allocator.occupancy
-
-    def admit(self, length: int) -> Optional[int]:
-        """Claim a slot for a request whose cache currently holds ``length``
-        valid positions (the prefilled ``prompt[:-1]``)."""
-        slot = self.allocator.admit()
-        if slot is None:
-            return None
-        self.lengths[slot] = length
-        self.active[slot] = True
-        return slot
-
-    def retire(self, slot: int) -> None:
-        """Free ``slot``. No device work: stale K/V past a slot's length are
-        never readable (decode mask) and the next occupant's prefill insert
-        overwrites the prefix."""
-        self.allocator.retire(slot)
-        self.lengths[slot] = 0
-        self.active[slot] = False
-
-    def quarantine(self, slot: int) -> None:
-        """Take a poisoned slot out of circulation. ``length`` resets to 0 so
-        the probe decode (token 0 over an empty cache — its own K/V write is
-        the only visible position) exercises the slot without reading the
-        suspect prefix."""
-        self.allocator.quarantine(slot)
-        self.lengths[slot] = 0
-        self.active[slot] = False
-
-    def release_quarantined(self, slot: int) -> None:
-        """Probe passed: the slot may serve requests again."""
-        self.allocator.release(slot)
-        self.lengths[slot] = 0
-        self.active[slot] = False
-
-    @property
-    def quarantined(self) -> frozenset:
-        return self.allocator.quarantined

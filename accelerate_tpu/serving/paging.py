@@ -1,9 +1,9 @@
 """Paged KV memory for the serving engine: block pool, page tables, COW
 prefix sharing.
 
-The slot cache (``kv_cache.py``) reserves ``max_len`` tokens of HBM per slot
-whether a request uses them or not. The paged layout replaces the per-slot
-slab with one fixed pool of ``page_size``-token blocks —
+A slab a slot would reserve ``max_len`` tokens of HBM per slot whether a
+request uses them or not. The paged layout is one fixed pool of
+``page_size``-token blocks —
 ``[L, num_pages, page_size, KV, D]`` — and a **fixed-shape** int32 page table
 per slot (``[num_slots, pages_per_slot]``) mapping logical token positions to
 physical pages. The table rides into the jitted decode step as a small host
@@ -29,8 +29,8 @@ Three pieces, all pure host bookkeeping (device programs live in
   stored tokens (a hash collision must degrade to a re-prefill, never to
   wrong attention).
 - :class:`PagedKVCache` — the per-engine facade: pools + tables + lengths/
-  active mirrors + lane (slot) allocator, with the same retire/quarantine
-  surface the engine drove on :class:`~.kv_cache.SlotKVCache`.
+  active mirrors + lane (slot) allocator
+  (:class:`~.kv_cache.SlotAllocator`), and whatever else a lane carries.
 
 Not every cached layer has the pool's shape. A model with sliding-window
 layers beside full ones (``models/exaone_moe.py``) gives the manager two kinds:
@@ -40,7 +40,12 @@ a slot, ``wk``/``wv`` ``[Lw, num_slots, KV, window, D]`` (position ``p`` at
 entry ``p % window``), allocated once and the same size whatever the length:
 a window layer never keeps or reads more than its window. A ring belongs to
 its lane, not to pages, so nothing of it can be shared, parked or handed off:
-the engine refuses those for such a model, by name.
+the engine refuses those for such a model, by name. What a lane carries
+beside its pages is the cache's to say, as ``extras``: the pytree the engine's
+decode and prefill programs take after the pool and hand back. It is empty
+for a model of one kind, so that its programs have no such argument; for this
+one it is ``(wk, wv, counts)``, the rings and the int32 counters the programs
+pass along with them.
 
 Copy-on-write: sharing is page-aligned (full pages only — the unaligned tail
 of a shared prefix is recomputed, never half-shared), so in steady state a
@@ -258,16 +263,16 @@ class PrefixCache:
 
 
 class PagedKVCache:
-    """Pools + page tables + host mirrors: the paged drop-in for
-    :class:`~.kv_cache.SlotKVCache` behind the engine.
+    """Pools + page tables + host mirrors, behind the engine.
 
     ``k``/``v`` come from the model's own ``init_cache(num_pages, page_size)``
     — pages ride the protocol's batch axis, so any decode-protocol model
     pages without changes. ``tables``/``lengths``/``active`` are HOST arrays
     shipped into the jitted programs per step; all device shapes are fixed at
     construction. ``init_window(num_slots)`` (a model with window layers)
-    adds the second kind of cached layer: ``wk``/``wv``, one ring a slot (see
-    the module docstring); ``windowed`` says whether there is one."""
+    adds the second kind of cached layer: ``wk``/``wv``, one ring a slot, and
+    ``counters`` int32 counters beside them, together ``extras`` (see the
+    module docstring); ``windowed`` says whether there is one."""
 
     def __init__(
         self,
@@ -279,6 +284,7 @@ class PagedKVCache:
         dtype=None,
         prefix_entries: int = 256,
         init_window=None,
+        counters: int = 0,
     ):
         import jax.numpy as jnp
 
@@ -295,11 +301,10 @@ class PagedKVCache:
         dtype = dtype if dtype is not None else jnp.bfloat16
         cache = init_cache(num_pages, page_size, dtype=dtype)
         self.k, self.v = cache["k"], cache["v"]
-        self.windowed = init_window is not None
-        self.wk = self.wv = None
-        if self.windowed:
+        self.extras: tuple = ()
+        if init_window is not None:
             rings = init_window(num_slots, dtype=dtype)
-            self.wk, self.wv = rings["wk"], rings["wv"]
+            self.extras = (rings["wk"], rings["wv"], jnp.zeros((counters,), jnp.int32))
         self.num_pages = num_pages
         self.num_slots = num_slots
         self.max_len = max_len
@@ -314,10 +319,26 @@ class PagedKVCache:
 
     # -- capacity ------------------------------------------------------------
 
+    def put(self, k, v, *extras) -> None:
+        """Take back what a decode or prefill program hands back: the pools
+        and whatever else a lane carries, in ``extras``' order."""
+        self.k, self.v, self.extras = k, v, extras
+
+    @property
+    def windowed(self) -> bool:
+        return bool(self.extras)
+
+    @property
+    def wk(self):
+        return self.extras[0]
+
+    @property
+    def wv(self):
+        return self.extras[1]
+
     @property
     def nbytes(self) -> int:
-        rings = int(self.wk.nbytes + self.wv.nbytes) if self.windowed else 0
-        return int(self.k.nbytes + self.v.nbytes) + rings
+        return int(self.k.nbytes + self.v.nbytes) + sum(int(ring.nbytes) for ring in self.extras[:2])
 
     @property
     def page_bytes(self) -> int:

@@ -189,12 +189,6 @@ class ServingRouter:
                     "disaggregated roles need at least one prefill-capable and "
                     "one decode-capable replica (mixed counts as both)"
                 )
-            dense = [i for i, r in enumerate(self.replicas) if not r.engine.paged]
-            if dense:
-                raise ValueError(
-                    f"disaggregated serving relays page-granular KV — replicas "
-                    f"{dense} run the dense slab (paged=False) and cannot hand off"
-                )
         if handoff_retry is None:
             from ..resilience.retry import HANDOFF_RETRY
 

@@ -73,7 +73,6 @@ class Request:
     deadline_s: Optional[float] = None  # relative to submitted_at; None = no deadline
     # filled in as the request moves through the engine
     slot: Optional[int] = None
-    prefill_bucket: Optional[int] = None
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None

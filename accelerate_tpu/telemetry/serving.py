@@ -46,14 +46,14 @@ def _percentiles_ms(samples: list[float], prefix: str, qs=(50, 90, 99)) -> dict:
 class ServingStats:
     """Accumulates engine-step and request-lifecycle samples.
 
-    ``num_pages``/``page_size`` are set by a paged engine (serving/paging.py)
-    and unlock the page-economy metrics: page occupancy, peak pages in use
+    ``num_pages``/``page_size`` are the engine's pool (serving/paging.py),
+    under the page-economy metrics: page occupancy, peak pages in use
     (the honest "what pool would this traffic have needed" number), prefix
     hit rate, chunked-prefill and preemption counters."""
 
     max_samples = 4096  # cap of every raw-sample list below (a decode step a sample: two minutes at 33 a second)
 
-    def __init__(self, num_slots: int, num_pages: Optional[int] = None, page_size: Optional[int] = None):
+    def __init__(self, num_slots: int, num_pages: int, page_size: int):
         self.num_slots = num_slots
         self.num_pages = num_pages
         self.page_size = page_size
@@ -91,8 +91,7 @@ class ServingStats:
         self.slot_quarantines = 0
         self.slot_quarantine_releases = 0
         self.watchdog_trips = 0
-        # paged-KV counters (serving/paging.py): zero/irrelevant on the dense
-        # slot layout, summed normally by the fleet rollup either way
+        # paged-KV counters (serving/paging.py)
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_tokens_reused = 0
@@ -299,7 +298,7 @@ class ServingStats:
         active: int,
         waiting: int,
         tokens: Optional[int] = None,
-        pages_in_use: Optional[int] = None,
+        pages_in_use: int = 0,
         context: int = 0,
     ) -> None:
         """``tokens`` = tokens actually delivered this step (defaults to
@@ -318,10 +317,9 @@ class ServingStats:
         self.occupancy_sum += active / self.num_slots
         self.queue_depth_sum += waiting
         self.max_active = max(self.max_active, active)
-        if pages_in_use is not None and self.num_pages:
-            self.last_pages_in_use = pages_in_use
-            self.peak_pages_in_use = max(self.peak_pages_in_use, pages_in_use)
-            self.page_occupancy_sum += pages_in_use / max(self.num_pages - 1, 1)
+        self.last_pages_in_use = pages_in_use
+        self.peak_pages_in_use = max(self.peak_pages_in_use, pages_in_use)
+        self.page_occupancy_sum += pages_in_use / max(self.num_pages - 1, 1)
 
     def record_first_token(self, ttft_s: float) -> None:
         _keep(self.ttft_seconds, ttft_s, self.max_samples)
@@ -387,24 +385,23 @@ class ServingStats:
             self.phase_seconds, self.longest_step, self.admissions,
             self.queue_wait_seconds_sum, self.queue_wait_seconds_max,
         ))
-        if self.num_pages:
-            out["num_pages"] = self.num_pages
-            out["page_size"] = self.page_size
-            out["pages_in_use"] = self.last_pages_in_use
-            out["peak_pages_in_use"] = self.peak_pages_in_use
-            out["prefix_hits"] = self.prefix_hits
-            out["prefix_misses"] = self.prefix_misses
-            out["prefix_tokens_reused"] = self.prefix_tokens_reused
-            looked_up = self.prefix_hits + self.prefix_misses
-            out["prefix_hit_rate"] = (
-                round(self.prefix_hits / looked_up, 4) if looked_up else 0.0
-            )
-            out["prefill_chunks"] = self.prefill_chunks
-            out["requests_preempted"] = self.requests_preempted
-            out["cow_page_copies"] = self.cow_page_copies
-            out["page_pressure_events"] = self.page_pressure_events
-            if self.steps:
-                out["page_occupancy"] = round(self.page_occupancy_sum / self.steps, 4)
+        out["num_pages"] = self.num_pages
+        out["page_size"] = self.page_size
+        out["pages_in_use"] = self.last_pages_in_use
+        out["peak_pages_in_use"] = self.peak_pages_in_use
+        out["prefix_hits"] = self.prefix_hits
+        out["prefix_misses"] = self.prefix_misses
+        out["prefix_tokens_reused"] = self.prefix_tokens_reused
+        looked_up = self.prefix_hits + self.prefix_misses
+        out["prefix_hit_rate"] = (
+            round(self.prefix_hits / looked_up, 4) if looked_up else 0.0
+        )
+        out["prefill_chunks"] = self.prefill_chunks
+        out["requests_preempted"] = self.requests_preempted
+        out["cow_page_copies"] = self.cow_page_copies
+        out["page_pressure_events"] = self.page_pressure_events
+        if self.steps:
+            out["page_occupancy"] = round(self.page_occupancy_sum / self.steps, 4)
         out["traces_completed"] = self.traces_completed
         out["trace_spans"] = self.trace_spans
         out["slo_good_events"] = self.slo_good_events
@@ -494,15 +491,13 @@ def fleet_rollup(
     for key in counters:
         out[key] = sum(getattr(s, key) for s in stats_list)
     out["num_slots"] = sum(s.num_slots for s in stats_list)
-    paged = [s for s in stats_list if s.num_pages]
-    if paged:
-        # pools are per-replica HBM: capacity and peaks ADD across the fleet
-        out["num_pages"] = sum(s.num_pages for s in paged)
-        out["peak_pages_in_use"] = sum(s.peak_pages_in_use for s in paged)
-        looked_up = out["prefix_hits"] + out["prefix_misses"]
-        out["prefix_hit_rate"] = (
-            round(out["prefix_hits"] / looked_up, 4) if looked_up else 0.0
-        )
+    # pools are per-replica HBM: capacity and peaks ADD across the fleet
+    out["num_pages"] = sum(s.num_pages for s in stats_list)
+    out["peak_pages_in_use"] = sum(s.peak_pages_in_use for s in stats_list)
+    looked_up = out["prefix_hits"] + out["prefix_misses"]
+    out["prefix_hit_rate"] = (
+        round(out["prefix_hits"] / looked_up, 4) if looked_up else 0.0
+    )
     out["max_active_slots"] = sum(s.max_active for s in stats_list)
     elapsed = max(s.elapsed_seconds for s in stats_list)
     out["throughput_tokens_per_sec"] = (
@@ -565,10 +560,7 @@ def fleet_rollup(
                 out[f"pool_{role}_slot_occupancy"] = round(
                     sum(s.occupancy_sum for s in group) / group_steps, 4
                 )
-            paged_group = [s for s in group if s.num_pages and s.steps]
-            paged_steps = sum(s.steps for s in paged_group)
-            if paged_steps:
                 out[f"pool_{role}_page_occupancy"] = round(
-                    sum(s.page_occupancy_sum for s in paged_group) / paged_steps, 4
+                    sum(s.page_occupancy_sum for s in group) / group_steps, 4
                 )
     return out

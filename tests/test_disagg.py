@@ -452,7 +452,7 @@ def test_fleet_rollup_handoff_economy_and_pools():
     assert out["pool_decode_slot_occupancy"] == 1.0
     assert out["pool_prefill_page_occupancy"] == 0.5
     # single-engine snapshots carry the same keys (zero), diffable column-wise
-    snap = ServingStats(2).snapshot()
+    snap = ServingStats(2, num_pages=9, page_size=16).snapshot()
     for key in ("handoffs_attempted", "handoffs_adopted", "handoff_fallbacks",
                 "handoff_pages_moved", "handoff_bytes_moved", "requests_parked",
                 "requests_adopted"):
@@ -485,8 +485,7 @@ def test_handoff_transient_classifier():
 
 
 def test_disagg_config_validation(llama):
-    """Roles must cover both phases, match the replica count, and ride on
-    paged engines (the dense slab has no page-granular KV to relay)."""
+    """Roles must cover both phases and match the replica count."""
     model, params = llama
     with pytest.raises(ValueError, match="at least one"):
         _disagg(llama, roles=("prefill", "prefill"))
@@ -495,16 +494,4 @@ def test_disagg_config_validation(llama):
             engine_factory=lambda: ServingEngine(model, params, num_slots=2, max_len=64),
             num_replicas=2,
             roles=["prefill", "decode", "mixed"],
-        )
-    with pytest.raises(ValueError, match="dense"):
-        ServingRouter(
-            engine_factory=lambda: ServingEngine(
-                model, params, num_slots=2, max_len=64, paged=False
-            ),
-            num_replicas=2,
-            roles=["prefill", "decode"],
-        )
-    with pytest.raises(ValueError, match="paged engine"):
-        ServingEngine(model, params, num_slots=2, max_len=64, paged=False).submit(
-            np.arange(4, dtype=np.int32), 4, prefill_only=True
         )

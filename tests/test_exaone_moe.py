@@ -215,14 +215,36 @@ def test_a_window_layers_tokens_a_slot_stay_put_while_the_context_grows_tenfold(
     assert cache.wk.nbytes + cache.wv.nbytes == ring_bytes and cache.nbytes == ring_bytes + cache.k.nbytes + cache.v.nbytes
 
 
+def test_a_quarantined_lane_has_its_ring_scrubbed_and_its_probe_recovers(tiny):
+    """Poison in a lane's ring (not in its pages) turns its logits non-finite:
+    the lane is quarantined and the ring scrubbed with it, or a masked entry's
+    0 x NaN would fail every probe after; the probe then passes, and the
+    request, requeued, is served from its prompt as if nothing had happened."""
+    cfg, model, params = tiny
+    engine = ServingEngine(model, params, **{**ENGINE, "num_slots": 1})
+    [prompt] = _prompts(cfg, [11], seed=5)
+    rid = engine.submit(prompt, max_new_tokens=6)
+    engine.step()  # prefilled, and a first token decoded
+    wk, wv, counts = engine.cache.extras
+    engine.cache.extras = (wk.at[:, 0].set(jnp.nan), wv, counts)
+    engine.step()
+    assert engine.cache.quarantined == frozenset({0}) and engine.scheduler.waiting == 1
+    assert not np.asarray(engine.cache.wk[:, 0]).any() and not np.asarray(engine.cache.wv[:, 0]).any()  # zeros, not NaN
+    engine.step()  # the probe alone rides this step
+    assert engine.cache.quarantined == frozenset() and engine.stats.slot_quarantine_releases == 1
+    results = engine.run()
+    assert engine.stats.slot_quarantines == 1 and engine.stats.requests_requeued == 1
+    assert results[rid].finish_reason == "length"
+    assert np.array_equal(generate(model, params, prompt[None], max_new_tokens=6)[0][prompt.size:], results[rid].generated)
+
+
 # -- (e) what the family cannot do yet raises by name ----------------------------------------
 
 
 @pytest.mark.parametrize("asked,named", [
     (dict(speculative=object()), "speculative decoding"),
     (dict(prefix_sharing=True), "prefix sharing"),
-    (dict(paged=False), "the dense slot cache"),
-], ids=["speculation", "prefix_sharing", "slot_cache"])
+], ids=["speculation", "prefix_sharing"])
 def test_the_engine_refuses_at_construction_what_a_ring_cannot_do(tiny, asked, named):
     _, model, params = tiny
     with pytest.raises(NotImplementedError, match=f"sliding-window layers cannot be served with {named}"):

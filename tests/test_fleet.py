@@ -153,15 +153,12 @@ def test_routed_fleet_zero_steady_state_recompiles(llama):
     router.warmup()
     warm = tracker.snapshot()
     # ONE replica's worth of programs: decode + one prefill per bucket (the
-    # paged engine scatters prefill pages directly — no insert programs; a
-    # dense engine would add one insert per bucket) + the handoff pair
-    # (page extract + adopt-insert, paged only — steady-state handoffs must
+    # engine scatters prefill pages directly — no insert programs) + the
+    # handoff pair (page extract + adopt-insert — steady-state handoffs must
     # compile nothing). The second replica's warmup hit the shared cache for
     # every one of them.
     engine = router.replicas[0].engine
-    per_bucket = 1 if engine.paged else 2
-    handoff_pair = 2 if engine.paged else 0
-    assert warm["jit_cache_misses"] == 1 + per_bucket * len(engine.buckets) + handoff_pair
+    assert warm["jit_cache_misses"] == 1 + len(engine.buckets) + 2
     router.generate_many(_prompts([3, 9, 20, 31, 6, 14], seed=4), max_new_tokens=4)
     steady = tracker.snapshot()
     tracker.stop()
@@ -341,7 +338,7 @@ def test_fleet_transient_classifier():
 def test_fleet_rollup_merges_raw_samples():
     """Counters sum; percentiles merge over raw samples (a mean of p99s is
     not a p99)."""
-    a, b = ServingStats(2), ServingStats(4)
+    a, b = ServingStats(2, num_pages=9, page_size=16), ServingStats(4, num_pages=9, page_size=16)
     for t in (0.010, 0.011, 0.012):
         a.record_step(t, active=2, waiting=1)
     for t in (0.100, 0.110):
@@ -407,9 +404,7 @@ def test_cancel_landing_mid_step_wins_over_same_step_retirement(llama):
     rid = engine.submit(_prompts([4], seed=16)[0], max_new_tokens=2)
     engine.step()  # admit + token 1; next step would retire on length
 
-    # hook whichever decode program the engine's layout actually runs
-    attr = "_paged_decode_program" if engine.paged else "_decode_program"
-    real = getattr(engine, attr)
+    real = engine._paged_decode_program
     acked = []
 
     def hooked():
@@ -422,9 +417,9 @@ def test_cancel_landing_mid_step_wins_over_same_step_retirement(llama):
 
         return wrapper
 
-    setattr(engine, attr, hooked)
+    engine._paged_decode_program = hooked
     results = {r.request_id: r for r in engine.step()}
-    setattr(engine, attr, real)
+    engine._paged_decode_program = real
     assert acked == [True]
     assert results[rid].finish_reason == "cancelled"
     assert engine.stats.requests_cancelled == 1

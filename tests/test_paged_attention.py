@@ -341,8 +341,7 @@ def test_layer_scan_closes_over_the_pool(family, program, llama, gpt2):
     slots = cache.num_slots
     if program == "decode":
         traced = jax.make_jaxpr(engine._paged_decode_program())(
-            params, cache.k, cache.v, engine._pending, cache.lengths, cache.active,
-            cache.tables, jax.random.split(jax.random.key(0), slots),
+            params, *engine._decode_arguments(jax.random.split(jax.random.key(0), slots))
         )
     else:
         traced = jax.make_jaxpr(engine._spec_verify_program())(
@@ -377,19 +376,6 @@ def test_kernel_decode_zero_steady_state_recompiles(llama):
         engine.submit(p, max_new_tokens=5)
     engine.run()
     assert engine.compiles.compile_count == mark
-
-
-def test_unpaged_engine_reports_kernel_fallback(llama):
-    """use_kernels on a dense-slab engine cannot engage (the kernel reads
-    page tables); the engine must say so — summary names the reason and the
-    decode path stays the reference."""
-    model, params = llama
-    engine = ServingEngine(
-        model, params, num_slots=2, max_len=64, paged=False, use_kernels=True
-    )
-    summary = engine.kernel_summary()
-    assert summary["decode_attention"] == "gather_reference"
-    assert "paged" in summary["decode_fallback_reason"]
 
 
 def test_kernels_telemetry_record(llama, tmp_path):

@@ -219,19 +219,14 @@ def test_fork_partial_rollback_refcount_cycle(llama):
 
 
 def test_paged_matches_dense_and_sequential_bit_exact(llama):
-    """The acceptance bar: paged vs dense slot-cache generation bit-equal at
-    temperature 0 on a mixed-length workload (page-aligned and not), both
-    equal to per-request sequential generate."""
+    """The acceptance bar: paged generation bit-equal at temperature 0, on a
+    mixed-length workload (page-aligned and not), to per-request sequential
+    generate over its dense cache."""
     model, params = llama
     prompts = _prompts([3, 8, 13, 17, 24, 31], seed=40)
-    paged = ServingEngine(
-        model, params, num_slots=3, max_len=64, paged=True, page_size=8
-    )
-    dense = ServingEngine(model, params, num_slots=3, max_len=64, paged=False)
+    paged = ServingEngine(model, params, num_slots=3, max_len=64, page_size=8)
     out_paged = paged.generate_many(prompts, max_new_tokens=6)
-    out_dense = dense.generate_many(prompts, max_new_tokens=6)
-    for prompt, a, b in zip(prompts, out_paged, out_dense):
-        np.testing.assert_array_equal(a, b)
+    for prompt, a in zip(prompts, out_paged):
         expected = generate(model, params, prompt[None], max_new_tokens=6)[0]
         np.testing.assert_array_equal(a, np.asarray(expected))
     assert paged.stats.peak_pages_in_use > 0

@@ -6,6 +6,8 @@ backend, so the compile/jit-cache invariants proven here are the TPU ones.
 """
 
 import json
+import os
+import sys
 import time
 
 import numpy as np
@@ -13,6 +15,10 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)  # the benchmark's own package: the tiny exaone_moe's weights
 
 from accelerate_tpu.models import GPT2, Llama
 from accelerate_tpu.models.generation import generate
@@ -36,6 +42,24 @@ def llama():
 
 
 @pytest.fixture(scope="module")
+def exaone_moe():
+    """The benchmark's rehearsal ``exaone_moe``: its window layers' rings and
+    its experts' counters ride beside the page pool (``cache.extras``)."""
+    from benchmark.lib import configs
+
+    cfg = configs.model_config("k-exaone-236b-a23b", rehearse=True)
+    family = configs.family(cfg)
+    return family.build(cfg), family.params(cfg, 11, jnp.float32)
+
+
+@pytest.fixture(params=["llama", "exaone_moe"])
+def with_and_without_rings(request):
+    """(model, params) of a model whose lanes carry pages alone, then of one
+    whose lanes carry rings beside them: one decode builder serves both."""
+    return request.getfixturevalue(request.param)
+
+
+@pytest.fixture(scope="module")
 def gpt2():
     model = GPT2("gpt2-tiny")
     return model, model.init(jax.random.key(1))
@@ -47,27 +71,21 @@ def _prompts(lengths, vocab=1024, seed=0):
 
 
 def _poison_slot_kv(engine, slot):
-    """NaN one slot's live K storage, wherever the layout keeps it: the
-    slot's batch index on the dense slab, the slot's physical pages when
-    paged (index 1 of a paged pool is a PAGE, not a slot — and page 0 is the
-    shared null page, which must stay finite)."""
-    if engine.paged:
-        pages = np.asarray(engine.cache.pages_of(slot), np.int32)
-        engine.cache.k = engine.cache.k.at[:, pages].set(jnp.nan)
-    else:
-        engine.cache.k = engine.cache.k.at[:, slot].set(jnp.nan)
+    """NaN one slot's live K storage: the slot's physical pages (index 1 of
+    the pool is a PAGE, not a slot — and page 0 is the shared null page,
+    which must stay finite)."""
+    pages = np.asarray(engine.cache.pages_of(slot), np.int32)
+    engine.cache.k = engine.cache.k.at[:, pages].set(jnp.nan)
 
 
 def _warm_program_count(engine, warmup=False):
     """Programs a fully-warmed engine holds: one decode step, plus one
-    prefill program per bucket — and on the dense layout a separate insert
-    program per bucket (paged prefill scatters into the pool directly).
-    ``warmup=True`` counts what ``warmup()`` compiles, which for a paged
-    engine adds the handoff pair (page extract + adopt-insert) that
-    disaggregated steady state must never compile mid-traffic."""
-    per_bucket = 1 if engine.paged else 2
-    handoff_pair = 2 if warmup and engine.paged else 0
-    return 1 + per_bucket * len(engine.buckets) + handoff_pair
+    prefill program per bucket (prefill scatters into the pool directly).
+    ``warmup=True`` counts what ``warmup()`` compiles, which adds the
+    handoff pair (page extract + adopt-insert) that disaggregated steady
+    state must never compile mid-traffic."""
+    handoff_pair = 2 if warmup else 0
+    return 1 + len(engine.buckets) + handoff_pair
 
 
 # -- slot allocator -----------------------------------------------------------
@@ -288,14 +306,12 @@ def test_cancel_queued_and_active_requests(llama):
     assert len(out) == 1
 
 
-def test_quarantine_requeue_and_probe_release(llama):
+def test_quarantine_requeue_and_probe_release(with_and_without_rings):
     """A slot producing non-finite logits is quarantined, its request requeues
     and completes correctly in a clean admission; the slot re-enters
     circulation only after the finite-logits probe passes."""
-    import jax.numpy as jnp
-
-    model, params = llama
-    prompt = _prompts([5], seed=27)[0]
+    model, params = with_and_without_rings
+    prompt = _prompts([5], model.config.vocab_size, seed=27)[0]
     engine = ServingEngine(model, params, num_slots=1, max_len=32)
     rid = engine.submit(prompt, max_new_tokens=4)
     engine.step()  # admit + first decode (healthy)
@@ -315,14 +331,12 @@ def test_quarantine_requeue_and_probe_release(llama):
     assert results[rid].finish_reason == "length"
 
 
-def test_quarantined_slot_never_serves_until_probe_passes(llama):
+def test_quarantined_slot_never_serves_until_probe_passes(with_and_without_rings):
     """While a slot is quarantined it is invisible to admission: with every
     slot quarantined, a waiting request stays queued until the probe passes."""
-    import jax.numpy as jnp
-
-    model, params = llama
+    model, params = with_and_without_rings
     engine = ServingEngine(model, params, num_slots=1, max_len=32)
-    engine.submit(_prompts([4], seed=28)[0], max_new_tokens=2)
+    engine.submit(_prompts([4], model.config.vocab_size, seed=28)[0], max_new_tokens=2)
     engine.step()
     _poison_slot_kv(engine, 0)
     engine.step()  # quarantine fires; request back at queue head
@@ -336,15 +350,14 @@ def test_quarantined_slot_never_serves_until_probe_passes(llama):
     assert all(r.finish_reason == "length" for r in results.values())
 
 
-def test_request_fails_after_max_requeues_instead_of_livelocking(llama):
+def test_request_fails_after_max_requeues_instead_of_livelocking(with_and_without_rings):
     """A request that keeps landing in quarantined slots (e.g. its own input
     drives the model non-finite) fails after max_request_requeues instead of
     requeue-cycling forever — run() terminates and everyone else is served."""
-    import jax.numpy as jnp
-
-    model, params = llama
+    model, params = with_and_without_rings
+    vocab = model.config.vocab_size
     engine = ServingEngine(model, params, num_slots=1, max_len=32)
-    rid = engine.submit(_prompts([4], seed=30)[0], max_new_tokens=4)
+    rid = engine.submit(_prompts([4], vocab, seed=30)[0], max_new_tokens=4)
     engine.step()
     # simulate a request already bounced through bad slots up to the cap
     engine.scheduler.slots[0].requeues = engine.max_request_requeues
@@ -354,8 +367,47 @@ def test_request_fails_after_max_requeues_instead_of_livelocking(llama):
     assert engine.stats.requests_failed == 1
     assert engine.stats.requests_requeued == 0  # failed, not requeued again
     # engine stays healthy: the slot probed back and serves new requests
-    out = engine.generate_many([_prompts([3], seed=31)[0]], max_new_tokens=2)
+    out = engine.generate_many([_prompts([3], vocab, seed=31)[0]], max_new_tokens=2)
     assert len(out) == 1
+
+
+def test_the_decode_program_hands_back_the_slots_tokens_first(with_and_without_rings):
+    """The seam a wrapper of ``_paged_decode_program`` stands on
+    (``tests/benchmark/faults.py:altered_token``, the mid-step cancel of
+    ``tests/test_fleet.py``): called with the weights and
+    ``_decode_arguments``, the program returns a tuple whose first element
+    holds the slots' tokens in its first ``num_slots`` entries, whatever a
+    lane carries beside its pages and whatever rides home behind them."""
+    model, params = with_and_without_rings
+    engine = ServingEngine(model, params, num_slots=3, max_len=32)
+    prompts = _prompts([5, 9], model.config.vocab_size, seed=60)
+    ids = [engine.submit(prompt, max_new_tokens=4) for prompt in prompts]
+    seen = []
+    real = engine._paged_decode_program
+
+    def hooked():
+        program = real()
+
+        def wrapper(*args):
+            nxt, *rest = out = program(*args)
+            decoding = {int(slot): engine.scheduler.slots[slot].id for slot in np.flatnonzero(engine.cache.active)}
+            seen.append((np.asarray(nxt), len(rest), decoding))
+            return out
+
+        return wrapper
+
+    engine._paged_decode_program = hooked
+    results = engine.run()
+    assert len(seen) == 4  # both requests decode from the first step on, a token a step
+    for step, (nxt, rest, decoding) in enumerate(seen):
+        assert nxt.ndim == 1 and nxt.dtype == np.int32 and nxt.size >= 3
+        assert rest == 3 + len(engine.cache.extras)  # the finite verdicts, the pools, and what else a lane carries
+        assert sorted(decoding.values()) == ids
+        for slot in range(3):
+            assert nxt[slot] == (results[decoding[slot]].generated[step] if slot in decoding else 0)
+    for prompt, rid in zip(prompts, ids):
+        expected = np.asarray(generate(model, params, prompt[None], max_new_tokens=4))[0][prompt.size:]
+        np.testing.assert_array_equal(results[rid].generated, expected)
 
 
 def test_watchdog_reports_oversized_step(llama):

@@ -81,8 +81,6 @@ def test_speculative_config_validation(llama):
     with pytest.raises(ValueError, match="num_branches"):
         SpeculativeConfig(draft_model=model, draft_params=params, mode="tree", num_branches=1)
     cfg = SpeculativeConfig(draft_model=model, draft_params=params, k=3)
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(model, params, num_slots=2, max_len=64, paged=False, speculative=cfg)
     with pytest.raises(ValueError, match="temperature-0"):
         ServingEngine(model, params, num_slots=2, max_len=64, temperature=0.7, speculative=cfg)
     bad_draft = Llama(model.config.replace(vocab_size=512))
@@ -347,7 +345,7 @@ def test_stats_snapshot_and_fleet_rollup_merge():
     """Engine-independent: spec counters SUM across replicas and the fleet
     accepted-length percentiles merge over raw samples (token counts — the
     one family of spec keys that must NOT get the ms scaling)."""
-    a, b = ServingStats(2), ServingStats(2)
+    a, b = ServingStats(2, num_pages=9, page_size=16), ServingStats(2, num_pages=9, page_size=16)
     a.record_spec_step(proposed=6, accepted_lengths=[2, 2])
     a.record_spec_step(proposed=6, accepted_lengths=[2])
     b.record_spec_step(proposed=3, accepted_lengths=[0])
@@ -366,4 +364,4 @@ def test_stats_snapshot_and_fleet_rollup_merge():
     assert out["spec_accepted_len_p50"] == 2.0
     assert out["spec_accepted_len_p99"] == 2.0
     # a spec-free replica contributes zeros, not missing keys
-    assert ServingStats(2).snapshot()["spec_steps"] == 0
+    assert ServingStats(2, num_pages=9, page_size=16).snapshot()["spec_steps"] == 0

@@ -277,7 +277,7 @@ def test_the_always_on_counters_account_for_the_step_with_and_without_a_tracer(l
 
 
 def test_the_fleet_rollup_merges_the_host_time_counters():
-    a, b = ServingStats(2), ServingStats(2)
+    a, b = ServingStats(2, num_pages=9, page_size=16), ServingStats(2, num_pages=9, page_size=16)
     a.record_phases(4, {"admit": 0.001, "fetch": 0.030}, 0.031)
     b.record_phases(9, {"admit": 0.002, "fetch": 0.900, "deliver": 0.098}, 1.0)
     b.record_phases(10, {"admit": 0.001, "fetch": 0.020}, 0.021)
@@ -290,11 +290,11 @@ def test_the_fleet_rollup_merges_the_host_time_counters():
     assert out["longest_step_ms"] == 1000.0 and out["longest_step_number"] == 9 and out["longest_step_fetch_ms"] == 900.0
     assert out["admissions"] == 2 and out["queue_wait_mean_ms"] == 130.0 and out["queue_wait_max_ms"] == 250.0
     assert out["prefill_tokens"] == 32 and out["prefill_tokens_real"] == 20 and out["decode_context_tokens"] == 75
-    assert "longest_step_ms" not in fleet_rollup([ServingStats(2)]) and "queue_wait_max_ms" not in ServingStats(2).snapshot()
+    assert "longest_step_ms" not in fleet_rollup([ServingStats(2, num_pages=9, page_size=16)]) and "queue_wait_max_ms" not in ServingStats(2, num_pages=9, page_size=16).snapshot()
 
 
 def test_the_per_sample_lists_are_bounded_and_short_runs_read_as_before():
-    stats = ServingStats(2)
+    stats = ServingStats(2, num_pages=9, page_size=16)
     samples = [0.001 * (i % 17 + 1) for i in range(300)]
     for s in samples:
         stats.record_step(s, active=1, waiting=0)
@@ -303,7 +303,7 @@ def test_the_per_sample_lists_are_bounded_and_short_runs_read_as_before():
         stats.record_span("decode", s)
     assert stats.step_seconds == samples and stats.span_seconds["decode"] == samples  # under the cap: every sample
     assert stats.snapshot()["per_token_p50_ms"] == pytest.approx(float(np.percentile(samples, 50)) * 1e3, abs=1e-3)
-    capped = ServingStats(2)
+    capped = ServingStats(2, num_pages=9, page_size=16)
     capped.max_samples = 64
     for i in range(10_000):
         capped.record_step(0.001 * (i % 17 + 1), active=1, waiting=0)
@@ -440,4 +440,4 @@ def test_the_experts_and_the_attended_counters_equal_a_hand_count(exaone):
     assert [counted[f"tokens_by_held_expert.{e}"] for e in range(cfg["num_experts"])] == stats.moe_tokens_by_held_expert.tolist()
     snapshot = engine.metrics()
     assert snapshot["moe_assignments_held"] == stats.moe_assignments_held and snapshot["attended_window_tokens"] > 0
-    assert "moe_assignments" not in ServingStats(2).snapshot()  # a model with neither keeps the keys it had
+    assert "moe_assignments" not in ServingStats(2, num_pages=9, page_size=16).snapshot()  # a model with neither keeps the keys it had

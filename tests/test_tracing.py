@@ -344,7 +344,7 @@ def test_fleet_rollup_merges_trace_and_slo_counters():
     """3-replica synthetic rollup: counters SUM; span-duration percentiles
     merge over the raw samples — the fleet p99 lands in the slow replica's
     tail, NOT at the mean of per-replica p99s."""
-    a, b, c = (ServingStats(2) for _ in range(3))
+    a, b, c = (ServingStats(2, num_pages=9, page_size=16) for _ in range(3))
     for _ in range(9):
         a.record_span("decode", 0.010)
     b.record_span("decode", 0.500)  # one slow outlier on one replica
@@ -371,7 +371,7 @@ def test_fleet_rollup_merges_trace_and_slo_counters():
     assert out["span_decode_p50_ms"] == 10.0
     assert out["span_queued_p99_ms"] >= 1.9
     # snapshots carry the same keys (diffable column-for-column)
-    snap = ServingStats(2).snapshot()
+    snap = ServingStats(2, num_pages=9, page_size=16).snapshot()
     for key in ("traces_completed", "trace_spans", "slo_good_events",
                 "slo_bad_events"):
         assert snap[key] == 0
@@ -432,7 +432,7 @@ def test_slo_classifiers_and_validation():
     with pytest.raises(ValueError, match="duplicate"):
         SLOMonitor([err, SLObjective("errors", "error_rate")])
     # per-replica counters land on the stats sink the rollup sums
-    stats = ServingStats(2)
+    stats = ServingStats(2, num_pages=9, page_size=16)
     monitor = SLOMonitor(default_objectives(ttft_s=1.0))
     monitor.observe(_trace(ttft=0.1), stats=stats)
     assert stats.slo_good_events == 3 and stats.slo_bad_events == 0
