@@ -203,6 +203,45 @@ def quantized_resident_params(streamed) -> Optional[dict]:
     return params
 
 
+@dataclass
+class _Flight:
+    """One device step between its dispatch and its landing: what the host
+    needs to deliver its tokens a ``step()`` later, as the books stood when
+    it went out. The plain decode program's outputs stay on the device until
+    then (``fetched``, ``ok``); a speculative step lands where it is made."""
+
+    number: int  # the program's: how many device steps went out before it
+    lanes: list  # the slots that decode in it
+    lengths: np.ndarray  # [S]: the live length each lane's token is decoded at
+    requests: list  # [S]: whom each lane served; a lane that changed hands since drops its token
+    quarantined: list  # the empty lanes whose finite-logits probe rides it
+    occupied: int  # seated lanes that decode or prefill (not one waiting for its last token)
+    compiles_before: int
+    dispatched: float  # when it went out (perf_counter)
+    fetched: Any = None
+    ok: Any = None
+
+
+class _StepBooks:
+    """What one ``step()`` keeps while it runs: the clock its phases are cut
+    by, the results it will return, and what its landing delivered."""
+
+    def __init__(self, mark, finished: list):
+        self.mark = mark
+        self.finished = finished
+        self.t0 = self.stamp = time.perf_counter()
+        self.phases: dict[str, float] = {}
+        self.landed: Optional[int] = None  # the program whose tokens it delivered
+        self.tokens = self.context = 0
+        self.experts: dict[str, int] = {}
+
+    def close(self, phase: str) -> None:
+        """``phase`` ends now: everything since the last boundary was its."""
+        now = time.perf_counter()
+        self.phases[phase] = self.phases.get(phase, 0.0) + now - self.stamp
+        self.stamp = now
+
+
 class StepWatchdog:
     """Wall-clock monitor for the blocking decode step.
 
@@ -366,7 +405,11 @@ class ServingEngine:
                 )
         self._kernels_reported = False  # one {"kind": "kernels"} record per engine
         self.scheduler = ContinuousBatchingScheduler(num_slots, max_queue=max_queue)
-        self._pending = np.zeros((num_slots,), np.int32)  # next input token per slot
+        # next input token per slot, where the host knows it; negative where it
+        # is the last decode program's, still on the device (`_prev`)
+        self._pending = np.zeros((num_slots,), np.int32)
+        self._flight: Optional[_Flight] = None  # the decode program dispatched and not yet landed
+        self._carried: list[ServingResult] = []  # finished by a landing outside step(): the next step() returns them
         self._rng = rng if rng is not None else jax.random.key(0)
         # cache donation halves decode HBM traffic; unsupported on CPU (warns)
         self._donate = jax.default_backend() in ("tpu", "gpu")
@@ -438,7 +481,9 @@ class ServingEngine:
         # adds no host sync. A routed fleet shares ONE tracer across its
         # replicas so a handed-off request keeps one trace.
         self.tracer = tracer
-        self._prefill_open: set[int] = set()  # request ids with an open prefill span
+        # request ids with an open prefill span -> the number of the decode
+        # program whose landing is the first fence sequenced after the chunk
+        self._prefill_open: dict[int, int] = {}
         self._decode_warm = False  # first decode completed (compile behind us)
         self._donation_checked = False  # one consult after the first compile
         self._draining = False  # drain(): stop admitting, finish active slots
@@ -449,6 +494,11 @@ class ServingEngine:
         self._parked: dict[int, dict] = {}
         if self._experts_shape is not None:
             self.stats.moe_tokens_by_held_expert = np.zeros((model.experts_here,), np.int64)
+        # the last decode program's `fetched`, on the device: the tokens, and
+        # behind them what a model with routed experts counts (two sets of
+        # held counts and the pairs hit)
+        counters = 2 * self._held_counts + 1 if self.windowed else 0
+        self._prev = jnp.zeros((num_slots + counters,), jnp.int32)
 
     @property
     def windowed(self) -> bool:
@@ -504,9 +554,14 @@ class ServingEngine:
 
     def _paged_decode_program(self):
         """The decode step over every lane: ``decode_step(params, pk, pv,
-        extras, tokens, lengths, active, tables, keys) -> (fetched, ok, pk,
-        pv, *extras)``, with ``fetched`` the lanes' tokens ``[S]``.
-        ``extras`` is what a lane carries beside its pages
+        extras, prev, tokens, lengths, active, tables, keys) -> (fetched, ok,
+        pk, pv, *extras)``, with ``fetched`` the lanes' tokens ``[S]``.
+        ``prev`` is the last decode program's ``fetched``, still on the
+        device: a lane whose ``tokens`` entry is negative takes its input
+        token from there (the host has not read it yet: ``step()`` dispatches
+        this program before it lands the last one), every other lane from
+        ``tokens`` (a lane the host seated since, with its prompt's last
+        token). ``extras`` is what a lane carries beside its pages
         (``PagedKVCache.extras``): one body serves whatever it holds, and an
         empty one is no argument of the compiled program. Where it holds the
         window layers' rings ``wk``/``wv`` ``[Lw, S, KV, R, D]`` and
@@ -526,7 +581,8 @@ class ServingEngine:
         use_kernel = self._use_decode_kernel
 
         def build():
-            def decode_step(params, pk, pv, extras, tokens, lengths, active, tables, keys):
+            def decode_step(params, pk, pv, extras, prev, tokens, lengths, active, tables, keys):
+                tokens = jnp.where(tokens < 0, prev[: tokens.shape[0]], tokens)
                 rings = extras[:2]  # (wk, wv), or nothing
                 kinds = ("k", "v", "wk", "wv")[: 2 + len(rings)]
 
@@ -795,9 +851,15 @@ class ServingEngine:
         ))
 
     def _decode_arguments(self, keys) -> tuple:
-        """What the paged decode program is called with, after the weights."""
+        """What the paged decode program is called with, after the weights.
+        The host's arrays go as COPIES: jax's CPU H2D is zero-copy, and the
+        program's fence is a ``step()`` later, after the host has moved its
+        books on (lengths, retirements, the next step's pages)."""
         cache = self.cache
-        return (cache.k, cache.v, cache.extras, self._pending, cache.lengths, cache.active, cache.tables, keys)
+        return (
+            cache.k, cache.v, cache.extras, self._prev, self._pending.copy(),
+            cache.lengths.copy(), cache.active.copy(), cache.tables.copy(), keys,
+        )
 
     def _page_copy_program(self):
         """Copy one page ``src → dst``: the on-device half of copy-on-write
@@ -986,10 +1048,10 @@ class ServingEngine:
                         self.cache.k, self.cache.v, np.int32(0), np.int32(0)
                     )
                     self.spec.copy_page(0, 0)
-                keys = jax.random.split(self._rng, self.cache.num_slots)
+                keys = self._sampling_keys(0)  # as the plain dispatch makes them: its small programs compile here too
                 _, _, *handed_back = self._paged_decode_program()(
-                    self.params, self.cache.k, self.cache.v, self.cache.extras, zeros,
-                    zeros, inactive, self.cache.tables, keys,
+                    self.params, self.cache.k, self.cache.v, self.cache.extras, self._prev,
+                    zeros, zeros, inactive, self.cache.tables, keys,
                 )
                 self.cache.put(*handed_back)
         finally:
@@ -1164,7 +1226,9 @@ class ServingEngine:
         (:attr:`~.scheduler.Request.payload`) for the router to re-submit
         elsewhere; ``retired`` are results for queued requests that were
         already cancelled or past deadline — those must terminate *here*, not
-        be resurrected on another engine."""
+        be resurrected on another engine. Lands what is in flight first; what
+        that finishes comes out of the next ``step()``."""
+        self._land()
         self._draining = True
         now = time.perf_counter()
         retired = []
@@ -1349,8 +1413,8 @@ class ServingEngine:
         programs = 0
         for slot in list(self.scheduler.active_slots):
             request = self.scheduler.slots[slot]
-            if request is None or self.cache.active[slot]:
-                continue
+            if request is None or self.cache.active[slot] or request.in_flight:
+                continue  # decoding, or seated only until its last token lands
             prefill_len = request.prompt.size - 1
             remaining = prefill_len - request.prefilled
             if remaining <= 0:
@@ -1392,12 +1456,16 @@ class ServingEngine:
                 )
                 if self.tracer is not None:
                     # one span per chunk (prefill[i]): opened at dispatch, closed
-                    # at the first decode fence sequenced after it
+                    # at the first decode fence sequenced after it (the landing
+                    # of the program this step dispatches, a step() later), or
+                    # when the request's next chunk goes out before that
+                    if request.id in self._prefill_open:
+                        self.tracer.span_end(request.id, "prefill", stats=self.stats)
                     self.tracer.span_start(
                         request.id, "prefill", replica=self.name,
                         tokens=take, span=span, position=request.prefilled,
                     )
-                    self._prefill_open.add(request.id)
+                    self._prefill_open[request.id] = self._steps
                 # the table ROW is copied at dispatch: jax's CPU H2D is zero-copy,
                 # so handing the program a live view of `tables` races host-side
                 # mutation (park/retire zero the row right after this dispatch,
@@ -1449,7 +1517,7 @@ class ServingEngine:
                 # phase HERE: close the chunk span now (the parked span must
                 # not start before its prefill ends) and open `parked`, which
                 # stays open until the handoff acks, falls back, or resumes
-                self._prefill_open.discard(request.id)
+                self._prefill_open.pop(request.id, None)
                 self.tracer.span_end(request.id, "prefill", stats=self.stats)
             pages = self.cache.park(slot)
             self._parked[request.id] = {
@@ -1486,7 +1554,7 @@ class ServingEngine:
         if self.tracer is not None:
             # the residence ended abruptly: close its spans and re-open
             # `queued` — the request honestly waits again from the head
-            self._prefill_open.discard(preempted.id)
+            self._prefill_open.pop(preempted.id, None)
             self.tracer.interrupt(preempted.id, outcome="preempted")
             self.tracer.span_start(
                 preempted.id, "queued", replica=self.name, after="preempted"
@@ -1589,6 +1657,7 @@ class ServingEngine:
         the switch."""
         if self.spec is None or not self.spec.enabled:
             return
+        self._land()  # nothing is while it speculates; the flip is made with host and device agreed
         self.spec.disable(reason)
         self.stats.record_spec_fallback()
         self._resilience({"event": "spec_disabled", "reason": reason})
@@ -1969,7 +2038,7 @@ class ServingEngine:
                     replica=self.name,
                 )
             else:
-                self._prefill_open.discard(request.id)
+                self._prefill_open.pop(request.id, None)
                 self.tracer.retire(
                     request.id, request.finish_reason, stamp=request.finished_at,
                     stats=self.stats, replica=self.name,
@@ -2049,11 +2118,29 @@ class ServingEngine:
 
     def step(self) -> list[ServingResult]:
         """One engine iteration: retire expired/cancelled requests, admit into
-        free slots, run one decode step over every active slot (plus the
-        finite-logits probe of any quarantined slot, which rides the same
-        fixed-shape program), quarantine slots that produced non-finite
-        logits, retire finished requests. Returns the requests that finished
-        THIS step (including expired/cancelled ones, with their reason).
+        free slots, dispatch one decode program over every active slot (plus
+        the finite-logits probe of any quarantined slot, which rides the same
+        fixed-shape program), then LAND the program the last iteration
+        dispatched: fetch its tokens, quarantine slots that produced
+        non-finite logits, retire finished requests. Returns the requests
+        whose last token landed THIS step, one program after it was computed
+        (and the expired/cancelled ones, with their reason).
+
+        One decode program is in flight while the host works: program *k* is
+        enqueued before program *k - 1*'s tokens are read, and takes them
+        from the device (``_paged_decode_program``'s ``prev``). The host's
+        books lead: at dispatch every active lane's ``cache.lengths`` moves on
+        by one, and a lane whose request reaches ``max_new_tokens`` with the
+        token now in flight sits the next program out. What only the token or
+        the clock can tell (EOS, non-finite logits, cancel, deadline) is acted
+        on at landing, one program late; the token that lane computed
+        meanwhile is dropped, never delivered (``stats.tokens_dropped_late``),
+        and what it wrote lies in pages the lane still held at dispatch. A
+        speculative step interleaves its own fetches and stays synchronous, a
+        step that parked a prefill or has a slot quarantined lands what it
+        dispatched, and every entry point that needs host and device agreed
+        (``extract_pages``, ``adopt_kv``, ``resume_parked``, ``drain``,
+        ``analyze``) lands first.
 
         The step runs in phases — admit, prefill, prepare_writes,
         decode_dispatch, fetch, deliver (telemetry/serving.py ``PHASES``) —
@@ -2064,20 +2151,24 @@ class ServingEngine:
         asks ``tracing()`` once and ``mark`` is the shared no-op."""
         mark = profiler.span if profiler.tracing() else profiler.no_span
         with mark("engine.step") as root:
-            return self._step(mark, root)
+            # what a landing outside step() delivered comes out first
+            books = _StepBooks(mark, self._carried)
+            self._carried = []
+            number = self._steps
+            if root is not None:
+                root.set_metadata(
+                    step=number, active=len(self.scheduler.active_slots),
+                    waiting=self.scheduler.waiting,
+                )
+            self._step(books)
+            self._close_step(root, number, books)
+            return books.finished
 
-    def _step(self, mark, root) -> list[ServingResult]:
-        t0 = time.perf_counter()
-        number = self._steps
-        phases: dict[str, float] = {}
-        if root is not None:
-            root.set_metadata(
-                step=number, active=len(self.scheduler.active_slots),
-                waiting=self.scheduler.waiting,
-            )
+    def _step(self, books: _StepBooks) -> None:
+        mark, finished = books.mark, books.finished
         with mark("engine.admit") as live:
             self._report_kernels()
-            finished: list[ServingResult] = self._retire_degraded(t0)
+            finished.extend(self._retire_degraded(books.t0))
             self._inject_chaos_burst()
             admitted, longest_wait = 0, 0.0
             for slot, request in self.scheduler.admit_ready(self._free_slot):
@@ -2106,8 +2197,7 @@ class ServingEngine:
                     longest_wait = max(longest_wait, wait)
             if live is not None:
                 live.set_metadata(admitted=admitted, queue_wait_ms_max=longest_wait * 1e3)
-        stamp = time.perf_counter()
-        phases["admit"] = stamp - t0
+        books.close("admit")
         # one prefill span per still-prefilling slot (chunked prefill
         # interleaves long prompts into the step cadence), then make
         # every decode write position privately backed (grow / COW)
@@ -2116,25 +2206,22 @@ class ServingEngine:
             finished.extend(failed)
             if live is not None:
                 live.set_metadata(programs=programs)
-        before, stamp = stamp, time.perf_counter()
-        phases["prefill"] = stamp - before
+        books.close("prefill")
         with mark("engine.prepare_writes"):
             finished.extend(self._prepare_decode_writes())
-        before, stamp = stamp, time.perf_counter()
-        phases["prepare_writes"] = stamp - before
+        books.close("prepare_writes")
 
         # whether any lane decodes this step: a few cheap statements outside
-        # every child span (the root's self time); the decode_dispatch phase's
-        # counter starts at the last stamp, so the phases still add up
+        # every child span (the root's self time); the next phase's counter
+        # starts at the last stamp, so the phases still add up
         active_idx = self.scheduler.active_slots
         quarantined = sorted(self.cache.quarantined)
-        if (not active_idx and not quarantined) or (
-            # every occupied slot is still prefilling: no lane would decode,
-            # so skip the device step — the next step() runs their next chunk
-            not quarantined and not any(self.cache.active[s] for s in active_idx)
-        ):
-            self._close_step(root, number, phases, stamp - t0)
-            return finished
+        if not quarantined and not any(self.cache.active[s] for s in active_idx):
+            # every occupied slot is still prefilling, or waits for its last
+            # token: no lane would decode, so skip the device step (the next
+            # step() runs the next chunk), and land what is in flight
+            self._land(books)
+            return
         if not active_idx and quarantined and self.scheduler.waiting:
             # fail loudly rather than spin run() forever: every slot is
             # quarantined and none is coming back within the probe budget
@@ -2147,103 +2234,178 @@ class ServingEngine:
                     "are producing non-finite logits unconditionally"
                 )
 
-        compiles_before = self.compiles.compile_count
         spec_on = self.spec is not None and self.spec.enabled
         if spec_on and self.chaos is not None and self.chaos.spec_disable(self._steps):
             # mid-stream chaos drill: flip to plain decode PERMANENTLY, this
             # very step — the stream must continue without a drop or dup
             self.disable_speculation("chaos")
             spec_on = False
-        drafted = None
-        emit = None
-        # a speculative step interleaves its dispatches and fetches: one span,
-        # one phase, in place of decode_dispatch and fetch
-        device_phase = "spec_step" if spec_on else "decode_dispatch"
-        with mark("engine." + device_phase):
-            # the watchdog watches steady-state decode, not XLA compilation: the
-            # very first decode (and any step that compiled a new program) may
-            # legitimately take seconds, and a trip there is pure noise
-            if self._watchdog is not None and self._decode_warm:
-                self._watchdog.arm()
-            keys = jax.random.split(jax.random.fold_in(self._rng, self._steps), self.cache.num_slots)
-            if spec_on:
-                # the speculative step REPLACES the plain decode: every active
-                # lane rides the verify program (a non-drafting lane's window is
-                # just its pending token — emit 1, the plain-decode token), and
-                # the quarantine probe rides the target's finite verdict as usual
-                tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
-            else:
-                nxt, ok, *handed_back = self._paged_decode_program()(
-                    self.params, *self._decode_arguments(keys)
-                )
-                self.cache.put(*handed_back)
-        before, stamp = stamp, time.perf_counter()
-        phases[device_phase] = stamp - before
-        held = None
-        if not spec_on:
-            with mark("engine.fetch"):
-                fetched = np.asarray(nxt)  # host fetch = per-step fence
-                finite = np.asarray(ok)
-            lanes = self.cache.num_slots
-            tokens_mat = fetched[:lanes, None]
-            if self._experts_shape is not None:
-                # the routed experts' counters came home behind the tokens:
-                # [sparse layers, held experts] tokens by held expert, this
-                # step's and the prefill programs' since the last, and the
-                # (layer, held expert) pairs those programs hit
-                held = fetched[lanes : lanes + self._held_counts].reshape(self._experts_shape)
-                *by_expert, prefill_hit = fetched[lanes + self._held_counts :].tolist()
-        with mark("engine.deliver") as live:
-            # `now` closes the fetch phase (empty after a speculative step)
-            # and is the decode fence's stamp
-            now = time.perf_counter()
-            phases["fetch"] = now - stamp
-            retired = len(finished)
-            decoding = int(np.count_nonzero(self.cache.active)) if held is not None else 0
-            delivered, context = self._deliver(
-                t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
-                compiles_before, finished,
+        if spec_on:
+            self._spec_step(books, active_idx, quarantined)
+            return
+        # program k goes out before program k - 1 comes home: the device has
+        # its next program queued while the host delivers the last one's
+        # tokens, returns to the caller, admits and prepares the one after
+        previous = self._flight
+        self._flight = self._dispatch_decode(books, active_idx, quarantined, overlapped=previous is not None)
+        if previous is not None:
+            self._deliver_flight(books, previous)
+        if self.cache.quarantined or any(result.finish_reason == "prefilled" for result in finished):
+            # a quarantined slot (its probe rides this program, or the last
+            # one's landing just found it poisoned and enqueued its scrubs)
+            # and a park (its pages go to whoever asks next: extract_pages)
+            # end the step with host and device agreed, as they always did
+            self._land(books)
+
+    def _sampling_keys(self, number: int):
+        """A key a slot for decode program ``number``: the same function of
+        (program number, slot) whatever is in flight."""
+        return jax.random.split(jax.random.fold_in(self._rng, number), self.cache.num_slots)
+
+    def _open_flight(self, active_idx, quarantined) -> _Flight:
+        """The books of the device step about to be dispatched: which lanes
+        ride it, for whom, at what lengths."""
+        waiting = sum(
+            1 for slot in active_idx
+            if not self.cache.active[slot] and self.scheduler.slots[slot].in_flight
+        )
+        flight = _Flight(
+            number=self._steps, lanes=np.flatnonzero(self.cache.active).tolist(), lengths=self.cache.lengths.copy(),
+            requests=list(self.scheduler.slots), quarantined=quarantined,
+            occupied=len(active_idx) - waiting, compiles_before=self.compiles.compile_count,
+            dispatched=time.perf_counter(),
+        )
+        # the watchdog watches steady-state decode, not XLA compilation: the
+        # very first decode (and any step that compiled a new program) may
+        # legitimately take seconds, and a trip there is pure noise. Armed at
+        # a program's dispatch, disarmed at ITS landing
+        if self._watchdog is not None and self._decode_warm:
+            self._watchdog.arm()
+        return flight
+
+    def _dispatch_decode(self, books: _StepBooks, active_idx, quarantined, overlapped: bool) -> _Flight:
+        """Enqueue the decode program and move the host's books on by what is
+        certain without its tokens."""
+        with books.mark("engine.decode_dispatch") as live:
+            if live is not None:
+                live.set_metadata(in_flight=int(overlapped))
+            flight = self._open_flight(active_idx, quarantined)
+            self._steps += 1
+            keys = self._sampling_keys(flight.number)
+            flight.fetched, flight.ok, *handed_back = self._paged_decode_program()(
+                self.params, *self._decode_arguments(keys)
             )
-            experts = {}
+            self.cache.put(*handed_back)
+            self._prev = flight.fetched
+            self.stats.record_dispatch(overlapped)
+            for slot in flight.lanes:
+                request = flight.requests[slot]
+                request.in_flight += 1
+                self.cache.lengths[slot] += 1
+                self._pending[slot] = -1  # its next input token is this program's, on the device
+                if len(request.generated) + request.in_flight >= request.max_new_tokens:
+                    # its last token is in flight: seated until that lands,
+                    # but no lane of the next program
+                    self.cache.active[slot] = False
+        books.close("decode_dispatch")
+        return flight
+
+    def _spec_step(self, books: _StepBooks, active_idx, quarantined) -> None:
+        """A speculative step interleaves its dispatches and fetches: one
+        span, one phase, in place of decode_dispatch and fetch; synchronous,
+        so it starts and ends with nothing in flight."""
+        self._land(books)
+        with books.mark("engine.spec_step"):
+            flight = self._open_flight(active_idx, quarantined)
+            # the speculative step REPLACES the plain decode: every active
+            # lane rides the verify program (a non-drafting lane's window is
+            # just its pending token — emit 1, the plain-decode token), and
+            # the quarantine probe rides the target's finite verdict as usual
+            tokens_mat, emit, finite, drafted = self._spec_device_step(active_idx)
+            self._steps += 1
+            for slot in flight.lanes:
+                flight.requests[slot].in_flight += 1
+                self.cache.lengths[slot] += emit[slot]
+        books.close("spec_step")
+        with books.mark("engine.deliver") as live:
+            self._deliver(books, flight, tokens_mat, emit, finite, drafted, live)
+        books.close("deliver")
+
+    def _land(self, books: Optional[_StepBooks] = None) -> None:
+        """Land the flight: fetch and deliver the decode program in flight,
+        if there is one, leaving host and device agreed. Outside ``step()``
+        (no ``books``) what it finishes is carried to the next ``step()``."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        if books is not None:
+            self._deliver_flight(books, flight)
+            return
+        mark = profiler.span if profiler.tracing() else profiler.no_span
+        books = _StepBooks(mark, self._carried)
+        # no step() around it: the program's seconds (the service rate the
+        # wait quotes are priced from) run from its dispatch, not from here
+        books.t0 = flight.dispatched
+        self._deliver_flight(books, flight)
+        if not self._warming:
+            self.stats.record_phases(flight.number, books.phases, sum(books.phases.values()))
+
+    def _deliver_flight(self, books: _StepBooks, flight: _Flight) -> None:
+        """Fetch one decode program's tokens (the wait for that program: the
+        fence of everything enqueued before it) and deliver them."""
+        with books.mark("engine.fetch"):
+            fetched = np.asarray(flight.fetched)
+            finite = np.asarray(flight.ok)
+        lanes = self.cache.num_slots
+        held = None
+        if self._experts_shape is not None:
+            # the routed experts' counters came home behind the tokens:
+            # [sparse layers, held experts] tokens by held expert, this
+            # step's and the prefill programs' since the last, and the
+            # (layer, held expert) pairs those programs hit
+            held = fetched[lanes : lanes + self._held_counts].reshape(self._experts_shape)
+            *by_expert, prefill_hit = fetched[lanes + self._held_counts :].tolist()
+        with books.mark("engine.deliver") as live:
+            books.close("fetch")  # the fence's stamp
+            self._deliver(books, flight, fetched[:lanes, None], None, finite, None, live)
             if held is not None:
-                experts = {"assignments_held": int(held.sum()), "experts_hit": int(np.count_nonzero(held))}
+                books.experts = {
+                    "assignments_held": books.experts.get("assignments_held", 0) + int(held.sum()),
+                    "experts_hit": books.experts.get("experts_hit", 0) + int(np.count_nonzero(held)),
+                }
                 self.stats.record_experts(
-                    decoding * self.model.config.moe_top_k * held.shape[0], held,
+                    len(flight.lanes) * self.model.config.moe_top_k * held.shape[0], held,
                     prefill_held=sum(by_expert), prefill_hit=prefill_hit,
                 )
-            if live is not None:
-                live.set_metadata(retired=len(finished) - retired)
-        stamp = time.perf_counter()
-        phases["deliver"] = stamp - now
-        self._close_step(root, number, phases, stamp - t0, delivered, context, decoded=1, **experts)
-        return finished
+        books.close("deliver")
 
-    def _close_step(
-        self, root, number: int, phases: dict, seconds: float,
-        tokens: int = 0, context: int = 0, decoded: int = 0, **experts: int,
-    ) -> None:
+    def _close_step(self, root, number: int, books: _StepBooks) -> None:
         """The step's books: its phase split into the always-on counters
         (warm-up's compiles are not a serving step's time) and what only the
-        end of a step knows onto its ``engine.step`` span (``experts``: a
-        model with routed experts adds ``assignments_held``, ``experts_hit``)."""
+        end of a step knows onto its ``engine.step`` span: the tokens it
+        delivered, of which program (``landed``; ``experts``: a model with
+        routed experts adds ``assignments_held``, ``experts_hit``)."""
         if not self._warming:
-            self.stats.record_phases(number, phases, seconds)
+            self.stats.record_phases(number, books.phases, books.stamp - books.t0)
         if root is not None:
-            root.set_metadata(tokens=tokens, context=context, decoded=decoded, **experts)
+            landed = {} if books.landed is None else {"landed": books.landed}
+            root.set_metadata(
+                tokens=books.tokens, context=books.context, decoded=int(books.landed is not None),
+                **landed, **books.experts,
+            )
 
-    def _deliver(
-        self, t0, now, active_idx, quarantined, tokens_mat, emit, finite, drafted,
-        compiles_before, finished,
-    ) -> tuple[int, int]:
+    def _deliver(self, books: _StepBooks, flight: _Flight, tokens_mat, emit, finite, drafted, live) -> None:
         """Everything after the token fetch: act on each lane's verdict
         (quarantine, cancel, deliver its tokens, retire), release probed
-        slots, roll back speculative windows, record the step. Appends to
-        ``finished``; returns (tokens delivered, the sum of the live lengths
-        they were decoded at)."""
-        if self._watchdog is not None:
-            self._watchdog.disarm()
-        self._steps += 1
-        compiled_this_step = self.compiles.compile_count > compiles_before
+        slots, roll back speculative windows, record the step. ``flight`` is
+        the device step the tokens are of; a lane whose request left since
+        its dispatch has its token dropped. Appends to ``books.finished`` and
+        adds to the step's tokens and contexts."""
+        t0, now, finished = books.t0, books.stamp, books.finished
+        retired_before = len(finished)
+        if self._watchdog is not None and self._flight is None:
+            self._watchdog.disarm()  # nothing newer is in flight: this was the program it watched
+        compiled_this_step = self.compiles.compile_count > flight.compiles_before
         if (
             self.step_timeout_s is not None
             and self._decode_warm
@@ -2262,35 +2424,29 @@ class ServingEngine:
         self._decode_warm = True
         if self.tracer is not None:
             # `now` is the decode fence the engine already paid for: close
-            # every prefill span dispatched up to here (their device work is
-            # sequenced before this fence) and drop SAMPLED step marks into
-            # open decode spans — the tracer never adds a sync of its own
-            for rid in self._prefill_open:
+            # every prefill span dispatched before this program (their device
+            # work is sequenced before this fence) and drop SAMPLED step marks
+            # into open decode spans — the tracer never adds a sync of its own
+            for rid in [rid for rid, fence in self._prefill_open.items() if fence <= flight.number]:
                 self.tracer.span_end(rid, "prefill", stamp=now, stats=self.stats)
-            self._prefill_open.clear()
-            if self._steps % self.tracer.sample_every == 0:
-                for slot in active_idx:
-                    marked = self.scheduler.slots[slot]
-                    if marked is not None and self.cache.active[slot]:
-                        self.tracer.mark_decode(marked.id, self._steps, now)
+                del self._prefill_open[rid]
+            if (flight.number + 1) % self.tracer.sample_every == 0:
+                for slot in flight.lanes:
+                    marked = flight.requests[slot]
+                    if self.scheduler.slots[slot] is marked:
+                        self.tracer.mark_decode(marked.id, flight.number + 1, now)
 
-        delivered = context = 0
-        if self.windowed:
-            # what this step's tokens attended of each kind of cache, from the
-            # host's lengths: every cached token a full layer, the window's a
-            # window layer (before the loop below moves the lengths)
-            live = self.cache.lengths[self.cache.active].astype(np.int64)
-            self.stats.record_attended(
-                window=int(np.minimum(live, self.cache.window_tokens_per_slot - 1).sum()) * int(self.cache.wk.shape[0]),
-                full=int(live.sum()) * int(self.cache.k.shape[0]),
-            )
-        for slot in active_idx:
-            request = self.scheduler.slots[slot]
-            if request is None or not self.cache.active[slot]:
-                # a still-prefilling slot (or a page-pressure casualty):
-                # its lane ran as inactive this step — no token to deliver,
-                # no verdict to act on
+        contexts: list[int] = []
+        dropped = 0
+        for slot in flight.lanes:
+            request = flight.requests[slot]
+            if self.scheduler.slots[slot] is not request:
+                # retired, preempted or quarantined since this program went
+                # out (found late: EOS, a poisoned lane, cancel, deadline):
+                # the token it computed meanwhile is nobody's
+                dropped += 1
                 continue
+            request.in_flight -= 1
             if not finite[slot]:
                 # poisoned slot: quarantine + scrub it (0 × NaN = NaN, so
                 # masked poison would otherwise fail every probe forever).
@@ -2309,7 +2465,7 @@ class ServingEngine:
                 else:
                     self.scheduler.requeue_front(slot)
                     if self.tracer is not None:
-                        self._prefill_open.discard(request.id)
+                        self._prefill_open.pop(request.id, None)
                         self.tracer.interrupt(request.id, outcome="quarantined")
                         self.tracer.span_start(
                             request.id, "queued", replica=self.name,
@@ -2320,7 +2476,10 @@ class ServingEngine:
                         {"event": "quarantine", "slot": slot, "request_id": request.id}
                     )
                 # releases the lane AND the pages; fully-freed pages must
-                # scrub on device before the pool recycles them
+                # scrub on device before the pool recycles them. The scrubs
+                # are enqueued behind whatever is in flight, which wrote this
+                # lane's next entry into pages (and the ring) it held at
+                # dispatch: those are the ones scrubbed here
                 freed = self.cache.quarantine(slot)
                 if freed:
                     mask = np.zeros((self.cache.num_pages,), bool)
@@ -2344,11 +2503,11 @@ class ServingEngine:
                 self.stats.record_quarantine()
                 continue
             if request.cancelled:
-                # the cancel landed DURING this step (a server thread, or a
-                # router failing the replica over) — it must win over natural
-                # retirement, or cancel()'s True is contradicted by a
-                # same-step "length"/"eos" result and whoever released
-                # per-request state on the ack frees it twice
+                # the cancel landed while this program ran (a server thread,
+                # or a router failing the replica over) — it must win over
+                # natural retirement, or cancel()'s True is contradicted by a
+                # "length"/"eos" result and whoever released per-request
+                # state on the ack frees it twice
                 self.cache.retire(slot)
                 done = self.scheduler.retire(slot, "cancelled")
                 self._record_degraded(done, slot=slot)
@@ -2363,11 +2522,9 @@ class ServingEngine:
             token = 0
             retired = False
             for j in range(count):
-                delivered += 1
                 token = int(tokens_mat[slot, j])
                 request.generated.append(token)
-                context += int(self.cache.lengths[slot])
-                self.cache.lengths[slot] += 1
+                contexts.append(int(flight.lengths[slot]) + j)
                 if request.first_token_at is None:
                     request.first_token_at = now
                     if self.tracer is not None:
@@ -2387,18 +2544,21 @@ class ServingEngine:
                 continue
             if request.past_deadline(now):
                 # the deadline passed during the decode: retiring here (with
-                # the partial output, this step's tokens included) saves the
-                # doomed request one more decode step vs waiting for the
+                # the partial output, this program's tokens included) saves
+                # the doomed request a decode step vs waiting for the
                 # top-of-next-step sweep
                 self.cache.retire(slot)
                 done = self.scheduler.retire(slot, "expired")
                 self._record_degraded(done, slot=slot)
                 finished.append(self._result_for(done))
-            else:
+            elif emit is not None:
+                # a speculative step reads its input token on the host; a
+                # plain one's is on the device, in the program's `prev`
                 self._pending[slot] = token
 
-        for slot in quarantined:
-            # the probe IS this step's decode of the (empty) quarantined slot
+        for slot in flight.quarantined:
+            # the probe IS this program's decode of the (empty) quarantined
+            # slot (such a program lands in the step that dispatched it)
             if finite[slot]:
                 self.cache.release_quarantined(slot)
                 self._probe_failures.pop(slot, None)
@@ -2412,27 +2572,43 @@ class ServingEngine:
             # hold the whole window — release the pages the accepted prefix
             # didn't reach (refcounts drop; tree losers were already dropped
             # at commit) and advance the draft pool's high-water mark
-            for slot in active_idx:
-                if not drafted[slot]:
-                    continue
-                request = self.scheduler.slots[slot]
-                if request is None or not self.cache.active[slot]:
+            for slot in flight.lanes:
+                if not drafted[slot] or self.scheduler.slots[slot] is not flight.requests[slot]:
                     continue  # retired/quarantined mid-window: pages already released
                 self.cache.trim_to_length(slot)
                 if self.spec.draft_ok[slot]:
                     self.spec.draft_len[slot] = int(self.cache.lengths[slot])
 
+        if self.windowed:
+            # what the delivered tokens attended of each kind of cache, from
+            # the lengths they were decoded at: every cached token a full
+            # layer, the window's a window layer
+            live_lengths = np.asarray(contexts, np.int64)
+            self.stats.record_attended(
+                window=int(np.minimum(live_lengths, self.cache.window_tokens_per_slot - 1).sum()) * int(self.cache.wk.shape[0]),
+                full=int(live_lengths.sum()) * int(self.cache.k.shape[0]),
+            )
+        context = sum(contexts)
         self.stats.record_step(
-            now - t0, active=len(active_idx), waiting=self.scheduler.waiting,
-            tokens=delivered,
+            now - t0, active=flight.occupied, waiting=self.scheduler.waiting,
+            tokens=len(contexts),
             pages_in_use=self.cache.pages_in_use,
             context=context,
+            dropped=dropped,
         )
-        return delivered, context
+        books.landed = flight.number
+        books.tokens += len(contexts)
+        books.context += context
+        if live is not None:
+            live.set_metadata(retired=len(finished) - retired_before)
 
     @property
     def busy(self) -> bool:
-        return self.scheduler.busy
+        """Whether ``step()`` has anything left to do or to hand back: a
+        request queued or seated (one whose last token is in flight stays
+        seated), a program whose tokens nobody waits for any more, or results
+        a landing outside ``step()`` carried over."""
+        return self.scheduler.busy or self._flight is not None or bool(self._carried)
 
     def run(self) -> dict[int, ServingResult]:
         """Drive ``step()`` until queue and slots drain; results by id."""
@@ -2463,8 +2639,8 @@ class ServingEngine:
         view of exactly the program ``step()`` runs. The page tables ride as
         an argument here just as in ``step()``, so the baked-constant scan
         proves no table ever froze into the program."""
-        keys = jax.random.split(self._rng, self.cache.num_slots)
-        return self._paged_decode_program().lower(self.params, *self._decode_arguments(keys))
+        self._land()
+        return self._paged_decode_program().lower(self.params, *self._decode_arguments(self._sampling_keys(0)))
 
     def _page_shape(self) -> tuple:
         """One page's block shape ``[L, page_size, KV, D]`` — the fixed unit
@@ -2490,6 +2666,7 @@ class ServingEngine:
         the transferable case — its dict carries ``parked: True`` and the
         ``last_token`` the destination decodes first. None when the request
         holds no pages here."""
+        self._land()  # a seated request's `length` is the host's, which runs ahead of a program in flight
         parked = self._parked.get(request_id)
         if parked is not None:
             return {"slot": None, "parked": True, **parked}
@@ -2520,6 +2697,7 @@ class ServingEngine:
         self._refuse_for_window_layers(
             self.windowed and "extract_pages: a handoff moves pages, and the window layers' rings are in none"
         )
+        self._land()
         program = self._page_extract_program()
         out = [program(self.cache.k, self.cache.v, np.int32(page)) for page in pages]
         return (
@@ -2593,6 +2771,7 @@ class ServingEngine:
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new_tokens}) "
                 f"exceeds the slot capacity max_len={self.cache.max_len}"
             )
+        self._land()
         if self._draining:
             raise QueueFull(
                 "engine is draining — not adopting new requests",
@@ -2694,6 +2873,7 @@ class ServingEngine:
         parked = self._parked.get(request_id)
         if parked is None:
             return False
+        self._land()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         slot = self.cache.seat(parked["pages"], parked["length"])
         if slot is None:

@@ -90,6 +90,10 @@ class Request:
     # once the slot is decode-visible)
     prefilled: int = 0
     prefix_hit: int = 0  # tokens reused from the prefix cache at admission
+    # decode programs dispatched with this request on a lane whose tokens the
+    # host has not read yet (the engine dispatches a step before it lands the
+    # last): counted with ``generated`` toward ``max_new_tokens``
+    in_flight: int = 0
 
     @property
     def deadline_at(self) -> Optional[float]:
@@ -211,6 +215,7 @@ class ContinuousBatchingScheduler:
         self.slots[slot] = None
         request.slot = None
         request.generated = []
+        request.in_flight = 0  # whatever is still on the device is dropped at landing
         request.first_token_at = None  # TTFT restarts honestly: no trusted token yet
         request.prefilled = 0  # the cache pages are gone; prefill restarts too
         request.prefix_hit = 0

@@ -8,9 +8,10 @@ engine working* (throughput, slot occupancy, queue depth). One
 per step and per request, and ``snapshot()`` flattens to the same
 scalar-dict shape the hub's trackers and ``telemetry.jsonl`` expect.
 
-The decode step's host fetch (the engine reads each step's tokens to test
-EOS) doubles as the timing fence, so per-step durations here are real wall
-times — no extra synchronization is added to measure.
+The decode step's host fetch (the engine reads each program's tokens to test
+EOS, one ``step()`` after it dispatched the program and with the next one
+already queued) doubles as the timing fence, so per-step durations here are
+real wall times — no extra synchronization is added to measure.
 """
 
 from __future__ import annotations
@@ -58,7 +59,9 @@ class ServingStats:
         self.num_pages = num_pages
         self.page_size = page_size
         self.first_decode_at: Optional[float] = None
-        self.steps = 0
+        self.steps = 0  # decode programs landed (their tokens fetched and delivered)
+        self.decode_overlapped = 0  # decode programs dispatched while the one before was still in flight
+        self.tokens_dropped_late = 0  # tokens computed for lanes found retired at landing (EOS, cancel, ... seen a program late)
         self.decode_seconds = 0.0
         self.step_seconds: list[float] = []  # wall time per decode step
         self.ttft_seconds: list[float] = []  # submit → first token, per request
@@ -292,6 +295,10 @@ class ServingStats:
         self.attended_window_tokens += window
         self.attended_full_tokens += full
 
+    def record_dispatch(self, overlapped: bool) -> None:
+        """One decode program enqueued, with the one before it landed or not."""
+        self.decode_overlapped += overlapped
+
     def record_step(
         self,
         duration_s: float,
@@ -300,13 +307,16 @@ class ServingStats:
         tokens: Optional[int] = None,
         pages_in_use: int = 0,
         context: int = 0,
+        dropped: int = 0,
     ) -> None:
         """``tokens`` = tokens actually delivered this step (defaults to
         ``active``; the engine passes fewer when a quarantined slot's token
         was discarded — throughput must never count undelivered tokens).
         ``pages_in_use`` feeds the paged-pool economy metrics. ``context`` =
         the sum, over the delivered tokens, of the live length each was
-        decoded at: what decode attention had to read, in tokens."""
+        decoded at: what decode attention had to read, in tokens. ``dropped``
+        = tokens the program computed for lanes whose request had left by the
+        time it landed."""
         if self.first_decode_at is None:
             self.first_decode_at = time.perf_counter() - duration_s
         self.steps += 1
@@ -314,6 +324,7 @@ class ServingStats:
         _keep(self.step_seconds, duration_s, self.max_samples)
         self.tokens_generated += active if tokens is None else tokens
         self.decode_context_tokens += context
+        self.tokens_dropped_late += dropped
         self.occupancy_sum += active / self.num_slots
         self.queue_depth_sum += waiting
         self.max_active = max(self.max_active, active)
@@ -350,6 +361,8 @@ class ServingStats:
         out = {
             "num_slots": self.num_slots,
             "steps": self.steps,
+            "decode_overlapped": self.decode_overlapped,
+            "tokens_dropped_late": self.tokens_dropped_late,
             "tokens_generated": self.tokens_generated,
             "prefill_tokens": self.prefill_tokens,
             "prefill_tokens_real": self.prefill_tokens_real,
@@ -474,7 +487,7 @@ def fleet_rollup(
     if not stats_list:
         return out
     counters = (
-        "steps", "tokens_generated", "prefill_tokens", "prefill_tokens_real",
+        "steps", "decode_overlapped", "tokens_dropped_late", "tokens_generated", "prefill_tokens", "prefill_tokens_real",
         "decode_context_tokens", "admissions", "requests_submitted",
         "requests_completed", "requests_rejected", "requests_expired",
         "requests_cancelled", "requests_requeued", "requests_failed",

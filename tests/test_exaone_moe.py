@@ -224,10 +224,11 @@ def test_a_quarantined_lane_has_its_ring_scrubbed_and_its_probe_recovers(tiny):
     engine = ServingEngine(model, params, **{**ENGINE, "num_slots": 1})
     [prompt] = _prompts(cfg, [11], seed=5)
     rid = engine.submit(prompt, max_new_tokens=6)
-    engine.step()  # prefilled, and a first token decoded
+    engine.step()  # prefilled, and a first decode program out
     wk, wv, counts = engine.cache.extras
     engine.cache.extras = (wk.at[:, 0].set(jnp.nan), wv, counts)
-    engine.step()
+    engine.step()  # the program that attends the poisoned ring goes out; the clean one's token lands
+    engine.step()  # its verdict lands, one program late: the lane and the ring it wrote meanwhile are scrubbed
     assert engine.cache.quarantined == frozenset({0}) and engine.scheduler.waiting == 1
     assert not np.asarray(engine.cache.wk[:, 0]).any() and not np.asarray(engine.cache.wv[:, 0]).any()  # zeros, not NaN
     engine.step()  # the probe alone rides this step

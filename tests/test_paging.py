@@ -511,7 +511,8 @@ def test_chunked_prefill_preserves_admitted_decode_cadence(llama):
         model, params, num_slots=2, max_len=48, page_size=8, prefill_chunk=8
     )
     short = engine.submit(_prompts([4], seed=50)[0], max_new_tokens=10)
-    engine.step()  # short admitted, prefilled, first token out
+    engine.step()  # short admitted, prefilled, its first decode program out
+    engine.step()  # the first token landed, the second in flight
     short_req = next(r for r in engine.scheduler.slots if r is not None and r.id == short)
     assert len(short_req.generated) == 1
     long_prompt = _prompts([33], seed=51)[0]  # prefill 32 = 4 chunks of 8
@@ -521,8 +522,11 @@ def test_chunked_prefill_preserves_admitted_decode_cadence(llama):
         assert len(short_req.generated) == 2 + step  # cadence: +1 per step
     long_req = next(r for r in engine.scheduler.slots if r is not None and r.id == lid)
     assert long_req.prefilled == 32
-    # the 4th chunk step made the long slot decode-visible that same step
-    assert len(long_req.generated) == 1
+    # the 4th chunk step made the long slot decode-visible that same step:
+    # its first token is in flight, and lands with the next
+    assert long_req.in_flight == 1 and len(long_req.generated) == 0
+    engine.step()
+    assert len(long_req.generated) == 1 and len(short_req.generated) == 6
     assert engine.stats.prefill_chunks >= 4
     results = engine.run()
     # split points change nothing: chunked output bit-equal sequential
@@ -572,8 +576,9 @@ def test_quarantine_scrubs_freed_pages_on_device(llama):
     engine.step()
     pages = engine.cache.pages_of(0)
     engine.cache.k = engine.cache.k.at[:, np.asarray(pages)].set(jnp.nan)
-    engine.step()  # non-finite verdict -> quarantine + device scrub
-    assert engine.stats.slot_quarantines == 1
+    engine.step()  # the program that reads the poison goes out
+    engine.step()  # its non-finite verdict lands -> quarantine + device scrub, behind the program then in flight
+    assert engine.stats.slot_quarantines == 1 and engine.stats.tokens_dropped_late == 1 and engine._flight is None
     for page in pages:
         np.testing.assert_array_equal(
             np.asarray(engine.cache.k[:, page], np.float32), 0.0
@@ -613,3 +618,35 @@ def test_routed_paged_fleet_zero_steady_state_recompiles(llama):
     for prompt, out in zip(prompts, outs):
         expected = generate(model, params, prompt[None], max_new_tokens=5)[0]
         np.testing.assert_array_equal(out, np.asarray(expected))
+
+
+@pytest.mark.parametrize("page_size", [4, 8])
+def test_a_token_in_flight_that_crosses_a_page_boundary_has_its_page(llama, page_size):
+    """The host's books lead the device by a program: ``cache.lengths`` moves
+    on at dispatch, so the page a lane's NEXT write needs is grown before the
+    program that writes it goes out, while the last program's token is still
+    in flight. Every write of a program in flight lies in a page its lane
+    holds, first entries of fresh pages included, and the streams are
+    ``generate()``'s."""
+    model, params = llama
+    engine = ServingEngine(model, params, num_slots=2, max_len=48, page_size=page_size, prefix_sharing=False)
+    prompts = _prompts([page_size - 1, 2 * page_size + 2], seed=90)
+    budget = 2 * page_size + 3
+    ids = [engine.submit(p, max_new_tokens=budget) for p in prompts]
+    results, fresh_pages = {}, 0
+    while engine.busy:
+        for result in engine.step():
+            results[result.request_id] = result
+        flight = engine._flight
+        for slot in flight.lanes if flight is not None else ():
+            if engine.scheduler.slots[slot] is not flight.requests[slot]:
+                continue  # retired by the landing that followed the dispatch: its pages are gone with it
+            position = int(flight.lengths[slot])  # where the program in flight writes this lane's entry
+            assert position // page_size < engine.cache.held[slot] and engine.cache.tables[slot, position // page_size] != 0
+            assert engine.cache.lengths[slot] == position + 1  # the host's length runs one ahead of it
+            fresh_pages += position % page_size == 0
+    assert fresh_pages >= 4 and engine.stats.decode_overlapped == engine.stats.steps - 1
+    for p, rid in zip(prompts, ids):
+        expected = np.asarray(generate(model, params, p[None], max_new_tokens=budget))
+        np.testing.assert_array_equal(results[rid].generated, expected[0][p.size:])
+    assert engine.cache.pages_in_use == 0

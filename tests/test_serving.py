@@ -207,8 +207,9 @@ def test_eos_retirement_frees_slot_next_step(llama):
     assert len(first.generated) == 2  # stopped at the EOS hit, not at 8
     assert first.generated[-1] == eos
     assert results[1].finish_reason == "length"
-    # 2 steps for the eos request + 2 for the queued one
-    assert engine.stats.steps == 4
+    # 2 programs for the eos request, the one that was in flight when its EOS
+    # landed (its token dropped, never delivered), 2 for the queued one
+    assert engine.stats.steps == 5 and engine.stats.tokens_dropped_late == 1
 
 
 def test_admission_control_queue_full(llama):
@@ -271,7 +272,8 @@ def test_expired_active_request_frees_slot_by_next_step(llama):
     engine = ServingEngine(model, params, num_slots=1, max_len=32)
     a = engine.submit(_prompts([4], seed=22)[0], max_new_tokens=8)
     b = engine.submit(_prompts([5], seed=23)[0], max_new_tokens=2)
-    engine.step()  # A admitted + one decode
+    engine.step()  # A admitted + one decode program out
+    engine.step()  # a second one out, the first one's token landed
     engine.scheduler.slots[0].deadline_s = 0.0  # deterministic expiry, no sleeps
     results = {}
     while engine.busy:
@@ -281,8 +283,10 @@ def test_expired_active_request_frees_slot_by_next_step(llama):
     assert 1 <= results[a].generated.size < 8  # partial output survives
     assert results[b].finish_reason == "length"
     assert len(results[b].generated) == 2
-    # A decoded once, B twice — the expired slot never burned another step
-    assert engine.stats.steps == 3
+    # A decoded twice (the token in flight at its expiry dropped), B twice —
+    # the expired slot never burned another step
+    assert engine.stats.steps == 4 and engine.stats.tokens_dropped_late == 1
+    assert results[a].generated.size == 1
 
 
 def test_cancel_queued_and_active_requests(llama):
@@ -339,7 +343,8 @@ def test_quarantined_slot_never_serves_until_probe_passes(with_and_without_rings
     engine.submit(_prompts([4], model.config.vocab_size, seed=28)[0], max_new_tokens=2)
     engine.step()
     _poison_slot_kv(engine, 0)
-    engine.step()  # quarantine fires; request back at queue head
+    engine.step()  # the program that reads the poison goes out; the clean one's token lands
+    engine.step()  # quarantine fires, one program late; request back at queue head
     assert engine.cache.quarantined == frozenset({0})
     assert engine.scheduler.waiting == 1
     assert engine.scheduler.active_slots == []
@@ -586,3 +591,293 @@ def test_generate_done_mask_matches_host_truncation_semantics(llama):
         if hits.size:
             expected[row, 4 + hits[0] + 1 :] = eos
     np.testing.assert_array_equal(with_eos, expected)
+
+
+# -- one decode program in flight: step() dispatches program k before it lands program k - 1 ---------
+
+
+def _pipelined_engine(model, params, **kwargs):
+    """Geometry that serves the tiny llama and the tiny ``exaone_moe`` (rings)
+    alike: small pages, two buckets, prompts beyond them in chunks."""
+    args = dict(num_slots=2, max_len=80, page_size=8, buckets=(8, 16), prefill_chunk=16, prefix_sharing=False)
+    return ServingEngine(model, params, **{**args, **kwargs})
+
+
+def _reference(model, params, prompt, new):
+    return np.asarray(generate(model, params, prompt[None], max_new_tokens=new))[0][prompt.size:]
+
+
+def _drain_steps(engine, results):
+    """Step until nothing is left, each result once."""
+    while engine.busy:
+        for result in engine.step():
+            assert result.request_id not in results  # no result returned twice
+            results[result.request_id] = result
+    assert engine._flight is None
+    return results
+
+
+def test_tokens_equal_generate_with_a_program_in_flight_throughout(with_and_without_rings):
+    """Mixed lengths, lanes joining mid-stream (one through a chunked
+    prefill): every step but the last leaves a program in flight, every
+    program but the first went out with the one before it still in flight,
+    and every request's tokens are per-request ``generate()``'s to the bit."""
+    model, params = with_and_without_rings
+    engine = _pipelined_engine(model, params, num_slots=3)
+    prompts = _prompts([4, 9, 40, 5, 21, 7], model.config.vocab_size, seed=70)
+    budgets = [26, 3, 5, 4, 6, 2]  # the first request holds a lane from the first program to the last
+    ids, results, later = [engine.submit(prompts[0], budgets[0])], {}, list(zip(prompts[1:], budgets[1:]))
+    calls = 0
+    while engine.busy:
+        if later and calls % 2 == 0:
+            ids.append(engine.submit(*later.pop(0)))
+        for result in engine.step():
+            assert result.request_id not in results
+            results[result.request_id] = result
+        calls += 1
+        assert (engine._flight is not None) == (ids[0] not in results)
+    for prompt, budget, rid in zip(prompts, budgets, ids):
+        np.testing.assert_array_equal(results[rid].generated, _reference(model, params, prompt, budget))
+        assert results[rid].finish_reason == "length"
+    stats = engine.stats
+    assert stats.steps == budgets[0] == calls - 1  # a program a call, and the last call lands the last one
+    assert stats.decode_overlapped == stats.steps - 1 and stats.tokens_dropped_late == 0
+    assert stats.prefill_chunks >= 2 and stats.tokens_generated == sum(budgets)
+    snapshot = engine.metrics()
+    assert snapshot["decode_overlapped"] == stats.decode_overlapped and snapshot["tokens_dropped_late"] == 0
+    assert engine.cache.pages_in_use == 0 and not engine.cache.active.any()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_landing_every_program_where_it_is_dispatched_changes_no_token(llama, temperature):
+    """The sampling keys are a function of (program number, slot), and the
+    host's leading books change no program's inputs: an engine made to land
+    after every step serves the same streams, sampled ones too."""
+    model, params = llama
+    prompts = _prompts([5, 12, 30, 3, 8], seed=72)  # a lane each: landing sooner must not re-seat a lane sooner
+    streams = []
+    for synchronous in (False, True):
+        engine = _pipelined_engine(model, params, num_slots=5, temperature=temperature, rng=jax.random.key(5))
+        ids = [engine.submit(prompt, max_new_tokens=7) for prompt in prompts]
+        results = {}
+        while engine.busy:
+            for result in engine.step():
+                results[result.request_id] = result
+            if synchronous:
+                engine._land()  # what it finishes comes out of the next step()
+                assert engine._flight is None
+        assert (engine.stats.decode_overlapped == 0) == synchronous
+        streams.append([results[rid].generated.tolist() for rid in ids])
+    assert streams[0] == streams[1] and all(len(stream) == 7 for stream in streams[0])
+
+
+def test_eos_found_a_program_late_drops_the_token_after_it(with_and_without_rings):
+    """EOS is in the token, so the host sees it one program late: the lane has
+    run one program more by then. That token is dropped, never delivered, and
+    its pages go back once."""
+    model, params = with_and_without_rings
+    prompts = _prompts([6, 11], model.config.vocab_size, seed=73)
+    references = [_reference(model, params, prompt, 8) for prompt in prompts]
+    eos = int(references[0][2])
+    engine = _pipelined_engine(model, params, eos_token_id=eos)
+    ids = [engine.submit(prompt, max_new_tokens=8) for prompt in prompts]
+    results = _drain_steps(engine, {})
+    late = 0
+    for rid, reference in zip(ids, references):
+        hits = np.flatnonzero(reference == eos)
+        expected = reference[: hits[0] + 1] if hits.size else reference
+        np.testing.assert_array_equal(results[rid].generated, expected)  # nothing past EOS
+        assert results[rid].finish_reason == ("eos" if hits.size else "length")
+        late += bool(hits.size and expected.size < 8)  # an EOS on the budget's last token was known to be the last
+    assert engine.stats.tokens_dropped_late == late >= 1
+    assert engine.stats.tokens_generated == sum(results[rid].generated.size for rid in ids)
+    assert engine.cache.pages_in_use == 0 and not engine.cache.pages.refcounts[1:].any()
+    assert engine.cache.lanes.free_count == 2
+
+
+@pytest.mark.parametrize("when", ["between_steps", "mid_step"])
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_cancel_and_deadline_land_with_a_program_in_flight(llama, how, when):
+    """A cancel or a deadline reaches a request whose next token is in flight:
+    that token is dropped, the request ends with its reason and what was
+    delivered before, once, and the lane beside it is served as if alone.
+    Mid-step (the flag flips while the next program is dispatched) a cancel
+    still wins over the token that lands in that step; a deadline keeps it."""
+    model, params = llama
+    engine = _pipelined_engine(model, params)
+    prompts = _prompts([5, 9], seed=74)
+    doomed, other = (engine.submit(prompt, max_new_tokens=budget) for prompt, budget in zip(prompts, (9, 6)))
+    results = {}
+    for _ in range(2):  # a token landed, one in flight
+        for result in engine.step():
+            results[result.request_id] = result
+    request = next(r for r in engine.scheduler.slots if r.id == doomed)
+    assert len(request.generated) == 1 and request.in_flight == 1 and engine._flight is not None
+
+    def doom():
+        if how == "cancel":
+            assert engine.cancel(doomed)
+        else:
+            request.deadline_s = 0.0
+
+    if when == "between_steps":
+        doom()
+    else:
+        real, waiting = engine._paged_decode_program, [doom]
+
+        def hooked():
+            program = real()
+
+            def wrapper(*args):
+                if waiting:
+                    waiting.pop()()
+                return program(*args)
+
+            return wrapper
+
+        engine._paged_decode_program = hooked
+    _drain_steps(engine, results)
+    kept = 2 if (how, when) == ("deadline", "mid_step") else 1
+    assert results[doomed].finish_reason == {"cancel": "cancelled", "deadline": "expired"}[how]
+    np.testing.assert_array_equal(results[doomed].generated, _reference(model, params, prompts[0], 9)[:kept])
+    np.testing.assert_array_equal(results[other].generated, _reference(model, params, prompts[1], 6))
+    assert engine.stats.tokens_dropped_late == 1 and engine.cache.pages_in_use == 0
+
+
+def test_a_poisoned_lane_is_quarantined_a_program_late_and_the_lane_beside_it_is_untouched(with_and_without_rings):
+    """The non-finite verdict lands one program late: the lane has written one
+    more entry by then, into pages (and the ring) it held at dispatch. All of
+    it is scrubbed behind that program, its token dropped; the null page and
+    the other lane never see the poison, and the requeued request is served
+    from its prompt."""
+    model, params = with_and_without_rings
+    engine = _pipelined_engine(model, params)
+    prompts = _prompts([7, 12], model.config.vocab_size, seed=75)
+    ids = [engine.submit(prompt, max_new_tokens=9) for prompt in prompts]
+    results = {}
+    for _ in range(2):
+        engine.step()
+    slot = next(r.slot for r in engine.scheduler.slots if r.id == ids[0])
+    pages = engine.cache.pages_of(slot)
+    _poison_slot_kv(engine, slot)
+    if engine.windowed:
+        wk, wv, counts = engine.cache.extras
+        engine.cache.extras = (wk.at[:, slot].set(jnp.nan), wv, counts)
+    engine.step()  # the program that reads the poison goes out
+    assert engine.stats.slot_quarantines == 0
+    for result in engine.step():  # its verdict lands; the step ends landed, its scrubs enqueued behind everything
+        results[result.request_id] = result
+    assert engine.stats.slot_quarantines == 1 and engine.cache.quarantined == frozenset({slot}) and engine._flight is None
+    assert engine.stats.tokens_dropped_late == 1
+    assert not np.asarray(engine.cache.k[:, np.asarray(pages)], np.float32).any()  # zeros, the late write included
+    if engine.windowed:
+        assert not np.asarray(engine.cache.wk[:, slot], np.float32).any()
+    _drain_steps(engine, results)
+    assert engine.stats.slot_quarantine_releases == 1 and engine.stats.requests_requeued == 1
+    for prompt, rid in zip(prompts, ids):
+        np.testing.assert_array_equal(results[rid].generated, _reference(model, params, prompt, 9))
+    assert np.isfinite(np.asarray(engine.cache.k, np.float32)).all() and np.isfinite(np.asarray(engine.cache.v, np.float32)).all()
+
+
+@pytest.mark.parametrize("entry", [
+    "run", "generate_many", "drain", "extract_pages", "adopt_kv", "park", "resume_parked", "kv_page_layout",
+    "lower_decode",
+])
+def test_whatever_needs_host_and_device_agreed_lands_first_and_loses_no_result(llama, entry):
+    """Each of these is called with a decode program in flight and leaves none;
+    what the landing finished is handed out by the next ``step()``, once, and
+    the streams are ``generate()``'s all the same."""
+    model, params = llama
+    engine = _pipelined_engine(model, params, num_slots=3)
+    prompts = _prompts([5, 9], seed=76)
+    budgets = [3, 8]  # after two steps the first request's last token is in flight
+    ids = [engine.submit(prompt, max_new_tokens=budget) for prompt, budget in zip(prompts, budgets)]
+    results = {}
+    for _ in range(2):
+        for result in engine.step():
+            results[result.request_id] = result
+    assert engine._flight is not None and not results
+    extra = {}  # request id -> (prompt, budget) of what the entry itself brought in
+    parked_prompt = _prompts([13], seed=77)[0]
+    if entry == "run":
+        results.update(engine.run())
+    elif entry == "generate_many":  # it hands out its own prompts' rows and, as ever, nobody else's results
+        rows = engine.generate_many([parked_prompt], max_new_tokens=4)
+        np.testing.assert_array_equal(rows[0][parked_prompt.size:], _reference(model, params, parked_prompt, 4))
+        assert engine._flight is None and not engine.busy and engine.stats.requests_completed == 3
+        return
+    elif entry == "drain":
+        queued = engine.submit(parked_prompt, max_new_tokens=4)
+        time.sleep(0.05)  # the program in flight is long done when the drain lands it
+        payloads, retired = engine.drain()
+        assert [p["request_id"] for p in payloads] == [queued] and not retired and engine.draining
+        # the landed program's seconds run from its dispatch: a wait quote is never priced from a fetch that found it done
+        assert engine.stats.step_seconds[-1] >= 0.05
+        assert engine.drain_eta_hint() > 0
+    elif entry == "extract_pages":
+        blocks_k, _ = engine.extract_pages([0])
+        assert blocks_k.shape[0] == 1
+    elif entry == "kv_page_layout":
+        layout = engine.kv_page_layout(ids[1])
+        request = next(r for r in engine.scheduler.slots if r is not None and r.id == ids[1])
+        assert layout["length"] == prompts[1].size - 1 + len(request.generated)  # the landed length: host and device agree
+    elif entry == "lower_decode":
+        engine._lower_decode()
+    else:
+        source = _pipelined_engine(model, params)
+        rid = source.submit(parked_prompt, max_new_tokens=5, prefill_only=True, request_id=900)
+        decoding = source.submit(prompts[0], max_new_tokens=6, request_id=901)
+        prefilled = []
+        while not prefilled:  # the step that parks ends landed, whatever decodes beside the prefill
+            prefilled = [r for r in source.step() if r.finish_reason == "prefilled"]
+            assert not prefilled or source._flight is None
+        layout = source.kv_page_layout(rid)
+        if entry == "park":
+            assert layout["parked"] and source.parked_count == 1
+            assert source.release_parked(rid)
+        elif entry == "adopt_kv":
+            blocks = source.extract_pages(layout["pages"])
+            assert engine.adopt_kv(parked_prompt, 5, layout, *blocks, request_id=rid) == rid
+            assert source.release_parked(rid) and source._flight is None
+            extra[rid] = (parked_prompt, 5)
+        else:
+            source.step()
+            assert source._flight is not None
+            assert source.resume_parked(rid, parked_prompt, 5) and source._flight is None
+            done = _drain_steps(source, {})
+            np.testing.assert_array_equal(done[rid].generated, _reference(model, params, parked_prompt, 5))
+        done = _drain_steps(source, {})
+        if entry != "resume_parked":
+            np.testing.assert_array_equal(done[decoding].generated, _reference(model, params, prompts[0], 6))
+    assert engine._flight is None or entry in ("park", "resume_parked")  # those two are the source's
+    if entry == "run":
+        assert not engine.busy
+    _drain_steps(engine, results)
+    for rid, (prompt, budget) in {**dict(zip(ids, zip(prompts, budgets))), **extra}.items():
+        np.testing.assert_array_equal(results[rid].generated, _reference(model, params, prompt, budget))
+    assert engine.stats.tokens_dropped_late == 0
+
+
+def test_a_speculative_engine_lands_every_step_where_it_is_made_until_it_is_disabled(llama):
+    """The speculative step interleaves its own fetches and stays synchronous;
+    once speculation is disabled mid-stream the plain program goes out ahead
+    of its landing like any other, and the stream is the same tokens."""
+    from accelerate_tpu.serving import SpeculativeConfig
+
+    model, params = llama
+    draft = Llama(model.config.replace(num_layers=1))
+    config = SpeculativeConfig(draft_model=draft, draft_params=draft.init(jax.random.key(7)), k=3)
+    engine = ServingEngine(model, params, num_slots=2, max_len=64, page_size=8, speculative=config)
+    prompts = _prompts([6, 10, 4], seed=78)
+    ids = [engine.submit(prompt, max_new_tokens=12) for prompt in prompts]
+    results = {}
+    for _ in range(3):
+        for result in engine.step():
+            results[result.request_id] = result
+        assert engine._flight is None
+    assert engine.stats.spec_steps > 0 and engine.stats.decode_overlapped == 0
+    engine.disable_speculation("test")
+    _drain_steps(engine, results)
+    assert engine.stats.decode_overlapped > 0 and engine.stats.tokens_dropped_late == 0
+    for prompt, rid in zip(prompts, ids):
+        np.testing.assert_array_equal(results[rid].generated, _reference(model, params, prompt, 12))
