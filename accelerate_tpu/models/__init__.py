@@ -5,11 +5,12 @@ from .exaone_moe import ExaoneMoe
 from .generation import generate
 from .gpt2 import GPT2
 from .llama import Llama
+from .mellum import Mellum
 from .moe import MoEBlock
 from .t5 import T5
 
 
-_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5, "exaone_moe": ExaoneMoe}
+_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5, "exaone_moe": ExaoneMoe, "mellum": Mellum}
 
 
 def build_model(name: str):

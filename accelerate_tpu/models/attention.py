@@ -7,6 +7,7 @@ the same signature for long sequences (parallel/ring_attention.py).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -38,6 +39,43 @@ def rotary_embedding(positions: jax.Array, head_dim: int, theta: float = 10000.0
     freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     angles = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float, original_max: int, beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies [D/2] as the source's ``rope_type: yarn``
+    defines them, and the ramp's bounds. ``dim(n) = D ln(original_max / (2 pi
+    n)) / (2 ln theta)`` is the pair whose wave turns ``n`` times over the
+    original context; pairs below ``low = floor(dim(beta_fast))`` keep their
+    frequency (they turn often: extrapolated), pairs above ``high =
+    ceil(dim(beta_slow))`` have it divided by ``factor`` (interpolated), and
+    between the two a linear ramp mixes them."""
+    pairs = head_dim // 2
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+
+    def dim(turns):
+        return head_dim * math.log(original_max / (2.0 * math.pi * turns)) / (2.0 * math.log(theta))
+
+    low, high = max(math.floor(dim(beta_fast)), 0), min(math.ceil(dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(pairs, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return (base / factor) * ramp + base * (1.0 - ramp), (low, high)
+
+
+def yarn_rotary_embedding(
+    positions: jax.Array, head_dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0, attention_factor: Optional[float] = None,
+):
+    """YaRN cos/sin tables for ``positions`` [..., S] → two [..., S, D/2]
+    float32 arrays: the angles of :func:`yarn_frequencies`, and BOTH tables
+    multiplied by ``attention_factor`` (default ``0.1 ln(factor) + 1``), so
+    that q . k grows by its square: YaRN's temperature, carried by the tables."""
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    with jax.named_scope("rope.yarn"):
+        freqs, _ = yarn_frequencies(head_dim, theta, factor, original_max, beta_fast, beta_slow)
+        angles = positions[..., None].astype(jnp.float32) * freqs
+        return jnp.cos(angles) * attention_factor, jnp.sin(angles) * attention_factor
 
 
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -117,3 +155,54 @@ def dot_product_attention(
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return grouped_output(probs, v)
+
+
+# a cache view at least this long is attended a block of keys at a time
+# (:func:`cached_causal_attention`); shorter ones in one product over the view
+LONG_VIEW = 4096
+KEY_BLOCK = 512
+
+
+def cached_causal_attention(
+    q: jax.Array,  # [B, S, N, D]: a span's queries at positions length ..
+    k: jax.Array,  # [B, T, K, D]: the cache view with the span's own keys written at length ..
+    v: jax.Array,  # [B, T, K, D]
+    length,  # scalar: positions cached before the span
+    mask: Optional[jax.Array] = None,  # what dot_product_attention takes for the same: key j <= query's position
+) -> jax.Array:
+    """Causal grouped-query attention of a span over its cache view. A short
+    view goes through :func:`dot_product_attention` under ``mask`` as it
+    always did. A long one (``LONG_VIEW`` positions or more, in whole
+    ``KEY_BLOCK`` s) is attended a block of keys at a time under an online
+    softmax, and only the blocks that hold a live key: the work follows the
+    live context, not the view's length (a 1024-token span of a 12,800-position
+    view held 1.7 GB of float32 scores a full layer, whatever the context;
+    PERF.md §6, PR 34). Softmax in fp32, the weighted sum in ``q``'s type, as
+    there."""
+    b, s, n, d = q.shape
+    t, kv = k.shape[1], k.shape[2]
+    if t < LONG_VIEW or t % KEY_BLOCK:
+        return dot_product_attention(q, k, v, mask=mask)
+    group = n // kv
+    qg = (q * (1.0 / jnp.sqrt(d).astype(q.dtype))).reshape(b, s, kv, group, d)
+    positions = length + jnp.arange(s)
+
+    def fold(i, carry):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice_in_dim(k, i * KEY_BLOCK, KEY_BLOCK, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(v, i * KEY_BLOCK, KEY_BLOCK, axis=1)
+        logits = jnp.einsum("bskgd,btkd->bkgst", qg, kb).astype(jnp.float32)
+        seen = (i * KEY_BLOCK + jnp.arange(KEY_BLOCK))[None, :] <= positions[:, None]
+        logits = jnp.where(seen[None, None, None], logits, -1e30)
+        m_new = jnp.maximum(m, logits.max(-1))
+        p = jnp.exp(logits - m_new[..., None])
+        keep = jnp.exp(m - m_new)
+        weighted = jnp.einsum("bkgst,btkd->bkgsd", p.astype(q.dtype), vb).astype(jnp.float32)
+        return m_new, l * keep + p.sum(-1), acc * keep[..., None] + weighted
+
+    # every query sees key 0, so the first block leaves every row a finite maximum
+    init = (jnp.full((b, kv, group, s), -5e29, jnp.float32), jnp.zeros((b, kv, group, s), jnp.float32), jnp.zeros((b, kv, group, s, d), jnp.float32))
+    blocks = (length + s + KEY_BLOCK - 1) // KEY_BLOCK
+    _, l, acc = jax.lax.fori_loop(0, blocks, fold, init)
+    out = (acc / l[..., None]).astype(q.dtype)  # [B, K, G, S, D]
+    return jnp.moveaxis(out, 3, 1).reshape(b, s, n, d)

@@ -16,7 +16,7 @@ from typing import Optional
 class TransformerConfig:
     """One config for both decoder (llama-style) and encoder (bert-style) stacks."""
 
-    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5" | "exaone_moe"
+    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5" | "exaone_moe" | "mellum"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -37,7 +37,8 @@ class TransformerConfig:
     num_experts: int = 1
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
-    # per-layer pattern (arch "exaone_moe", models/exaone_moe.py): the kind of
+    # per-layer pattern (archs "exaone_moe" and "mellum", models/exaone_moe.py,
+    # models/mellum.py): the kind of
     # attention ("sliding_attention" | "full_attention") and of MLP ("dense" |
     # "sparse") of every layer, the window of the sliding layers, the routed
     # experts' width beside the dense layers' ``intermediate_size``, and the
@@ -51,6 +52,14 @@ class TransformerConfig:
     num_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     experts_held: Optional[tuple] = None
+    # rotary parameters by layer kind (arch "mellum"): ((kind, ((key, value),
+    # ...)), ...) with the source's ``rope_parameters`` keys (``rope_type``
+    # "default" | "yarn", ``rope_theta``, and YaRN's ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``attention_factor``); a kind not named takes plain rotary at
+    # ``rope_theta``. Pairs, not a dict: a config is hashed (``rope_by_kind``
+    # builds it from the source's nested dict; ``rope_of`` reads it)
+    rope_parameters: tuple = ()
     # encoder-decoder (t5) extras: relative-position bias bucketing and the
     # decoder's BOS (t5 starts generation from the pad token)
     rel_buckets: int = 32
@@ -65,8 +74,18 @@ class TransformerConfig:
     def dim_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def rope_of(self, kind: str) -> dict:
+        """The rotary parameters of a layer kind, as the source's keys."""
+        return dict(dict(self.rope_parameters).get(kind, (("rope_type", "default"), ("rope_theta", self.rope_theta))))
+
     def replace(self, **kwargs) -> "TransformerConfig":
         return replace(self, **kwargs)
+
+
+def rope_by_kind(rope_parameters: dict) -> tuple:
+    """``TransformerConfig.rope_parameters`` from a source config's
+    ``rope_parameters`` nested by layer kind."""
+    return tuple((kind, tuple(sorted(of_kind.items()))) for kind, of_kind in sorted(rope_parameters.items()))
 
 
 _REGISTRY: dict[str, TransformerConfig] = {

@@ -37,7 +37,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .attention import apply_rotary, dense_init, dot_product_attention, rotary_embedding
+from .attention import apply_rotary, cached_causal_attention, dense_init, rotary_embedding
 from .config import TransformerConfig, get_config
 from .llama import rms_norm
 from .moe import dropless_experts
@@ -92,12 +92,18 @@ def window_attention(q, k, v, ring_k, ring_v, length, window: int):
 
 
 class ExaoneMoe:
-    """(init, decode protocol) of an EXAONE-MoE-style causal LM."""
+    """(init, decode protocol) of an EXAONE-MoE-style causal LM. The two kinds
+    of cache and the walk over the unrolled layers are any such stack's; what
+    is this family's own is its weights (``_init``), its rotary tables
+    (``_rotary_tables``) and its layer's equations (``_block``), which
+    ``models/mellum.py`` replaces."""
+
+    arch = "exaone_moe"
 
     def __init__(self, config: TransformerConfig | str):
         cfg = self.config = get_config(config) if isinstance(config, str) else config
-        if cfg.arch != "exaone_moe":
-            raise ValueError(f"ExaoneMoe needs arch 'exaone_moe', got {cfg.arch!r}")
+        if cfg.arch != self.arch:
+            raise ValueError(f"{type(self).__name__} needs arch {self.arch!r}, got {cfg.arch!r}")
         for name, kinds, allowed in (
             ("layer_types", cfg.layer_types, (SLIDING, FULL)), ("mlp_layer_types", cfg.mlp_layer_types, (DENSE, SPARSE)),
         ):
@@ -199,8 +205,38 @@ class ExaoneMoe:
             x.reshape(b * s, h), lp["router"], lp["router_bias"], lp["moe_gate"], lp["moe_up"], lp["moe_down"],
             top_k=cfg.moe_top_k, scaling=cfg.routed_scaling_factor, first=self.first_expert,
         )
-        counted = jnp.where((jnp.arange(s) < real)[None, :, None], held.reshape(b, s, -1), 0)
+        counted = self._of_real_tokens(held, b, s, real)
         return shared + routed.reshape(b, s, h), counted.sum((0, 1))
+
+    @staticmethod
+    def _of_real_tokens(held, b: int, s: int, real):
+        """``held`` [b*s, held experts] as [b, s, held experts], nought on a
+        bucket's padding (all but the ``real`` leading tokens of each row)."""
+        return jnp.where((jnp.arange(s) < real)[None, :, None], held.reshape(b, s, -1), 0)
+
+    def _rotary_tables(self, positions):
+        """What ``_block`` rotates q and k with: one float32 (cos, sin) for the
+        stack (the sliding layers take it, the full layers none)."""
+        return rotary_embedding(positions[None, :], self.config.dim_per_head, self.config.rope_theta, dtype=jnp.float32)
+
+    def _block(self, i: int, lp: dict, h: jax.Array, rope, attend, real):
+        """Layer ``i``'s equations over the residual stream ``h`` [B, S, H]:
+        (``h`` after the layer, what ``_mlp`` counted). ``attend(i, q, k, v)``
+        attends the layer's cache and keeps the span's k and v."""
+        cfg = self.config
+        b, s, _ = h.shape
+        nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
+        q = rms_norm((h @ lp["wq"]).reshape(b, s, nh, d), lp["q_norm"], cfg.norm_eps)
+        k = rms_norm((h @ lp["wk"]).reshape(b, s, nkv, d), lp["k_norm"], cfg.norm_eps)
+        v = (h @ lp["wv"]).reshape(b, s, nkv, d)
+        if i in self.window_layers:
+            cos, sin = rope
+            q = apply_rotary(q.astype(jnp.float32), cos, sin).astype(h.dtype)
+            k = apply_rotary(k.astype(jnp.float32), cos, sin).astype(h.dtype)
+        attn = attend(i, q, k, v)
+        h = h + rms_norm(attn.reshape(b, s, nh * d) @ lp["wo"], lp["attn_norm"], cfg.norm_eps)
+        out, chosen = self._mlp(lp, h, real)
+        return h + rms_norm(out, lp["mlp_norm"], cfg.norm_eps), chosen
 
     def forward_with_cache(self, params: dict, input_ids: jax.Array, cache: dict):
         """The decode protocol: ``input_ids`` [B, S] (a prefill block or one
@@ -212,25 +248,20 @@ class ExaoneMoe:
         counts the real fed tokens each held expert was chosen by, a layer."""
         cfg = self.config
         b, s = input_ids.shape
-        nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
         length = cache["length"]
         real = cache.get("real", s)
         paged = "attend" in cache
         h = jnp.take(params["embed_tokens"], input_ids, axis=0)
         positions = length + jnp.arange(s)
-        cos, sin = rotary_embedding(positions[None, :], d, cfg.rope_theta, dtype=jnp.float32)
+        rope = self._rotary_tables(positions)
         # full layers without a hook: causal over the cache, as models/generation.py
         mask = None if paged else (jnp.arange(cache["k"].shape[2])[None, :] <= positions[:, None])[None, None]
 
         full_k, full_v, ring_k, ring_v, held = [], [], [], [], []
-        for i, lp in enumerate(params["layers"]):
-            q = rms_norm((h @ lp["wq"]).reshape(b, s, nh, d), lp["q_norm"], cfg.norm_eps)
-            k = rms_norm((h @ lp["wk"]).reshape(b, s, nkv, d), lp["k_norm"], cfg.norm_eps)
-            v = (h @ lp["wv"]).reshape(b, s, nkv, d)
+
+        def attend(i, q, k, v):
             if i in self.window_layers:
                 w = self.window_layers.index(i)
-                q = apply_rotary(q.astype(jnp.float32), cos, sin).astype(h.dtype)
-                k = apply_rotary(k.astype(jnp.float32), cos, sin).astype(h.dtype)
                 with jax.named_scope("attn.window"):
                     attn = window_attention(q, k, v, cache["wk"][w], cache["wv"][w], length, cfg.sliding_window)
                 if paged:
@@ -238,22 +269,23 @@ class ExaoneMoe:
                 else:
                     ring_k.append(ring_after(cache["wk"][w], k, length, real))
                     ring_v.append(ring_after(cache["wv"][w], v, length, real))
-            else:
-                f = self.full_layers.index(i)
-                with jax.named_scope("attn.full"):
-                    if paged:
-                        attn = cache["attend"](q, k, v, {**cache, "layer": jnp.int32(f)})
-                        full_k.append(k), full_v.append(v)
-                    else:
-                        kc = jax.lax.dynamic_update_slice(cache["k"][f], k.astype(cache["k"].dtype), (0, length, 0, 0))
-                        vc = jax.lax.dynamic_update_slice(cache["v"][f], v.astype(cache["v"].dtype), (0, length, 0, 0))
-                        attn = dot_product_attention(q, kc.astype(q.dtype), vc.astype(q.dtype), mask=mask)
-                        full_k.append(kc), full_v.append(vc)
-            h = h + rms_norm(attn.reshape(b, s, nh * d) @ lp["wo"], lp["attn_norm"], cfg.norm_eps)
-            out, chosen = self._mlp(lp, h, real)
+                return attn
+            f = self.full_layers.index(i)
+            with jax.named_scope("attn.full"):
+                if paged:
+                    attn = cache["attend"](q, k, v, {**cache, "layer": jnp.int32(f)})
+                    full_k.append(k), full_v.append(v)
+                else:
+                    kc = jax.lax.dynamic_update_slice(cache["k"][f], k.astype(cache["k"].dtype), (0, length, 0, 0))
+                    vc = jax.lax.dynamic_update_slice(cache["v"][f], v.astype(cache["v"].dtype), (0, length, 0, 0))
+                    attn = cached_causal_attention(q, kc.astype(q.dtype), vc.astype(q.dtype), length, mask)
+                    full_k.append(kc), full_v.append(vc)
+            return attn
+
+        for i, lp in enumerate(params["layers"]):
+            h, chosen = self._block(i, lp, h, rope, attend, real)
             if chosen is not None:
                 held.append(chosen)
-            h = h + rms_norm(out, lp["mlp_norm"], cfg.norm_eps)
 
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         head = params["embed_tokens"].T if cfg.tie_embeddings else params["lm_head"]
@@ -274,16 +306,17 @@ class ExaoneMoe:
 
     def forward_window_with_cache(self, params, input_ids, cache):
         raise NotImplementedError(
-            "ExaoneMoe.forward_window_with_cache: speculative verify scores a candidate window against the paged "
+            f"{type(self).__name__}.forward_window_with_cache: speculative verify scores a candidate window against the paged "
             "cache, and a rejected window would have to be rolled back out of the sliding layers' rings"
         )
 
     def apply(self, params, input_ids, *args, **kwargs):
         raise NotImplementedError(
-            "ExaoneMoe.apply: the training forward pass (no cache, attention masks, the routers' balance loss) is "
+            f"{type(self).__name__}.apply: the training forward pass (no cache, attention masks, the routers' balance loss) is "
             "not written for this family; serve it through forward_with_cache"
         )
 
     @staticmethod
     def loss_fn(model):
-        raise NotImplementedError("ExaoneMoe.loss_fn: training is not written for this family (see ExaoneMoe.apply)")
+        name = type(model).__name__
+        raise NotImplementedError(f"{name}.loss_fn: training is not written for this family (see {name}.apply)")

@@ -22,25 +22,29 @@ Design notes (MXU/ICI-first):
 
 Two expert paths live here. ``routed_mlp`` (above: capacity dispatch, softmax
 scores, ungated GELU experts; callers ``MoEBlock`` and ``models/llama.py``'s
-MoE decoder, the training path). ``dropless_experts`` (below: sigmoid scores,
-top-k of all the router's experts, gated SiLU experts as one grouped matrix
-product over the assignments sorted by expert, no token ever dropped, and a
-*share*: the layer is told which experts it holds as (first, count), routes
-over all of them and computes the part of the result its own give; caller
-``models/exaone_moe.py``, the serving path, a prefill span's many tokens and a
-decode step's few alike). On one chip the share runs without its exchange:
+MoE decoder, the training path). ``dropless_experts`` (below: the top-k of all
+the router's experts by a named scoring — ``sigmoid_topk``: sigmoid scores with
+a selection bias and a scale, ``models/exaone_moe.py``; ``softmax_topk``:
+softmax probabilities renormalised over the chosen, ``models/mellum.py`` —
+gated SiLU experts as one grouped matrix product over the assignments sorted
+by expert, no token ever dropped, and a *share*: the layer is told which
+experts it holds as (first, count), routes over all of them and computes the
+part of the result its own give; the serving path, a prefill span's many
+tokens and a decode step's few alike). On one chip the share runs without its exchange:
 what the absent experts would add is left out.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.runtime import interpret_mode
 from ..utils.constants import MESH_AXIS_EXPERT
 from .attention import dense_init
 
@@ -213,6 +217,24 @@ def sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, s
     return chosen.astype(jnp.int32), weights
 
 
+def softmax_topk(x: jax.Array, router: jax.Array, top_k: int, scaling: float):
+    """Route ``x`` [T, H] by softmax probabilities over ALL of the router's
+    experts, in float32: the chosen set the ``top_k`` largest, weights the
+    chosen probabilities renormalised to one (the source's ``norm_topk_prob``)
+    and scaled; no bias. Returns as :func:`sigmoid_topk`."""
+    with jax.named_scope("moe.route"):
+        probs = jax.nn.softmax(x.astype(jnp.float32) @ router.astype(jnp.float32), axis=-1)
+        picked, chosen = jax.lax.top_k(probs, top_k)
+        weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weights
+
+
+# how a layer chooses its experts, by name: (x, *the router's arrays, top_k,
+# scaling) -> (experts [T, k], weights [T, k]); looked up as a call is traced,
+# so a test may plant a fault under a name
+SCORINGS = ("sigmoid_topk", "softmax_topk")
+
+
 # rows a grouped product takes at a time: the sorted assignments go through the
 # held experts in chunks of this many, and only the chunks that hold a held
 # expert's assignment run. On a v5e a chunk's products are near the ridge (an
@@ -223,14 +245,58 @@ def sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, s
 CHUNK_ROWS = 256
 
 
-def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int):
+# a grouped product whose tile is one expert's WHOLE matrix may hold this much
+# of VMEM: two buffers of the matrix, of a tile of rows and of its result
+# (under the 16 MB a kernel may use on a v5e)
+_WHOLE_MATRIX_BYTES = 12 << 20
+_ROW_TILE = 128
+
+
+def whole_matrix_tiling(rows: int, k: int, n: int, dtype) -> Optional[tuple]:
+    """(rows, k, n) tile of a grouped product that takes one expert's whole
+    ``[k, n]`` matrix at a time, where that fits VMEM twice over and the shapes
+    tile; None where it does not (K-EXAONE's 25 MB matrices)."""
+    size = jnp.dtype(dtype).itemsize
+    held = 2 * size * (k * n + _ROW_TILE * k + _ROW_TILE * n) + 4 * _ROW_TILE * n
+    if rows % _ROW_TILE or k % 128 or n % 128 or held > _WHOLE_MATRIX_BYTES:
+        return None
+    return (_ROW_TILE, k, n)
+
+
+def grouped_dot(rows: jax.Array, w: jax.Array, sizes: jax.Array, kernel: Optional[bool] = None) -> jax.Array:
+    """``rows`` [M, K], sorted by group, times each group's matrix of ``w``
+    [G, K, N] -> [M, N]; rows past the groups' sizes hold whatever. XLA's own
+    grouped product (``jax.lax.ragged_dot``) walks an expert's matrix in small
+    tiles: at mellum2's widths (64 experts of 2304 x 896) it takes 2.2-2.6 ms
+    for a decode step's 512 rows where reading the 264 MB takes 0.32 (my chip
+    run, PR 34), the time going to its grid steps. Where an expert's whole
+    matrix fits VMEM (:func:`whole_matrix_tiling`) the product runs as the
+    Pallas grouped matmul with that one tile a visited (tile of rows, expert)
+    pair, 0.42 ms there; K-EXAONE's matrices do not fit, and keep XLA's.
+    ``kernel``: None = on the TPU alone (off it XLA's own, as every test ran)."""
+    tiling = whole_matrix_tiling(rows.shape[0], w.shape[1], w.shape[2], w.dtype)
+    if kernel is None:
+        kernel = not interpret_mode()
+    if tiling is None or not kernel:
+        return jax.lax.ragged_dot(rows, w, sizes)
+    # the kernel itself, not its jitted wrapper: a custom call is named in the
+    # device trace by the innermost scope around it, here "moe.experts"
+    gmm = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm").gmm.__wrapped__
+    return gmm(rows, w, sizes.astype(jnp.int32), rows.dtype, tiling, interpret=interpret_mode())
+
+
+def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int, whole: bool = False):
     """The held experts' part of the routed result. ``chosen``/``weights``
     [T, k] over all experts; ``w_*`` hold experts ``first .. first + count``.
     Every assignment on a held expert is computed, whatever the imbalance: the
     T*k assignments are sorted by held expert (the others behind them), and
     the gated SiLU MLP runs over them, ``CHUNK_ROWS`` at a time, as three
     grouped matrix products whose group sizes are the held experts' loads
-    within the chunk; as many chunks run as the held assignments fill. Returns
+    within the chunk; as many chunks run as the held assignments fill. With
+    the ``whole`` layer held nothing sorts behind and every chunk would run,
+    each launch walking all the experts' weights (64 x 12 MB at mellum2's
+    widths: 32 launches a 1024-token span read what one reads; PERF.md §6,
+    PR 34): the rows then go through in ONE chunk. Returns
     (y [T, H], held [T, count] int32: 1 where the token chose that held expert)."""
     t, k = chosen.shape
     count = w_gate.shape[0]
@@ -242,7 +308,7 @@ def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int):
     loads = held.sum(0)
     ends = jnp.cumsum(loads)
     starts, total = ends - loads, ends[-1]
-    chunk = min(t * k, CHUNK_ROWS)
+    chunk = t * k if whole else min(t * k, CHUNK_ROWS)
     pad = -(t * k) % chunk
     token = jnp.pad(order // k, (0, pad))
     weight = jnp.pad(jnp.where(on_held, weights, 0.0).reshape(-1)[order], (0, pad))
@@ -253,8 +319,8 @@ def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int):
         sizes = jnp.clip(ends - lo, 0, chunk) - jnp.clip(starts - lo, 0, chunk)
         with jax.named_scope("moe.experts"):
             rows = jnp.take(x, rows_of, axis=0)
-            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) * jax.lax.ragged_dot(rows, w_up, sizes)
-            out = jax.lax.ragged_dot(hidden.astype(x.dtype), w_down, sizes)
+            hidden = jax.nn.silu(grouped_dot(rows, w_gate, sizes)) * grouped_dot(rows, w_up, sizes)
+            out = grouped_dot(hidden.astype(x.dtype), w_down, sizes)
         # rows past the held assignments belong to no group: whatever the
         # grouped product left there must not reach a token, not even times zero
         live = lo + jnp.arange(chunk) < total
@@ -266,24 +332,26 @@ def _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _dropless(top_k: int, scaling: float, first: int):
-    """``dropless_experts`` for one (top_k, scaling, first expert), as a
-    function whose batching rule folds a mapped axis into the tokens: the
+def _dropless(scoring: str, top_k: int, scaling: float, first: int):
+    """``dropless_experts`` for one (scoring, top_k, scaling, first expert),
+    as a function whose batching rule folds a mapped axis into the tokens: the
     serving engine maps its decode step over slots (one token a slot), and
     under that ``vmap`` the experts must see the step's tokens of all slots as
-    ONE batch, each held expert's weights read once, not gathered a slot."""
+    ONE batch, each held expert's weights read once, not gathered a slot.
+    ``routing`` is the scoring's arrays: (router, bias) or (router,)."""
 
     @jax.custom_batching.custom_vmap
-    def experts(x, router, bias, w_gate, w_up, w_down):
-        chosen, weights = sigmoid_topk(x, router, bias, top_k, scaling)
-        return _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first)
+    def experts(x, routing, w_gate, w_up, w_down):
+        chosen, weights = globals()[scoring](x, *routing, top_k, scaling)
+        whole = w_gate.shape[0] == routing[0].shape[-1]
+        return _grouped_experts(x, chosen, weights, w_gate, w_up, w_down, first, whole)
 
     @experts.def_vmap
-    def experts_over_slots(axis_size, in_batched, x, router, bias, w_gate, w_up, w_down):
-        if any(in_batched[1:]):
+    def experts_over_slots(axis_size, in_batched, x, routing, w_gate, w_up, w_down):
+        if any(jax.tree.leaves(in_batched[1:])):
             raise NotImplementedError("dropless experts batch tokens over ONE set of weights")
         t = x.shape[1]
-        y, held = experts(x.reshape(axis_size * t, x.shape[-1]), router, bias, w_gate, w_up, w_down)
+        y, held = experts(x.reshape(axis_size * t, x.shape[-1]), routing, w_gate, w_up, w_down)
         return (y.reshape(axis_size, t, -1), held.reshape(axis_size, t, -1)), (True, True)
 
     return experts
@@ -292,23 +360,29 @@ def _dropless(top_k: int, scaling: float, first: int):
 def dropless_experts(
     x: jax.Array,  # [T, H]
     router: jax.Array,  # [H, E]: every expert of the layer, held here or not
-    bias: jax.Array,  # [E]: per-expert selection bias
+    bias: Optional[jax.Array],  # [E]: per-expert selection bias ("sigmoid_topk"; None for a scoring without one)
     w_gate: jax.Array,  # [count, H, F]: the held experts
     w_up: jax.Array,  # [count, H, F]
     w_down: jax.Array,  # [count, F, H]
     top_k: int,
     scaling: float = 1.0,
     first: int = 0,
+    scoring: str = "sigmoid_topk",
 ) -> tuple[jax.Array, jax.Array]:
     """Dropless routed experts over a held share: routes over all ``E``
-    experts and returns what the ``count`` held ones (``first .. first +
-    count``) add, ``sum over e in chosen and held of weight_e * E_e(x)``, and
-    ``held`` [T, count] int32, 1 where a token chose that held expert (the
-    loads and the counters are sums of it)."""
+    experts by ``scoring`` (one of ``SCORINGS``) and returns what the
+    ``count`` held ones (``first .. first + count``) add, ``sum over e in
+    chosen and held of weight_e * E_e(x)``, and ``held`` [T, count] int32, 1
+    where a token chose that held expert (the loads and the counters are sums
+    of it). With every expert held (``first`` 0, ``count`` E) every assignment
+    is held."""
+    if scoring not in SCORINGS:
+        raise ValueError(f"scoring {scoring!r} is not one of {SCORINGS}")
     if top_k > router.shape[-1]:
         raise ValueError(f"top_k={top_k} > num_experts={router.shape[-1]}")
     if first < 0 or first + w_gate.shape[0] > router.shape[-1]:
         raise ValueError(
             f"held experts {first}..{first + w_gate.shape[0]} lie outside the router's {router.shape[-1]}"
         )
-    return _dropless(int(top_k), float(scaling), int(first))(x, router, bias, w_gate, w_up, w_down)
+    routing = (router,) if bias is None else (router, bias)
+    return _dropless(scoring, int(top_k), float(scaling), int(first))(x, routing, w_gate, w_up, w_down)

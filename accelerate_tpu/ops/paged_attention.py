@@ -71,6 +71,23 @@ op is a ``[.., KV, D]`` elementwise product, a minor-axis reduce, or a
 major-axis reduce, which is what Mosaic lowers for this layout without a
 relayout. All arithmetic is fp32.
 
+**Fewer KV heads than a tile's 8 sublanes** (4, 2 or 1; mellum2 has 4): the
+pool stays ``[L, P, page_size, KV, D]`` and no head is padded in HBM. The
+launch views a page as ``[page_size / pack, pack * KV, D]`` with ``pack = 8
+// KV``: the same bytes in the same order (XLA makes the reshape a bitcast of
+the pool; ``tests/test_mosaic_compile.py``), so a tile row holds ``pack``
+consecutive tokens and sublane ``r`` is (token ``r // KV`` of the row, KV
+head ``r % KV``). Inside the kernel a sublane is a "head": the query of a KV
+head is repeated on its ``pack`` sublanes, each keeps the online softmax over
+its own tokens (every ``pack``-th of the page) through the unchanged fold,
+positions are masked by ``row * pack + r // KV < length``, the window's own
+keys (padded to whole rows; they are few) count on the first token's sublanes
+only, and at the end the ``pack`` partial softmaxes of a head are merged by
+``log2(pack)`` sublane rotations. The DMAs, the block pipeline and the
+invariants below are untouched: a page is the same 16 KB x KV of HBM either
+way. With ``KV % 8 == 0`` nothing of this is traced and the kernel is the
+one it was (``tests/test_paged_attention.py`` holds its jaxpr to a hash).
+
 The engine calls the op per slot under its slot ``vmap``; a custom batching
 rule turns that into ONE slot-batched ``pallas_call`` per layer per step
 with the slot axis as the grid.
@@ -107,21 +124,49 @@ from .runtime import interpret_mode
 def paged_kernel_fallback_reason(
     page_shape: tuple, num_heads: int, kv_heads: int
 ) -> Optional[str]:
-    """Why the paged kernel cannot serve this pool geometry (None = it can).
-    Interpret mode runs any shape; Mosaic DMAs whole ``[page_size, KV, D]``
-    pages, whose ``(KV, D)`` face must fill ``(8, 128)`` tiles. The engine
-    records the reason in its ``{"kind":"kernels"}`` telemetry so a fleet's
-    kernel coverage is a query away."""
-    d = int(page_shape[-1])
+    """Why the paged kernel cannot serve this pool geometry ``[P, page_size,
+    KV, D]`` (None = it can). Interpret mode runs any shape; Mosaic DMAs whole
+    ``[page_size, KV, D]`` pages, whose ``(KV, D)`` face must fill ``(8,
+    128)`` tiles, or divide one and share it between consecutive tokens of
+    the page (:func:`_tokens_per_tile`). The engine records the reason in its
+    ``{"kind":"kernels"}`` telemetry so a fleet's kernel coverage is a query
+    away."""
+    d, page_size = int(page_shape[-1]), int(page_shape[-3])
     if num_heads % kv_heads:
         return f"num_heads {num_heads} not a multiple of kv_heads {kv_heads}"
     if interpret_mode():
         return None
     if d % 128:
         return f"head dim {d} is not a multiple of 128 (Mosaic lane tiling)"
-    if kv_heads % 8:
-        return f"kv_heads {kv_heads} is not a multiple of 8 (Mosaic sublane tiling)"
+    if kv_heads % 8 and _tokens_per_tile(kv_heads, page_size) == 1:
+        return (
+            f"kv_heads {kv_heads} neither fills (8, 128) sublane tiles nor divides one with "
+            f"page_size {page_size} a multiple of the tokens a tile then holds"
+        )
     return None
+
+
+def _tokens_per_tile(kv_heads: int, page_size: int) -> int:
+    """How many consecutive tokens of a page share one 8-sublane tile: 1
+    where the KV heads fill tiles by themselves (a multiple of 8, or interpret
+    mode's free shapes), else ``8 // kv_heads`` (4 heads: a token pair)."""
+    if kv_heads % 8 and 8 % kv_heads == 0 and page_size % (8 // kv_heads) == 0:
+        return 8 // kv_heads
+    return 1
+
+
+def pool_tile_view(pool: jax.Array) -> jax.Array:
+    """A pool ``[.., page_size, KV, D]`` whose KV heads share a sublane tile,
+    as ``[.., page_size / pack, pack * KV, D]``: the same bytes in the same
+    order (XLA makes the reshape a bitcast), with an ``(8, D)`` face that
+    fills tiles. What attends, gathers or scatters whole pages does it through
+    this view: on the ``(KV, D)`` face of 4 heads XLA relays the WHOLE pool
+    out heads-major around a page gather or scatter and back (five copies of
+    1.7 GB a prefill program of mellum2's cell, PERF.md §6, PR 34). The pool
+    itself where the heads fill tiles."""
+    page_size, kv, d = pool.shape[-3:]
+    pack = _tokens_per_tile(kv, page_size)
+    return pool if pack == 1 else pool.reshape(*pool.shape[:-3], page_size // pack, kv * pack, d)
 
 
 def _fold(carry, q, k, v, valid):
@@ -177,10 +222,14 @@ def _paged_kernel(
     block_pages: int,
     window: int,
     group: int,
+    pack: int,
 ):
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
     layer = layer_ref[0]
+    # with ``pack`` > 1 everything here is in the packed view: ``page_size``
+    # counts a page's tile rows of ``pack`` tokens each, and a "head" is a
+    # sublane (token % pack, kv head), with a softmax of its own until the end
     kv, d = q_ref.shape[-2:]
     f32 = jnp.float32
     queries = [q_ref[0, r].astype(f32) for r in range(window * group)]  # [KV, D] each
@@ -194,7 +243,7 @@ def _paged_kernel(
         return jax.lax.div(n + jnp.int32(m - 1), jnp.int32(m))
 
     def pages_of(s):
-        return ceil_div(lengths_ref[s], page_size)
+        return ceil_div(lengths_ref[s], page_size * pack)
 
     def copies(s, b, buf, act):
         """``act`` ("start" | "wait") on the copies of block ``b`` of slot
@@ -226,6 +275,9 @@ def _paged_kernel(
     nblocks = ceil_div(npages, block_pages)
     first = pipe[0]
     pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 0)
+    if pack > 1:  # sublane (token % pack, kv head) of tile row (token // pack)
+        sublane = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 1)
+        pos_in_page = pos_in_page * pack + sublane // (kv // pack)
 
     # the launch's first non-empty slot has no predecessor to have started
     # its first block
@@ -267,7 +319,7 @@ def _paged_kernel(
             v = v_buf[buf, p].astype(f32)
             # mask the partial last page: positions >= length hold stale pool
             # data (or the unwritten tail) and must underflow exp to exactly 0
-            valid = (b * block_pages + p) * page_size + pos_in_page < length
+            valid = (b * block_pages + p) * (page_size * pack) + pos_in_page < length
             return tuple(_fold(c, q, k, v, valid) for c, q in zip(carry, queries))
 
         return jax.lax.fori_loop(0, jnp.minimum(npages - b * block_pages, block_pages), page, carry)
@@ -281,8 +333,19 @@ def _paged_kernel(
     kn = kn_ref[0].astype(f32)
     vn = vn_ref[0].astype(f32)
     key_pos = jax.lax.broadcasted_iota(jnp.int32, (window, kv, 1), 0)
+    if pack > 1:  # the window's keys lie on the first token's sublanes; the others hold padding
+        key_pos = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (window, kv, 1), 1) < kv // pack, key_pos, window)
     for r, (c, q) in enumerate(zip(carry, queries)):
-        _, l, acc = _fold(c, q, kn, vn, key_pos <= r // group)
+        m, l, acc = _fold(c, q, kn, vn, key_pos <= r // group)
+        # one kv head's ``pack`` sublanes hold softmaxes over disjoint keys of
+        # the same query: merge them (every sublane ends with the whole)
+        shift = kv // 2
+        while shift >= kv // pack:
+            m_o, l_o, acc_o = (pltpu.roll(x, shift, 0) for x in (m, l, acc))
+            m_all = jnp.maximum(m, m_o)
+            mine, theirs = jnp.exp(m - m_all), jnp.exp(m_o - m_all)
+            m, l, acc = m_all, l * mine + l_o * theirs, acc * mine + acc_o * theirs
+            shift //= 2
         o_ref[0, r] = (acc / l).astype(o_ref.dtype)
 
 
@@ -300,13 +363,25 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
     # head h = g*group + gi reads kv head g (the zoo's GQA convention): lay
     # the queries out as rows of one-query-per-kv-head, row = wi*group + gi
     q_rows = q.reshape(s, w, kv, group, d).transpose(0, 1, 3, 2, 4).reshape(s, rows, kv, d)
+    # fewer KV heads than a tile's 8 sublanes: view a page ``[ps, KV, D]`` as
+    # ``[ps/pack, pack*KV, D]`` (the same bytes in the same order; no head is
+    # padded in HBM), so a tile row holds ``pack`` consecutive tokens. The
+    # query of a kv head is repeated on each of its sublanes, and the window's
+    # own keys, which are few, are padded to whole rows.
+    pack = _tokens_per_tile(kv, ps)
+    heads = kv
+    if pack > 1:
+        kv, ps = kv * pack, ps // pack
+        pool_k, pool_v = pool_tile_view(pool_k), pool_tile_view(pool_v)
+        q_rows = jnp.tile(q_rows, (1, 1, pack, 1))
+        k_new, v_new = (jnp.pad(x, ((0, 0), (0, 0), (0, kv - heads), (0, 0))) for x in (k_new, v_new))
 
     def per_slot(n):
         return pl.BlockSpec((1, n, kv, d), lambda i, *_: (i, 0, 0, 0), memory_space=pltpu.VMEM)
 
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, page_size=ps, block_pages=block_pages, window=w, group=group
+            _paged_kernel, page_size=ps, block_pages=block_pages, window=w, group=group, pack=pack
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -335,7 +410,7 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
         tables.astype(jnp.int32), lengths.astype(jnp.int32), layer.astype(jnp.int32).reshape(1),
         q_rows, k_new, v_new, pool_k, pool_v,
     )
-    return out.reshape(s, w, group, kv, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
+    return out[:, :, :heads].reshape(s, w, group, heads, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
 
 
 @jax.custom_batching.custom_vmap

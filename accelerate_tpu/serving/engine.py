@@ -543,13 +543,17 @@ class ServingEngine:
         Static on purpose: the paged programs close over it, and those live
         in the model-lifetime jit cache — a bound method would pin the whole
         engine (KV pool included) long after the engine is discarded."""
+        from ..ops.paged_attention import pool_tile_view
+
         if layer is not None:
             row = layer * pool_k.shape[1] + row
             pool_k = pool_k.reshape(1, -1, *pool_k.shape[2:])
             pool_v = pool_v.reshape(1, -1, *pool_v.shape[2:])
-        taken_k = jnp.take(pool_k, row, axis=1)  # [L, pps, ps, ...]
-        taken_v = jnp.take(pool_v, row, axis=1)
-        shape = (taken_k.shape[0], 1, taken_k.shape[1] * taken_k.shape[2]) + taken_k.shape[3:]
+        # whole pages are taken through the view whose faces fill tiles (the
+        # pool itself at 8 KV heads): the same bytes, and no relayout of the pool
+        taken_k = jnp.take(pool_tile_view(pool_k), row, axis=1)  # [L, pps, ps, ...]
+        taken_v = jnp.take(pool_tile_view(pool_v), row, axis=1)
+        shape = (taken_k.shape[0], 1, row.shape[0] * pool_k.shape[2]) + pool_k.shape[3:]
         return {"k": taken_k.reshape(shape), "v": taken_v.reshape(shape), "length": length}
 
     def _paged_decode_program(self):
@@ -651,11 +655,14 @@ class ServingEngine:
                 # inactive at length 0, and its ring holds the chunks' live
                 # K/V (scrubbed of poison where a lane is quarantined)
                 (wk, wv, counts), (rk, rv, held) = extras, of_rings
-                lanes, entry = jnp.arange(wk.shape[1]), lengths % wk.shape[3]
-                rk = jnp.where(lane, rk.astype(wk.dtype), wk[:, lanes, :, entry])  # the indexed axes lead: [S, Lw, KV, D]
-                rv = jnp.where(lane, rv.astype(wv.dtype), wv[:, lanes, :, entry])
-                wk = wk.at[:, lanes, :, entry].set(rk)
-                wv = wv.at[:, lanes, :, entry].set(rv)
+                # one entry of each active lane's ring, written as ONE select over the
+                # rings where they lie: a scatter (or an update slice) over (lane, entry)
+                # has XLA relay the stacked rings out entries-major and back, four copies
+                # of the whole of them a step (PERF.md §6, PR 34)
+                hit = (jnp.arange(wk.shape[3])[None, :] == (lengths % wk.shape[3])[:, None]) & active[:, None]  # [S, R]
+                hit = hit[None, :, None, :, None]
+                wk = jnp.where(hit, jnp.moveaxis(rk, 0, 1)[:, :, :, None, :].astype(wk.dtype), wk)  # [S, Lw, KV, D] -> [Lw, S, KV, 1, D]
+                wv = jnp.where(hit, jnp.moveaxis(rv, 0, 1)[:, :, :, None, :].astype(wv.dtype), wv)
                 held = jnp.sum(jnp.where(active[:, None, None], held, 0), axis=0)
                 fetched = jnp.concatenate(
                     [jnp.where(active, nxt, jnp.int32(0)), held.reshape(-1).astype(jnp.int32), counts]
@@ -798,6 +805,8 @@ class ServingEngine:
         program hit: the next decode step's fetch brings them home. Where it
         is empty, ``real`` and ``slot`` are not read, and ``jit`` leaves them
         out of the program."""
+        from ..ops.paged_attention import pool_tile_view
+
         fwc = self._fwc
         ps = self.cache.page_size
         n_pages = span // ps
@@ -816,10 +825,14 @@ class ServingEngine:
                 _, nc = fwc(params, ids, cache)
                 new_k = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], start, span, axis=1)
                 new_v = jax.lax.dynamic_slice_in_dim(nc["v"][:, 0], start, span, axis=1)
-                shape = (new_k.shape[0], n_pages, ps) + new_k.shape[2:]
                 wids = jax.lax.dynamic_slice_in_dim(row, start // ps, n_pages)
-                pk = pk.at[:, wids].set(new_k.reshape(shape).astype(pk.dtype))
-                pv = pv.at[:, wids].set(new_v.reshape(shape).astype(pv.dtype))
+
+                def written(pool, new):  # whole pages, through the view whose faces fill tiles (`_gathered_view`)
+                    view = pool_tile_view(pool)
+                    pages = new.reshape(view.shape[0], n_pages, *view.shape[2:]).astype(pool.dtype)
+                    return view.at[:, wids].set(pages).reshape(pool.shape)
+
+                pk, pv = written(pk, new_k), written(pv, new_v)
                 if windowed:
                     wk = jax.lax.dynamic_update_index_in_dim(wk, nc["wk"][:, 0].astype(wk.dtype), slot, axis=1)
                     wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)

@@ -97,3 +97,56 @@ def test_the_two_kind_cells_kernels_compile_at_its_geometry(one_chip, monkeypatc
     text = jax.jit(jax.vmap(routed, in_axes=(0, None, None, None, None, None))).lower(shape((slots, 1, hidden)), *weights).compile().as_text()
     assert len(re.findall(rf"= bf16\[{CHUNK_ROWS},\d+\]\S* custom-call\(", text)) == 3
     assert text.count("ragged-dot-metadata = ") == 1 and " while(" in text
+
+
+def test_four_kv_heads_share_a_tile_and_the_pool_is_viewed_not_copied(one_chip, monkeypatch):
+    """``mellum2.serve-code``: 64 slots on two full layers' pool of 51,201
+    pages at 4 KV heads of 128 (a page ``[16, 4, 128]`` read as ``[8, 8,
+    128]``, a token pair a tile row). Mosaic takes the packed geometry, the
+    view of the stacked pool is a bitcast (no copy of 1.7 GB a launch, no
+    padded head), and the one launch needs no scratch in HBM."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.paged_attention import paged_decode_attention, paged_kernel_fallback_reason
+
+    slots, layers, pages, ps, kv, nh, d, pps = 64, 2, 51201, 16, 4, 32, 128, 800
+    assert paged_kernel_fallback_reason((pages, ps, kv, d), nh, kv) is None
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q, kn, vn, tables, lengths, pool_k, pool_v, layer):
+        one = lambda q, kn, vn, row, n: paged_decode_attention(q, kn, vn, pool_k, pool_v, row, n, layer)
+        return jax.vmap(one)(q, kn, vn, tables, lengths)
+
+    pool = shape((layers, pages, ps, kv, d))
+    compiled = jax.jit(attend).lower(
+        shape((slots, 1, 1, nh, d)), shape((slots, 1, 1, kv, d)), shape((slots, 1, 1, kv, d)),
+        shape((slots, pps), jnp.int32), shape((slots,), jnp.int32), pool, pool, shape((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(rf"= bf16\[{layers},{pages},8,8,{d}\]\S* bitcast\(", text)) == 2  # K and V: views of the pool where it lies
+    assert not re.search(rf"= bf16\[{layers},{pages},\d+,\d+,{d}\]\S* (copy|fusion)\(", text), "the pool is copied or relaid out"
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mellum2s_experts_compile_as_the_grouped_kernel_named_for_the_trace(one_chip, monkeypatch):
+    """64 experts of 2304 x 896 all held: a decode step's 512 assignments and a
+    prefill span's 8,192 each go through three launches of the Pallas grouped
+    matmul (one expert's whole matrix a tile), in ONE chunk, and the custom
+    calls carry the scope's name, ``moe.experts``, which the benchmark's
+    ``expert_mlp_roofline.serve`` reads the trace by."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.models.moe import dropless_experts
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    hidden, width, experts = 2304, 896, 64
+    weights = (shape((hidden, experts)), shape((experts, hidden, width)), shape((experts, hidden, width)), shape((experts, width, hidden)))
+    routed = lambda x, router, gate, up, down: dropless_experts(x, router, None, gate, up, down, top_k=8, scoring="softmax_topk")
+    for tokens, fn in ((1024, jax.jit(routed)), (64, jax.jit(jax.vmap(routed, in_axes=(0, None, None, None, None))))):
+        x = shape((tokens, hidden)) if tokens == 1024 else shape((tokens, 1, hidden))
+        text = fn.lower(x, *weights).compile().as_text()
+        assert len(re.findall(r"%moe\.experts[.\d]* = bf16\[\d+,\d+\]\S* custom-call\(", text)) == 3, tokens
+        assert "ragged-dot" not in text

@@ -74,11 +74,33 @@ def test_kernel_matches_gather_reference_op_level(num_layers, layer):
 
 # the op-level geometry of the pipeline's tests: pages of 8 make blocks of
 # B = 16 table entries (128 cached tokens), and a table row of 2B + 2 entries
-# holds two whole blocks and a partial third
-PS, KV, D = 8, 2, 32
+# holds two whole blocks and a partial third. Eight KV heads fill a sublane
+# tile by themselves (the accepted serving cells' geometry and code path);
+# fewer share a tile between consecutive tokens, and have tests of their own
+PS, KV, D = 8, 8, 32
 PPS = 34
 B = _pages_per_block(PS, KV, D, jnp.float32, PPS)
 POOL_PAGES = 80
+
+
+def test_eight_kv_heads_trace_the_kernel_they_traced_before_the_packed_geometries():
+    """With KV heads that fill a tile nothing of the shared-tile geometry is
+    traced: the jaxpr of the slot-batched launch at mistral-7b's head geometry
+    (32 query heads on 8 KV heads of 128, pages of 16, bf16) is, letter for
+    letter, the text it was at the commit before PR 34 (its SHA-256; jaxpr
+    text holds no source positions)."""
+    import hashlib
+
+    from accelerate_tpu.ops.paged_attention import _paged_call
+
+    slots, layers, pages, ps, kv, nh, d, pps = 4, 2, 9, 16, 8, 32, 128, 4
+    s = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype)
+    text = str(jax.make_jaxpr(_paged_call)(
+        s((slots, 1, nh, d)), s((slots, 1, kv, d)), s((slots, 1, kv, d)), s((layers, pages, ps, kv, d)), s((layers, pages, ps, kv, d)),
+        s((slots, pps), jnp.int32), s((slots,), jnp.int32), s((), jnp.int32),
+    ))
+    assert "roll" not in text and "name=paged_attention" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == "be191c118418346f6c9451436b09da39fa93b3b93a42be83d7d3bb383ba714ac"
 
 
 def test_block_size_follows_the_shapes():
@@ -241,6 +263,48 @@ def test_a_launch_leaves_nothing_behind_for_the_next():
     np.testing.assert_array_equal(first, flipped[::-1])
 
 
+# KV heads that fill a tile (8), and that share one between 2 and 4 consecutive
+# tokens of a page: mellum2's 4 heads, and 2 (the construction gives it for free)
+@pytest.mark.parametrize("window", [1, 3])
+@pytest.mark.parametrize("kv,ps", [(8, 16), (4, 16), (2, 16), (4, 8), (1, 8)])
+def test_kernel_matches_reference_whatever_share_of_a_tile_the_kv_heads_fill(kv, ps, window):
+    """One slot-batched launch (the engine's ``vmap``) over lanes of mixed
+    lengths: empty, inside the first tile row, odd and even, on both sides of
+    a page's and of a block's end. With fewer than 8 KV heads a page
+    ``[ps, KV, D]`` is read as ``[ps/pack, pack*KV, D]``: a tile row holds
+    ``pack`` consecutive tokens, each sublane keeps a softmax of its own, and
+    they are merged at the end. Every lane against the gather reference on its
+    own table row, eight query heads a KV head; every page no lane holds is
+    NaN, and so is the other layer."""
+    rng = np.random.default_rng(100 * kv + ps + window)
+    d, group, pps = 32, 8, 20
+    block = _pages_per_block(ps, kv, d, jnp.float32, pps) * ps
+    lengths = (0, 1, 2, 3, ps - 1, ps, ps + 1, 2 * ps + 5, block - 1, block, block + 1, block + ps + 2, pps * ps)
+    pages = sum(-(-n // ps) for n in lengths) + 3
+    shape = (2, pages, ps, kv, d)
+    pool_k, pool_v = rng.normal(size=shape).astype(np.float32), rng.normal(size=shape).astype(np.float32)
+    free = iter(rng.permutation(pages))
+    tables = np.zeros((len(lengths), pps), np.int32)
+    for lane, n in enumerate(lengths):
+        tables[lane, : -(-n // ps)] = [next(free) for _ in range(-(-n // ps))]
+    held = np.unique(tables[np.arange(pps)[None] < -(-np.asarray(lengths)[:, None] // ps)])
+    draw = lambda *dims: jnp.asarray(rng.normal(size=dims).astype(np.float32))
+    q, kn, vn = draw(len(lengths), 1, window, kv * group, d), draw(len(lengths), 1, window, kv, d), draw(len(lengths), 1, window, kv, d)
+    attend = jax.jit(lambda q, kn, vn, pk, pv, tables, lengths: jax.vmap(
+        lambda q, kn, vn, row, n: paged_verify_attention(q, kn, vn, pk, pv, row, n, jnp.int32(1))
+    )(q, kn, vn, tables, lengths))
+    got = np.asarray(attend(
+        q, kn, vn, _poisoned(pool_k, 1, held), _poisoned(pool_v, 1, held), jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    ))
+    assert np.all(np.isfinite(got))
+    for lane, n in enumerate(lengths):
+        want = _reference(
+            q[lane], kn[lane], vn[lane], jnp.asarray(pool_k[1]), jnp.asarray(pool_v[1]), jnp.asarray(tables[lane]),
+            jnp.int32(n), scale=1.0 / d**0.5,
+        )
+        np.testing.assert_allclose(got[lane], np.asarray(want), rtol=2e-5, atol=2e-6, err_msg=f"lane {lane}, length {n}")
+
+
 def test_zero_length_attends_only_new_token():
     """length=0 (a fresh or inactive lane) walks no pages: the output is
     attention over the single new token — exactly v_new — so idle lanes can
@@ -269,6 +333,23 @@ def test_fallback_reason_interpret_accepts_mosaic_rejects(monkeypatch):
     assert reason is not None and "128" in reason
     monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "1")
     assert paged_kernel_fallback_reason(shape, 4, 2) is None
+
+
+@pytest.mark.parametrize("kv,ps,runs", [
+    (8, 16, True), (16, 16, True),  # whole tiles of heads
+    (4, 16, True), (2, 16, True), (1, 16, True), (4, 2, True),  # a tile shared by 2, 4, 8, 2 consecutive tokens
+    (4, 1, False), (2, 2, False),  # a page of fewer tokens than share a tile
+    (3, 16, False), (6, 16, False), (12, 16, False),  # heads that neither fill nor divide a tile
+])
+def test_fallback_reason_names_only_what_mosaic_cannot_tile(kv, ps, runs, monkeypatch):
+    """Compiled (not interpreted), the kernel serves KV heads in whole
+    sublane tiles and, since PR 34, 4, 2 or 1 of them: mellum2's 4 no longer
+    fall back. The reason names what still cannot run."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    reason = paged_kernel_fallback_reason((64, ps, kv, 128), 8 * kv, kv)
+    assert (reason is None) == runs, reason
+    if not runs:
+        assert f"kv_heads {kv}" in reason and f"page_size {ps}" in reason
 
 
 # ---------------------------------------------------------------------------
