@@ -18,14 +18,34 @@ softmax as a final block under a causal in-window mask, so the engine's
 write-back stays a separate scatter exactly as in the reference program.
 Plain decode is the window of one token.
 
-The page walk is a software pipeline: copies are in flight while the VPU
-folds. A page's 64 KB need 0.08 us of HBM's bandwidth but ~0.5 us from the
-DMA's issue to its landing (PERF.md §6, PR 31), so the wait is latency, and
-it is paid once for many pages:
+The fold is two MXU products a block, over every query row at once. A page
+``[page_size, KV, D]`` is, byte for byte, a matrix ``[page_size * KV, D]``
+whose row ``c`` is (token ``c // KV``, KV head ``c % KV``); the launch takes
+the pool through that view, ``[L, P, page_size * KV, D]`` (a bitcast of the
+pool where it lies; ``tests/test_mosaic_compile.py`` holds it to the compiled
+text), and the queries as ``[R, D]``, ``R = W * NH``, row ``(wi * group + gi)
+* KV + g`` the query of window position ``wi`` that is the ``gi``-th of KV
+head ``g``'s group: a row's KV head is its index modulo KV, as a column's is.
+``scores = q [R, D] x k [C, D]^T`` is then every query row against every
+(token, KV head) of the block; the entries whose column's head is the row's
+own are the scores wanted, and the others (``KV - 1`` in ``KV``) are masked
+to ``NEG_INF`` together with the positions past the length. The softmax runs
+on ``[R, C]`` with the lanes full; the masked weights are exact zeros, so
+``p [R, C] x v [C, D]`` is each head's weighted sum over its own KV head's
+values and nothing else. The redundancy (8 x at 8 KV heads) is spent on a
+unit that has nothing else to do; nothing is relaid out, and any number of KV
+heads is the same body on other shapes (mellum2's page of 4 heads is simply
+``[64, D]``). Until PR 35 the fold ran on the VPU, a product and a lane
+reduction a query row and a page (305-421 ns a page, where HBM needs 80).
+
+The page walk is a software pipeline: copies are in flight while the block
+before is folded. A page's 64 KB need 0.08 us of HBM's bandwidth but ~0.5 us
+from the DMA's issue to its landing (PERF.md §6, PR 31), so the wait is
+latency, and it is paid once for many pages:
 
 - A **block** is B consecutive entries of the slot's table row. The K and V
-  copies of all its pages (each ``[page_size, KV, D]``, contiguous in the
-  pool) are started together into one ``[B, page_size, KV, D]`` buffer and
+  copies of all its pages (each ``[page_size * KV, D]``, contiguous in the
+  pool) are started together into one ``[B * page_size * KV, D]`` buffer and
   waited for together. B is derived, not set (:func:`_pages_per_block`):
   ``_BLOCK_TOKENS`` cached tokens' worth of pages (8 pages of 16), at most a
   table row's entries, at most what fits two buffers of K and two of V in
@@ -39,72 +59,67 @@ it is paid once for many pages:
   block, and whether it is in flight — are scratch that outlives a grid
   step; the slot axis therefore runs in order (``"arbitrary"``), and the
   first slot resets the state.
-- The fold stays at the grain of one page, read from the buffer in an inner
-  loop: a block of 128 tokens in fp32 would be 128 vregs for K alone.
+- The fold takes a whole block as ONE matrix (``[R, 1024]`` scores at the
+  serving cells' 8 KV heads): what a fold costs beside its products is fixed
+  (~0.3 us: the chain from scores to max to ``exp`` to the value product),
+  so a page a fold read slower than the VPU form and eight pages a fold
+  twice as fast (PERF.md §6, PR 35). A slot's last block is folded whole
+  too, its unfetched part masked.
 
 Three invariants, each held by a test in tests/test_paged_attention.py:
 (1) a page past the length bound is never read — copies start only for a
 block's entries below the slot's page count, a zero-length lane starts none
 and is stepped over by its predecessor's prefetch; (2) rows of a block that
-no copy filled hold whatever VMEM held (NaN bits, possibly) and are never
-folded — the inner loop stops at the page count — so they contribute exactly
-nothing, while the tail of a partial PAGE holds stale finite pool data that
-the mask turns into exact zeros; (3) every copy started is waited for
-exactly once, the last slot's included, through one list of descriptors
-under one predicate for both — a copy left in flight at the kernel's end is
-a hang or a corrupted buffer on the chip and invisible in interpret mode.
+no copy filled hold whatever VMEM held (NaN bits, possibly) and contribute
+exactly nothing: their K columns lie past the length and are masked after
+the product (a select, which NaN scores do not survive), and their V rows
+are ZEROED before the value product, because there a zero weight times NaN
+is NaN (the VPU fold never touched such a row; a product does); the tail of a
+partial PAGE holds stale finite pool data that the mask turns into exact
+zeros; (3) every copy started is waited for exactly once, the last slot's
+included, through one list of descriptors under one predicate for both — a
+copy left in flight at the kernel's end is a hang or a corrupted buffer on
+the chip and invisible in interpret mode.
 
-The kernel takes the whole STACKED pool ``[L, P, page_size, KV, D]`` where it
-lies in HBM and addresses it by (layer, page); the layer index is a third
-scalar-prefetch operand. It does not take one layer's pool ``[P, ...]``: a
-per-layer slice of the stacked pool fed to a custom call is a COPY — the
-call's operand must be a buffer of its own, so XLA materializes the layer's
-whole pool, K and V, for every layer of every step, eight times what the
-kernel then reads (18 % of the serving cell's device time until PR 28;
-PERF.md §6). The decode protocols therefore close their layer scan over the
-pool and scan the layer index (``models/attention.py:split_decode_cache``).
-A single-layer pool is the stacked pool with ``L = 1`` and ``layer = 0``.
-
-The pool keeps heads on the sublane axis (``[.., KV, D]`` tiles), so scores
-are a lane reduction per head on the VPU rather than an MXU matmul — every
-op is a ``[.., KV, D]`` elementwise product, a minor-axis reduce, or a
-major-axis reduce, which is what Mosaic lowers for this layout without a
-relayout. All arithmetic is fp32.
-
-**Fewer KV heads than a tile's 8 sublanes** (4, 2 or 1; mellum2 has 4): the
-pool stays ``[L, P, page_size, KV, D]`` and no head is padded in HBM. The
-launch views a page as ``[page_size / pack, pack * KV, D]`` with ``pack = 8
-// KV``: the same bytes in the same order (XLA makes the reshape a bitcast of
-the pool; ``tests/test_mosaic_compile.py``), so a tile row holds ``pack``
-consecutive tokens and sublane ``r`` is (token ``r // KV`` of the row, KV
-head ``r % KV``). Inside the kernel a sublane is a "head": the query of a KV
-head is repeated on its ``pack`` sublanes, each keeps the online softmax over
-its own tokens (every ``pack``-th of the page) through the unchanged fold,
-positions are masked by ``row * pack + r // KV < length``, the window's own
-keys (padded to whole rows; they are few) count on the first token's sublanes
-only, and at the end the ``pack`` partial softmaxes of a head are merged by
-``log2(pack)`` sublane rotations. The DMAs, the block pipeline and the
-invariants below are untouched: a page is the same 16 KB x KV of HBM either
-way. With ``KV % 8 == 0`` nothing of this is traced and the kernel is the
-one it was (``tests/test_paged_attention.py`` holds its jaxpr to a hash).
+The kernel takes the whole STACKED pool where it lies in HBM and addresses it
+by (layer, page); the layer index is a third scalar-prefetch operand. It does
+not take one layer's pool ``[P, ...]``: a per-layer slice of the stacked pool
+fed to a custom call is a COPY — the call's operand must be a buffer of its
+own, so XLA materializes the layer's whole pool, K and V, for every layer of
+every step, eight times what the kernel then reads (18 % of the serving
+cell's device time until PR 28; PERF.md §6). The decode protocols therefore
+close their layer scan over the pool and scan the layer index
+(``models/attention.py:split_decode_cache``). A single-layer pool is the
+stacked pool with ``L = 1`` and ``layer = 0``.
 
 The engine calls the op per slot under its slot ``vmap``; a custom batching
 rule turns that into ONE slot-batched ``pallas_call`` per layer per step
 with the slot axis as the grid.
 
-Numerics: the running max starts at the flash kernel's ``M_INIT`` so padded
-tail positions of a partial page underflow ``exp`` to exactly 0. Pages are
-folded one by one in table order, whatever B is. A window
-row's own key is always valid, so a row can never be fully masked. At
-temperature 0 the engine's kernel path emits the same tokens as the
-gather-reference path in fp32 (pinned by tests/test_paged_attention.py over
-mixed lengths for both decode protocols); the blocked accumulation order
-means logits agree to roundoff, not bit-for-bit.
+Precision, step by step. The queries arrive pre-scaled in their own dtype.
+The score product takes queries and keys in one dtype, the pool's where the
+queries share it: bf16 x bf16 products are exact in fp32 and the
+accumulation is fp32, so the scores are the VPU form's up to summation
+order. The mask, the running max ``m``, ``exp``, the running sum ``l`` and
+the accumulator ``acc`` are fp32. The probabilities go into the value
+product in the pool's dtype, which is what the program's own attention does
+everywhere outside this kernel (``softmax(...).astype(q.dtype)``,
+``models/attention.py``); ``l`` sums them before that rounding. With fp32
+operands (the tests) both products run at ``Precision.HIGHEST``. The
+window's own keys, ``W * KV`` columns, are folded in fp32 whatever the pool's
+dtype. The running max starts at the flash kernel's ``M_INIT`` so masked
+entries underflow ``exp`` to exactly 0; a window row's own key is always
+valid, so a row can never be fully masked. At temperature 0 the engine's
+kernel path emits the same tokens as the gather-reference path in fp32
+(pinned by tests/test_paged_attention.py over mixed lengths for both decode
+protocols); the blocked accumulation order means logits agree to roundoff,
+not bit-for-bit.
 
 Off-TPU the kernel runs in interpret mode (tier-1 exercises the page walk
 for real). Geometries Mosaic cannot tile are named by
 :func:`paged_kernel_fallback_reason`; the engine then keeps its gather
-program and reports why.
+program and reports why. :func:`pool_tile_view` is not the kernel's: it
+serves the prefill's page gather and write (``serving/engine.py``).
 """
 
 from __future__ import annotations
@@ -125,12 +140,14 @@ def paged_kernel_fallback_reason(
     page_shape: tuple, num_heads: int, kv_heads: int
 ) -> Optional[str]:
     """Why the paged kernel cannot serve this pool geometry ``[P, page_size,
-    KV, D]`` (None = it can). Interpret mode runs any shape; Mosaic DMAs whole
-    ``[page_size, KV, D]`` pages, whose ``(KV, D)`` face must fill ``(8,
-    128)`` tiles, or divide one and share it between consecutive tokens of
-    the page (:func:`_tokens_per_tile`). The engine records the reason in its
-    ``{"kind":"kernels"}`` telemetry so a fleet's kernel coverage is a query
-    away."""
+    KV, D]`` (None = it can). Interpret mode runs any shape; Mosaic DMAs a
+    page as the matrix ``[page_size * KV, D]``, whose rows must fill
+    8-sublane tiles and whose ``D`` must fill 128 lanes: any number of KV
+    heads, 1, 3 and 6 among them, as long as the page's rows come in eights
+    (each answer from a compile ahead of time for a described v5e, bf16 and
+    fp32 pools; ``tests/test_mosaic_compile.py`` holds both sides). The
+    engine records the reason in its ``{"kind":"kernels"}`` telemetry so a
+    fleet's kernel coverage is a query away."""
     d, page_size = int(page_shape[-1]), int(page_shape[-3])
     if num_heads % kv_heads:
         return f"num_heads {num_heads} not a multiple of kv_heads {kv_heads}"
@@ -138,10 +155,10 @@ def paged_kernel_fallback_reason(
         return None
     if d % 128:
         return f"head dim {d} is not a multiple of 128 (Mosaic lane tiling)"
-    if kv_heads % 8 and _tokens_per_tile(kv_heads, page_size) == 1:
+    if (page_size * kv_heads) % 8:
         return (
-            f"kv_heads {kv_heads} neither fills (8, 128) sublane tiles nor divides one with "
-            f"page_size {page_size} a multiple of the tokens a tile then holds"
+            f"a page's rows, page_size {page_size} x kv_heads {kv_heads} = {page_size * kv_heads}, "
+            "are not a multiple of 8 (Mosaic sublane tiling)"
         )
     return None
 
@@ -169,23 +186,6 @@ def pool_tile_view(pool: jax.Array) -> jax.Array:
     return pool if pack == 1 else pool.reshape(*pool.shape[:-3], page_size // pack, kv * pack, d)
 
 
-def _fold(carry, q, k, v, valid):
-    """Fold one block of keys into a row's online softmax. ``q`` is
-    ``[KV, D]`` (one query per kv head), ``k``/``v`` are ``[T, KV, D]``,
-    ``valid`` broadcasts against ``[T, KV, 1]``; all fp32."""
-    m, l, acc = carry
-    s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [T, KV, 1]
-    s = jnp.where(valid, s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=0))
-    p = jnp.exp(s - m_new[None])
-    correction = jnp.exp(m - m_new)
-    return (
-        m_new,
-        l * correction + jnp.sum(p, axis=0),
-        acc * correction + jnp.sum(p * v, axis=0),
-    )
-
-
 # a block aims at this many cached tokens: the fixed cost of issuing a DMA
 # and seeing it land (~0.5 us, six times what HBM needs for a 64 KB page) is
 # then paid once for the block's pages, not once a page
@@ -207,43 +207,67 @@ def _paged_kernel(
     tables_ref,  # SMEM [S, pps] int32 (scalar prefetch): page-table rows
     lengths_ref,  # SMEM [S] int32 (scalar prefetch): committed positions
     layer_ref,  # SMEM [1] int32 (scalar prefetch): which layer of the pool
-    q_ref,  # VMEM [1, W*group, KV, D]: row wi*group+gi (pre-scaled)
-    kn_ref,  # VMEM [1, W, KV, D]: the window's keys (pre-scatter)
-    vn_ref,  # VMEM [1, W, KV, D]
-    pool_k_ref,  # ANY (HBM) [L, P, ps, KV, D]: the stacked pool, in place
-    pool_v_ref,  # ANY (HBM) [L, P, ps, KV, D]
-    o_ref,  # VMEM [1, W*group, KV, D] out
-    k_buf,  # VMEM [2, B, ps, KV, D] pool dtype: two buffers of one block
-    v_buf,  # VMEM [2, B, ps, KV, D]
+    q_ref,  # VMEM [1, R, D]: row (wi*group + gi)*KV + g, pre-scaled
+    kn_ref,  # VMEM [1, W*KV, D]: the window's keys (pre-scatter), row wi*KV + g
+    vn_ref,  # VMEM [1, W*KV, D]
+    pool_k_ref,  # ANY (HBM) [L, P, ps*KV, D]: the stacked pool, in place
+    pool_v_ref,  # ANY (HBM) [L, P, ps*KV, D]
+    o_ref,  # VMEM [1, R, D] out
+    k_buf,  # VMEM [2, B*ps*KV, D] pool dtype: two buffers of one block
+    v_buf,  # VMEM [2, B*ps*KV, D]
     sems,  # DMA semaphores [2, 2]: (K | V, buffer)
     pipe,  # SMEM [2] int32: (buffer of the next block to fold, is it in flight)
     *,
     page_size: int,
+    kv_heads: int,
     block_pages: int,
     window: int,
-    group: int,
-    pack: int,
 ):
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
     layer = layer_ref[0]
-    # with ``pack`` > 1 everything here is in the packed view: ``page_size``
-    # counts a page's tile rows of ``pack`` tokens each, and a "head" is a
-    # sublane (token % pack, kv head), with a softmax of its own until the end
-    kv, d = q_ref.shape[-2:]
+    rows, d = q_ref.shape[-2:]
+    kv = kv_heads
+    page_rows = page_size * kv
     f32 = jnp.float32
-    queries = [q_ref[0, r].astype(f32) for r in range(window * group)]  # [KV, D] each
-    init = (
-        jnp.full((kv, 1), M_INIT, f32),
-        jnp.zeros((kv, 1), f32),
-        jnp.zeros((kv, d), f32),
-    )
+    q = q_ref[0]
+
+    def fold(carry, q, k, v, valid):
+        """Fold keys ``k`` / values ``v`` ``[C, D]`` into every row's online
+        softmax at once; ``valid`` ``[R, C]`` holds a row's own KV head's
+        columns that it may attend, so the other heads' weights are exact
+        zeros in the value product. Both products take their operands in one
+        dtype, the pool's where the queries share it (bf16 x bf16 is exact in
+        the MXU's fp32 accumulation; fp32 operands run at ``HIGHEST``)."""
+        m, l, acc = carry
+        dtype = jnp.promote_types(q.dtype, k.dtype)
+        precision = jax.lax.Precision.HIGHEST if dtype == f32 else jax.lax.Precision.DEFAULT
+        s = jax.lax.dot_general(
+            q.astype(dtype), k.astype(dtype), (((1,), (1,)), ((), ())), preferred_element_type=f32, precision=precision
+        )
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m - m_new)
+        weighted = jax.lax.dot_general(
+            p.astype(dtype), v.astype(dtype), (((1,), (0,)), ((), ())), preferred_element_type=f32, precision=precision
+        )
+        return m_new, l * correction + jnp.sum(p, axis=-1, keepdims=True), acc * correction + weighted
+
+    def own_head(columns):
+        """``[R, columns]``: is the column's KV head (``c % KV``) the row's."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, columns), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, columns), 1)
+        return jax.lax.rem(col, kv) == jax.lax.rem(row, kv), row, col
 
     def ceil_div(n, m):
         return jax.lax.div(n + jnp.int32(m - 1), jnp.int32(m))
 
     def pages_of(s):
-        return ceil_div(lengths_ref[s], page_size * pack)
+        return ceil_div(lengths_ref[s], page_size)
+
+    def page_at(p):
+        return pl.ds(p * page_rows, page_rows)
 
     def copies(s, b, buf, act):
         """``act`` ("start" | "wait") on the copies of block ``b`` of slot
@@ -257,7 +281,7 @@ def _paged_kernel(
             def _():
                 page = tables_ref[s, b * block_pages + p]
                 for kind, (pool, dst) in enumerate(((pool_k_ref, k_buf), (pool_v_ref, v_buf))):
-                    dma = pltpu.make_async_copy(pool.at[layer, page], dst.at[buf, p], sems.at[kind, buf])
+                    dma = pltpu.make_async_copy(pool.at[layer, page], dst.at[buf, page_at(p)], sems.at[kind, buf])
                     getattr(dma, act)()
 
     # scratch outlives a grid step (and a launch): the first slot resets the
@@ -274,10 +298,7 @@ def _paged_kernel(
     npages = pages_of(slot)
     nblocks = ceil_div(npages, block_pages)
     first = pipe[0]
-    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 0)
-    if pack > 1:  # sublane (token % pack, kv head) of tile row (token // pack)
-        sublane = jax.lax.broadcasted_iota(jnp.int32, (page_size, kv, 1), 1)
-        pos_in_page = pos_in_page * pack + sublane // (kv // pack)
+    same_head, _, col = own_head(block_pages * page_rows)
 
     # the launch's first non-empty slot has no predecessor to have started
     # its first block
@@ -312,41 +333,37 @@ def _paged_kernel(
 
         copies(slot, b, buf, "wait")
 
-        # fold at the grain of one page, and only the pages that were
-        # fetched: the rest of a partial block holds whatever VMEM held
-        def page(p, carry):
-            k = k_buf[buf, p].astype(f32)
-            v = v_buf[buf, p].astype(f32)
-            # mask the partial last page: positions >= length hold stale pool
-            # data (or the unwritten tail) and must underflow exp to exactly 0
-            valid = (b * block_pages + p) * (page_size * pack) + pos_in_page < length
-            return tuple(_fold(c, q, k, v, valid) for c, q in zip(carry, queries))
+        # fold the whole block as one matrix ``[B*ps*KV, D]``. Rows that no
+        # copy filled (a slot's last block may be partial) hold whatever VMEM
+        # held: in K their columns lie past the length and are masked, but a
+        # zero weight times NaN bits in V is NaN in a product, so those V rows
+        # are zeroed first
+        present = npages - b * block_pages
+        for p in range(1, block_pages):
+            @pl.when(p >= present)
+            def _():
+                v_buf[buf, page_at(p), :] = jnp.zeros((page_rows, d), v_buf.dtype)
 
-        return jax.lax.fori_loop(0, jnp.minimum(npages - b * block_pages, block_pages), page, carry)
+        # a column's position is below the length iff the column is below
+        # this many: positions >= length hold stale pool data (or the
+        # unwritten tail) and must underflow exp to exactly 0
+        live = (length - b * (block_pages * page_size)) * kv
+        return fold(carry, q, k_buf[buf], v_buf[buf], jnp.logical_and(same_head, col < live))
 
-    carry = jax.lax.fori_loop(0, nblocks, block, (init,) * len(queries))
+    init = (jnp.full((rows, 1), M_INIT, f32), jnp.zeros((rows, 1), f32), jnp.zeros((rows, d), f32))
+    carry = jax.lax.fori_loop(0, nblocks, block, init)
 
     # the candidate window (positions length..length+W-1) is not in the pool
     # yet — the engine's write-back is a separate masked scatter — so it folds
     # in as one final block with a causal mask INSIDE the window: the row at
     # window position wi may attend window keys 0..wi
-    kn = kn_ref[0].astype(f32)
-    vn = vn_ref[0].astype(f32)
-    key_pos = jax.lax.broadcasted_iota(jnp.int32, (window, kv, 1), 0)
-    if pack > 1:  # the window's keys lie on the first token's sublanes; the others hold padding
-        key_pos = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (window, kv, 1), 1) < kv // pack, key_pos, window)
-    for r, (c, q) in enumerate(zip(carry, queries)):
-        m, l, acc = _fold(c, q, kn, vn, key_pos <= r // group)
-        # one kv head's ``pack`` sublanes hold softmaxes over disjoint keys of
-        # the same query: merge them (every sublane ends with the whole)
-        shift = kv // 2
-        while shift >= kv // pack:
-            m_o, l_o, acc_o = (pltpu.roll(x, shift, 0) for x in (m, l, acc))
-            m_all = jnp.maximum(m, m_o)
-            mine, theirs = jnp.exp(m - m_all), jnp.exp(m_o - m_all)
-            m, l, acc = m_all, l * mine + l_o * theirs, acc * mine + acc_o * theirs
-            shift //= 2
-        o_ref[0, r] = (acc / l).astype(o_ref.dtype)
+    valid, row, col = own_head(window * kv)
+    if window > 1:
+        valid = jnp.logical_and(valid, jax.lax.div(col, kv) <= jax.lax.div(row, rows // window))
+    # in fp32: the window's keys are few (W*KV columns), and Mosaic does not
+    # lower a bf16 product whose contraction is one row (one KV head, W = 1)
+    _, l, acc = fold(carry, q.astype(f32), kn_ref[0].astype(f32), vn_ref[0].astype(f32), valid)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
@@ -355,62 +372,53 @@ def _paged_call(q, k_new, v_new, pool_k, pool_v, tables, lengths, layer):
     ``[L, P, ps, KV, D]``, ``tables`` ``[S, pps]``, ``lengths`` ``[S]``,
     ``layer`` scalar → ``[S, W, NH, D]``."""
     s, w, nh, d = q.shape
-    kv = k_new.shape[2]
-    ps = pool_k.shape[-3]
+    layers, pages, ps, kv = pool_k.shape[:4]
     group = nh // kv
-    rows = w * group
+    rows = w * nh
+    page_rows = ps * kv
     block_pages = _pages_per_block(ps, kv, d, pool_k.dtype, tables.shape[1])
-    # head h = g*group + gi reads kv head g (the zoo's GQA convention): lay
-    # the queries out as rows of one-query-per-kv-head, row = wi*group + gi
-    q_rows = q.reshape(s, w, kv, group, d).transpose(0, 1, 3, 2, 4).reshape(s, rows, kv, d)
-    # fewer KV heads than a tile's 8 sublanes: view a page ``[ps, KV, D]`` as
-    # ``[ps/pack, pack*KV, D]`` (the same bytes in the same order; no head is
-    # padded in HBM), so a tile row holds ``pack`` consecutive tokens. The
-    # query of a kv head is repeated on each of its sublanes, and the window's
-    # own keys, which are few, are padded to whole rows.
-    pack = _tokens_per_tile(kv, ps)
-    heads = kv
-    if pack > 1:
-        kv, ps = kv * pack, ps // pack
-        pool_k, pool_v = pool_tile_view(pool_k), pool_tile_view(pool_v)
-        q_rows = jnp.tile(q_rows, (1, 1, pack, 1))
-        k_new, v_new = (jnp.pad(x, ((0, 0), (0, 0), (0, kv - heads), (0, 0))) for x in (k_new, v_new))
+    # head h = g*group + gi reads kv head g (the zoo's GQA convention): row
+    # (wi*group + gi)*KV + g, so a row's KV head is its index modulo KV, as a
+    # page's row c = token*KV + g has head c modulo KV
+    q_rows = q.reshape(s, w, kv, group, d).transpose(0, 1, 3, 2, 4).reshape(s, rows, d)
 
     def per_slot(n):
-        return pl.BlockSpec((1, n, kv, d), lambda i, *_: (i, 0, 0, 0), memory_space=pltpu.VMEM)
+        return pl.BlockSpec((1, n, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM)
 
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, page_size=ps, block_pages=block_pages, window=w, group=group, pack=pack
+            _paged_kernel, page_size=ps, kv_heads=kv, block_pages=block_pages, window=w
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s,),
             in_specs=[
                 per_slot(rows),
-                per_slot(w),
-                per_slot(w),
+                per_slot(w * kv),
+                per_slot(w * kv),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=per_slot(rows),
             scratch_shapes=[
-                pltpu.VMEM((2, block_pages, ps, kv, d), pool_k.dtype),
-                pltpu.VMEM((2, block_pages, ps, kv, d), pool_v.dtype),
+                pltpu.VMEM((2, block_pages * page_rows, d), pool_k.dtype),
+                pltpu.VMEM((2, block_pages * page_rows, d), pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s, rows, kv, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, rows, d), q.dtype),
         # a slot's last block starts the next slot's first: slots run in order
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(),
         name="paged_attention",
     )(
         tables.astype(jnp.int32), lengths.astype(jnp.int32), layer.astype(jnp.int32).reshape(1),
-        q_rows, k_new, v_new, pool_k, pool_v,
+        q_rows, k_new.reshape(s, w * kv, d), v_new.reshape(s, w * kv, d),
+        # a page [ps, KV, D] is, byte for byte, the matrix [ps*KV, D]: a bitcast of the pool
+        pool_k.reshape(layers, pages, page_rows, d), pool_v.reshape(layers, pages, page_rows, d),
     )
-    return out[:, :, :heads].reshape(s, w, group, heads, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
+    return out.reshape(s, w, group, kv, d).transpose(0, 1, 3, 2, 4).reshape(s, w, nh, d)
 
 
 @jax.custom_batching.custom_vmap

@@ -29,17 +29,12 @@ def one_chip():
         yield SingleDeviceSharding(topo.devices[0])
 
 
-def test_paged_attention_addresses_the_stacked_pool_under_mosaic(one_chip, monkeypatch):
-    """The serving cell's geometry (mistral-7b: 32 slots, 16 layers of a
-    2561-page pool, 8 KV heads of 128): the page DMA's two-index source
-    ``pool.at[layer, page]`` on the 5-D HBM ref lowers, the slot vmap lands
-    in ONE custom call, and nothing around it writes a layer of the pool."""
-    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")  # build the real kernel off-TPU
+def _compile_paged_decode(one_chip, slots, layers, pages, ps, kv, nh, d, pps, dtype=jnp.bfloat16):
+    """The engine's slot ``vmap`` of the paged kernel over one stacked pool
+    ``[layers, pages, ps, kv, d]``, compiled for the described chip."""
     from accelerate_tpu.ops.paged_attention import paged_decode_attention
 
-    slots, layers, pages, ps, kv, nh, d, pps = 32, 16, 2561, 16, 8, 32, 128, 80
-
-    def shape(dims, dtype=jnp.bfloat16):
+    def shape(dims, dtype=dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def attend(q, kn, vn, tables, lengths, pool_k, pool_v, layer):
@@ -47,40 +42,52 @@ def test_paged_attention_addresses_the_stacked_pool_under_mosaic(one_chip, monke
         return jax.vmap(one)(q, kn, vn, tables, lengths)
 
     pool = shape((layers, pages, ps, kv, d))
-    compiled = jax.jit(attend).lower(
+    return jax.jit(attend).lower(
         shape((slots, 1, 1, nh, d)), shape((slots, 1, 1, kv, d)), shape((slots, 1, 1, kv, d)),
         shape((slots, pps), jnp.int32), shape((slots,), jnp.int32), pool, pool, shape((), jnp.int32),
     ).compile()
+
+
+def _pool_is_viewed_where_it_lies(compiled, layers, pages, page_rows, d):
+    """One custom call; the kernel's operand ``[L, P, ps*KV, D]`` is a bitcast
+    of the stacked pool, K and V (a page ``[ps, KV, D]`` is, byte for byte, the
+    matrix ``[ps*KV, D]``); nothing copies or relays the pool out; no scratch in
+    HBM."""
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert not re.search(rf"= bf16\[{pages},{ps},{kv},{d}\]", text), "a layer of the pool is copied out"
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pages * ps * kv * d  # under one layer's pool
+    assert len(re.findall(rf"= bf16\[{layers},{pages},{page_rows},{d}\]\S* bitcast\(", text)) == 2
+    assert not re.search(rf"= bf16\[({layers},)?{pages},[\d,]+{d}\]\S* (copy|fusion)\(", text), "the pool, or a layer of it, is copied or relaid out"
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_paged_attention_addresses_the_stacked_pool_under_mosaic(one_chip, monkeypatch):
+    """The serving cell's geometry (mistral-7b: 32 slots, 16 layers of a
+    2561-page pool, 8 KV heads of 128, R = 32 query rows against pages of 128
+    rows): the page DMA's two-index source ``pool.at[layer, page]`` on the
+    4-D view of the HBM ref lowers, both MXU products lower, the slot vmap
+    lands in ONE custom call, and nothing around it writes a layer of the
+    pool."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")  # build the real kernel off-TPU
+    slots, layers, pages, ps, kv, nh, d, pps = 32, 16, 2561, 16, 8, 32, 128, 80
+    compiled = _compile_paged_decode(one_chip, slots, layers, pages, ps, kv, nh, d, pps)
+    _pool_is_viewed_where_it_lies(compiled, layers, pages, ps * kv, d)
 
 
 def test_the_two_kind_cells_kernels_compile_at_its_geometry(one_chip, monkeypatch):
     """``k-exaone.serve-mixed``: 128 slots on ONE full layer's pool of 20,481
-    pages, eight query heads a KV head (mistral has four), and the held experts'
-    grouped products (XLA's own Mosaic grouped product, a chunk of 256 rows at
-    a time) for a prefill chunk's many tokens and for a decode step's few."""
+    pages, eight query heads a KV head (mistral has four: R = 64 query rows),
+    and the held experts' grouped products (XLA's own Mosaic grouped product, a
+    chunk of 256 rows at a time) for a prefill chunk's many tokens and for a
+    decode step's few."""
     monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
     from accelerate_tpu.models.moe import CHUNK_ROWS, dropless_experts
-    from accelerate_tpu.ops.paged_attention import paged_decode_attention
 
     slots, pages, ps, kv, nh, d, pps = 128, 20481, 16, 8, 64, 128, 160
+    compiled = _compile_paged_decode(one_chip, slots, 1, pages, ps, kv, nh, d, pps)
+    _pool_is_viewed_where_it_lies(compiled, 1, pages, ps * kv, d)
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    def attend(q, kn, vn, tables, lengths, pool_k, pool_v, layer):
-        one = lambda q, kn, vn, row, n: paged_decode_attention(q, kn, vn, pool_k, pool_v, row, n, layer)
-        return jax.vmap(one)(q, kn, vn, tables, lengths)
-
-    pool = shape((1, pages, ps, kv, d))
-    compiled = jax.jit(attend).lower(
-        shape((slots, 1, 1, nh, d)), shape((slots, 1, 1, kv, d)), shape((slots, 1, 1, kv, d)),
-        shape((slots, pps), jnp.int32), shape((slots,), jnp.int32), pool, pool, shape((), jnp.int32),
-    ).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
     hidden, width, held, experts = 6144, 2048, 16, 128
     weights = (
@@ -99,35 +106,41 @@ def test_the_two_kind_cells_kernels_compile_at_its_geometry(one_chip, monkeypatc
     assert text.count("ragged-dot-metadata = ") == 1 and " while(" in text
 
 
-def test_four_kv_heads_share_a_tile_and_the_pool_is_viewed_not_copied(one_chip, monkeypatch):
+def test_four_kv_heads_are_pages_of_64_rows_and_the_pool_is_viewed_not_copied(one_chip, monkeypatch):
     """``mellum2.serve-code``: 64 slots on two full layers' pool of 51,201
-    pages at 4 KV heads of 128 (a page ``[16, 4, 128]`` read as ``[8, 8,
-    128]``, a token pair a tile row). Mosaic takes the packed geometry, the
-    view of the stacked pool is a bitcast (no copy of 1.7 GB a launch, no
-    padded head), and the one launch needs no scratch in HBM."""
+    pages at 4 KV heads of 128: a page ``[16, 4, 128]`` is the matrix ``[64,
+    128]``, no shared-tile geometry, no padded head. Mosaic takes it, the view
+    of the stacked pool is a bitcast (no copy of 1.7 GB a launch), and the one
+    launch needs no scratch in HBM."""
     monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
-    from accelerate_tpu.ops.paged_attention import paged_decode_attention, paged_kernel_fallback_reason
+    from accelerate_tpu.ops.paged_attention import paged_kernel_fallback_reason
 
     slots, layers, pages, ps, kv, nh, d, pps = 64, 2, 51201, 16, 4, 32, 128, 800
     assert paged_kernel_fallback_reason((pages, ps, kv, d), nh, kv) is None
+    compiled = _compile_paged_decode(one_chip, slots, layers, pages, ps, kv, nh, d, pps)
+    _pool_is_viewed_where_it_lies(compiled, layers, pages, ps * kv, d)
 
-    def shape(dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    def attend(q, kn, vn, tables, lengths, pool_k, pool_v, layer):
-        one = lambda q, kn, vn, row, n: paged_decode_attention(q, kn, vn, pool_k, pool_v, row, n, layer)
-        return jax.vmap(one)(q, kn, vn, tables, lengths)
+@pytest.mark.parametrize("kv,ps,group", [
+    (1, 16, 8), (1, 8, 1), (2, 4, 8), (3, 16, 2), (6, 4, 1), (12, 2, 1), (8, 1, 4), (16, 16, 2),  # page rows in eights: 16, 8, 8, 48, 24, 24, 8, 256
+    (3, 4, 2), (2, 2, 8), (4, 1, 8), (6, 2, 1),  # 12, 4, 4, 12 rows a page
+])
+def test_the_gate_says_what_mosaic_takes(kv, ps, group, one_chip, monkeypatch):
+    """``paged_kernel_fallback_reason`` against the compiler itself, on both
+    sides of its one geometric rule (a page's ``ps * KV`` rows in eights, a
+    bf16 pool): where it names no reason the kernel compiles, one KV head
+    attending a one-token window among them, and where it names one Mosaic
+    refuses the page DMA."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.paged_attention import paged_kernel_fallback_reason
 
-    pool = shape((layers, pages, ps, kv, d))
-    compiled = jax.jit(attend).lower(
-        shape((slots, 1, 1, nh, d)), shape((slots, 1, 1, kv, d)), shape((slots, 1, 1, kv, d)),
-        shape((slots, pps), jnp.int32), shape((slots,), jnp.int32), pool, pool, shape((), jnp.int32),
-    ).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert len(re.findall(rf"= bf16\[{layers},{pages},8,8,{d}\]\S* bitcast\(", text)) == 2  # K and V: views of the pool where it lies
-    assert not re.search(rf"= bf16\[{layers},{pages},\d+,\d+,{d}\]\S* (copy|fusion)\(", text), "the pool is copied or relaid out"
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    reason = paged_kernel_fallback_reason((64, ps, kv, 128), kv * group, kv)
+    if reason is None:
+        text = _compile_paged_decode(one_chip, 4, 2, 64, ps, kv, kv * group, 128, 8).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+    else:
+        with pytest.raises(Exception, match="(?i)mosaic|tiled|memref|pallas"):
+            _compile_paged_decode(one_chip, 4, 2, 64, ps, kv, kv * group, 128, 8)
 
 
 def test_mellum2s_experts_compile_as_the_grouped_kernel_named_for_the_trace(one_chip, monkeypatch):

@@ -39,6 +39,18 @@ def _mixed_prompts(vocab, lengths, seed=0):
     return [rng.integers(1, vocab, (n,)).astype(np.int32) for n in lengths]
 
 
+def _deal_pages(rng, lengths, page_size, pages_per_slot, pool_pages):
+    """Table rows ``[len(lengths), pages_per_slot]``: each lane's pages are
+    its own, dealt from a shuffled pool; entries past a lane's page count stay
+    page 0."""
+    free = iter(rng.permutation(pool_pages))
+    tables = np.zeros((len(lengths), pages_per_slot), np.int32)
+    for lane, n in enumerate(lengths):
+        held = -(-n // page_size)
+        tables[lane, :held] = [next(free) for _ in range(held)]
+    return tables
+
+
 # ---------------------------------------------------------------------------
 # op level
 # ---------------------------------------------------------------------------
@@ -74,33 +86,52 @@ def test_kernel_matches_gather_reference_op_level(num_layers, layer):
 
 # the op-level geometry of the pipeline's tests: pages of 8 make blocks of
 # B = 16 table entries (128 cached tokens), and a table row of 2B + 2 entries
-# holds two whole blocks and a partial third. Eight KV heads fill a sublane
-# tile by themselves (the accepted serving cells' geometry and code path);
-# fewer share a tile between consecutive tokens, and have tests of their own
+# holds two whole blocks and a partial third. Other KV head counts run the
+# same body on other shapes, and have tests of their own
 PS, KV, D = 8, 8, 32
 PPS = 34
 B = _pages_per_block(PS, KV, D, jnp.float32, PPS)
 POOL_PAGES = 80
 
 
-def test_eight_kv_heads_trace_the_kernel_they_traced_before_the_packed_geometries():
-    """With KV heads that fill a tile nothing of the shared-tile geometry is
-    traced: the jaxpr of the slot-batched launch at mistral-7b's head geometry
-    (32 query heads on 8 KV heads of 128, pages of 16, bf16) is, letter for
-    letter, the text it was at the commit before PR 34 (its SHA-256; jaxpr
-    text holds no source positions)."""
-    import hashlib
+def _primitives(jaxpr):
+    """Every primitive's name under ``jaxpr``, in order, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_primitives(sub))
+    return names
 
+
+def _kernel_primitives(kv, group):
     from accelerate_tpu.ops.paged_attention import _paged_call
 
-    slots, layers, pages, ps, kv, nh, d, pps = 4, 2, 9, 16, 8, 32, 128, 4
+    slots, layers, pages, ps, d, pps = 4, 2, 9, 16, 128, 4
     s = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype)
-    text = str(jax.make_jaxpr(_paged_call)(
-        s((slots, 1, nh, d)), s((slots, 1, kv, d)), s((slots, 1, kv, d)), s((layers, pages, ps, kv, d)), s((layers, pages, ps, kv, d)),
+    traced = jax.make_jaxpr(_paged_call)(
+        s((slots, 1, kv * group, d)), s((slots, 1, kv, d)), s((slots, 1, kv, d)), s((layers, pages, ps, kv, d)), s((layers, pages, ps, kv, d)),
         s((slots, pps), jnp.int32), s((slots,), jnp.int32), s((), jnp.int32),
-    ))
-    assert "roll" not in text and "name=paged_attention" in text
-    assert hashlib.sha256(text.encode()).hexdigest() == "be191c118418346f6c9451436b09da39fa93b3b93a42be83d7d3bb383ba714ac"
+    )
+    (call,) = [eqn for eqn in traced.jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    assert call.params["name"] == "paged_attention"  # what the benchmark's roofline metric reads the trace by
+    return _primitives(call.params["jaxpr"])
+
+
+@pytest.mark.parametrize("kv,group", [(8, 4), (8, 8), (4, 8), (2, 8), (1, 8)])
+def test_one_body_for_every_kv_head_count_with_two_products_a_fold(kv, group):
+    """The fold is two MXU products over all query rows at once, whatever the
+    geometry: the traced kernel (mistral-7b's heads, k-exaone's, mellum2's 4 KV
+    heads, 2, 1; pages of 16, bf16) holds two ``dot_general``s for the block
+    fold and two for the window's own keys, one lane-wise max and one sum a
+    fold however many rows a KV head has (the VPU fold had them a row), no
+    sublane rotation, and the same primitives in the same order at every KV
+    head count: one body, no shared-tile geometry."""
+    names = _kernel_primitives(kv, group)
+    assert names.count("dot_general") == 4
+    assert names.count("reduce_max") == 2 and names.count("reduce_sum") == 2
+    assert not [n for n in names if "roll" in n or "rotate" in n]
+    assert names == _kernel_primitives(8, 4)
 
 
 def test_block_size_follows_the_shapes():
@@ -193,8 +224,10 @@ def test_kernel_never_reads_unwalked_pages_and_masks_stale_tails():
     length = B * PS + PS + 3  # one whole block, then one page and 3 positions of the next
     walked = -(-length // PS)
     assert B < walked < 2 * B < PPS  # poison lies inside the last block AND in a later one
+    # copies (``jnp.array``): on the CPU ``jnp.asarray`` may alias the numpy
+    # buffer, which is overwritten below while ``clean`` may still be pending
     clean = _attend(
-        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(table),
+        q, kn, vn, jnp.array(pool_k), jnp.array(pool_v), jnp.asarray(table),
         jnp.int32(length), jnp.int32(layer),
     )
     partial = table[walked - 1]
@@ -204,7 +237,7 @@ def test_kernel_never_reads_unwalked_pages_and_masks_stale_tails():
     pool_k[layer, partial, 3:] = 1e6  # stale-but-finite tail of the partial page
     pool_v[layer, partial, 3:] = -1e6
     poisoned = _attend(
-        q, kn, vn, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(table),
+        q, kn, vn, jnp.array(pool_k), jnp.array(pool_v), jnp.asarray(table),
         jnp.int32(length), jnp.int32(layer),
     )
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
@@ -215,11 +248,7 @@ def _lanes(rng, lengths):
     are its own, and every page no lane holds is NaN."""
     slots = len(lengths)
     pool_k, pool_v = _pool(rng)
-    free = iter(rng.permutation(POOL_PAGES))
-    tables = np.zeros((slots, PPS), np.int32)
-    for lane, n in enumerate(lengths):
-        for j in range(-(-n // PS)):
-            tables[lane, j] = next(free)
+    tables = _deal_pages(rng, lengths, PS, PPS, POOL_PAGES)
     held = np.unique(tables[np.arange(PPS)[None] < -(-np.asarray(lengths)[:, None] // PS)])
     q, kn, vn = (jnp.stack(x) for x in zip(*(_window(rng, 1, 4 * KV) for _ in range(slots))))
     return (
@@ -263,19 +292,18 @@ def test_a_launch_leaves_nothing_behind_for_the_next():
     np.testing.assert_array_equal(first, flipped[::-1])
 
 
-# KV heads that fill a tile (8), and that share one between 2 and 4 consecutive
-# tokens of a page: mellum2's 4 heads, and 2 (the construction gives it for free)
+# mistral-7b's and k-exaone's 8 KV heads, mellum2's 4, and 2 and 1: pages of 128, 64, 32, 32 and 8 rows
 @pytest.mark.parametrize("window", [1, 3])
 @pytest.mark.parametrize("kv,ps", [(8, 16), (4, 16), (2, 16), (4, 8), (1, 8)])
 def test_kernel_matches_reference_whatever_share_of_a_tile_the_kv_heads_fill(kv, ps, window):
     """One slot-batched launch (the engine's ``vmap``) over lanes of mixed
-    lengths: empty, inside the first tile row, odd and even, on both sides of
-    a page's and of a block's end. With fewer than 8 KV heads a page
-    ``[ps, KV, D]`` is read as ``[ps/pack, pack*KV, D]``: a tile row holds
-    ``pack`` consecutive tokens, each sublane keeps a softmax of its own, and
-    they are merged at the end. Every lane against the gather reference on its
-    own table row, eight query heads a KV head; every page no lane holds is
-    NaN, and so is the other layer."""
+    lengths: empty, inside the first page, odd and even, on both sides of a
+    page's and of a block's end. A page ``[ps, KV, D]`` is read as the matrix
+    ``[ps * KV, D]`` whatever KV is: row ``c`` is (token ``c // KV``, KV head
+    ``c % KV``), and a query row attends the columns of its own KV head alone.
+    Every lane against the gather reference on its own table row, eight query
+    heads a KV head; every page no lane holds is NaN, and so is the other
+    layer."""
     rng = np.random.default_rng(100 * kv + ps + window)
     d, group, pps = 32, 8, 20
     block = _pages_per_block(ps, kv, d, jnp.float32, pps) * ps
@@ -283,10 +311,7 @@ def test_kernel_matches_reference_whatever_share_of_a_tile_the_kv_heads_fill(kv,
     pages = sum(-(-n // ps) for n in lengths) + 3
     shape = (2, pages, ps, kv, d)
     pool_k, pool_v = rng.normal(size=shape).astype(np.float32), rng.normal(size=shape).astype(np.float32)
-    free = iter(rng.permutation(pages))
-    tables = np.zeros((len(lengths), pps), np.int32)
-    for lane, n in enumerate(lengths):
-        tables[lane, : -(-n // ps)] = [next(free) for _ in range(-(-n // ps))]
+    tables = _deal_pages(rng, lengths, ps, pps, pages)
     held = np.unique(tables[np.arange(pps)[None] < -(-np.asarray(lengths)[:, None] // ps)])
     draw = lambda *dims: jnp.asarray(rng.normal(size=dims).astype(np.float32))
     q, kn, vn = draw(len(lengths), 1, window, kv * group, d), draw(len(lengths), 1, window, kv, d), draw(len(lengths), 1, window, kv, d)
@@ -303,6 +328,68 @@ def test_kernel_matches_reference_whatever_share_of_a_tile_the_kv_heads_fill(kv,
             jnp.int32(n), scale=1.0 / d**0.5,
         )
         np.testing.assert_allclose(got[lane], np.asarray(want), rtol=2e-5, atol=2e-6, err_msg=f"lane {lane}, length {n}")
+
+
+def test_rows_of_a_partial_block_that_no_copy_filled_may_hold_nan_bits():
+    """What the MXU form introduced: a masked column's weight is an exact
+    zero, and ``0 x NaN`` in a product is NaN, where the VPU fold never
+    touched an unfetched row. Lanes 0 and 1 each fill one of the two buffers
+    with a whole block whose later pages are NaN (their own outputs are NaN
+    and beside the point); the lanes after them fetch a partial block into the
+    same buffers, one page or two of B, and must come out finite and equal to
+    the gather reference: the rows their copies did not fill still hold the
+    NaN bits, in K (masked: their columns lie past the length) and in V
+    (zeroed before the fold)."""
+    rng = np.random.default_rng(7)
+    lengths = (B * PS, B * PS, 1, PS + 3, B * PS + 2, 2 * PS)
+    pool_k, pool_v = _pool(rng)
+    tables = _deal_pages(rng, lengths, PS, PPS, POOL_PAGES)
+    clean_k, clean_v = pool_k.copy(), pool_v.copy()
+    for lane in (0, 1):
+        pool_k[0, tables[lane, 1:B]] = np.nan
+        pool_v[0, tables[lane, 1:B]] = np.nan
+    q, kn, vn = (jnp.stack(x) for x in zip(*(_window(rng, 1, 4 * KV) for _ in lengths)))
+    got = np.asarray(_attend_slots(q, kn, vn, jnp.array(pool_k), jnp.array(pool_v), jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)))
+    assert np.all(np.isnan(got[:2]).any(axis=(1, 2, 3, 4)))  # the poison was read: the buffers hold it
+    for lane in range(2, len(lengths)):
+        want = _reference(
+            q[lane], kn[lane], vn[lane], jnp.asarray(clean_k[0]), jnp.asarray(clean_v[0]), jnp.asarray(tables[lane]),
+            jnp.int32(lengths[lane]), scale=1.0 / D**0.5,
+        )
+        assert np.all(np.isfinite(got[lane])), f"lane {lane}, length {lengths[lane]}"
+        np.testing.assert_allclose(got[lane], np.asarray(want), rtol=2e-5, atol=2e-6, err_msg=f"lane {lane}")
+
+
+# the three serving cells' head geometry: query rows R a slot, rows ps*KV a page
+@pytest.mark.parametrize("cell,nh,kv", [("mistral-7b", 32, 8), ("k-exaone", 64, 8), ("mellum2", 32, 4)])
+def test_kernel_matches_reference_at_the_serving_cells_shapes_in_bf16(cell, nh, kv):
+    """R 32 / 64 / 32 query rows against pages of 128 / 128 / 64 rows, heads of
+    128, pages of 16, bf16 pool and queries as the cells run them: both
+    products take bf16 operands (the probabilities go into the value product
+    in the pool's dtype, as the program's own attention does) with fp32
+    accumulation. Against the gather reference in fp32 on the same bf16
+    values. Tolerance 2^-6 absolute: the output is a bf16 below 4 in
+    magnitude (a convex combination of standard normal values), whose last
+    place is 2^-7 below 2 and 2^-6 below 4; the probabilities' rounding to
+    bf16 (2^-9 relative a weight, signs mixed) lies under it."""
+    rng = np.random.default_rng(nh + kv)
+    ps, d, pps = 16, 128, 12
+    block = _pages_per_block(ps, kv, d, jnp.bfloat16, pps) * ps
+    lengths = (0, 5, ps, block - 1, block, block + ps + 3, pps * ps)
+    pages = sum(-(-n // ps) for n in lengths) + 1
+    draw = lambda *dims: jnp.asarray(rng.normal(size=dims), jnp.bfloat16)
+    pool_k, pool_v = draw(1, pages, ps, kv, d), draw(1, pages, ps, kv, d)
+    tables = _deal_pages(rng, lengths, ps, pps, pages)
+    q, kn, vn = draw(len(lengths), 1, 1, nh, d), draw(len(lengths), 1, 1, kv, d), draw(len(lengths), 1, 1, kv, d)
+    got = _attend_slots(q, kn, vn, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    assert got.dtype == jnp.bfloat16
+    f32 = lambda x: x.astype(jnp.float32)
+    for lane, n in enumerate(lengths):
+        want = _reference(
+            f32(q[lane]), f32(kn[lane]), f32(vn[lane]), f32(pool_k[0]), f32(pool_v[0]), jnp.asarray(tables[lane]),
+            jnp.int32(n), scale=1.0 / d**0.5,
+        )
+        np.testing.assert_allclose(np.asarray(f32(got[lane])), np.asarray(want), rtol=0, atol=2.0**-6, err_msg=f"{cell}, lane {lane}, length {n}")
 
 
 def test_zero_length_attends_only_new_token():
@@ -336,15 +423,16 @@ def test_fallback_reason_interpret_accepts_mosaic_rejects(monkeypatch):
 
 
 @pytest.mark.parametrize("kv,ps,runs", [
-    (8, 16, True), (16, 16, True),  # whole tiles of heads
-    (4, 16, True), (2, 16, True), (1, 16, True), (4, 2, True),  # a tile shared by 2, 4, 8, 2 consecutive tokens
-    (4, 1, False), (2, 2, False),  # a page of fewer tokens than share a tile
-    (3, 16, False), (6, 16, False), (12, 16, False),  # heads that neither fill nor divide a tile
+    (8, 16, True), (16, 16, True), (8, 1, True),  # pages of 128, 256, 8 rows
+    (4, 16, True), (2, 16, True), (1, 16, True), (4, 2, True), (1, 8, True),  # fewer KV heads: the same body on fewer rows
+    (3, 16, True), (6, 16, True), (12, 16, True), (6, 4, True),  # any head count whose page rows come in eights
+    (4, 1, False), (2, 2, False), (3, 4, False), (1, 4, False), (6, 2, False),  # 4, 4, 12, 4, 12 rows a page
 ])
 def test_fallback_reason_names_only_what_mosaic_cannot_tile(kv, ps, runs, monkeypatch):
-    """Compiled (not interpreted), the kernel serves KV heads in whole
-    sublane tiles and, since PR 34, 4, 2 or 1 of them: mellum2's 4 no longer
-    fall back. The reason names what still cannot run."""
+    """Compiled (not interpreted), a page is the matrix ``[ps * KV, D]`` and
+    any KV head count runs whose page rows fill 8-sublane tiles: 3, 6 and 12
+    heads no longer fall back (``tests/test_mosaic_compile.py`` compiles both
+    sides of the gate). The reason names what still cannot run."""
     monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
     reason = paged_kernel_fallback_reason((64, ps, kv, 128), 8 * kv, kv)
     assert (reason is None) == runs, reason
