@@ -208,8 +208,8 @@ def run(args) -> int:
     kv_seq = getattr(args, "max_seq_len", None)
     kv_page = getattr(args, "page_size", None) or 16
     kv_fn = None
-    if config is not None and config.arch in ("llama", "gpt2"):
-        from ..serving.kv_cache import kv_cache_bytes, paged_kv_cache_bytes
+    if config is not None and config.arch in ("llama", "gpt2", "jamba"):
+        from ..serving.kv_cache import kv_cache_bytes, paged_kv_cache_bytes, recurrent_state_bytes
 
         kv_seq = kv_seq or config.max_seq_len
         dense_fn = lambda dtype_bytes: kv_cache_bytes(config, kv_batch, kv_seq, dtype_bytes)  # noqa: E731
@@ -234,6 +234,16 @@ def run(args) -> int:
             f"{_convert_bytes(pool)} + page tables {_convert_bytes(table)} bf16 — "
             f"a request only holds pages for tokens it produced"
         )
+        state = recurrent_state_bytes(config, kv_batch)
+        if state:
+            # a stack with state-space layers: only its attention layers have pages (above), and
+            # every lane carries a convolution tail and a float32 state whatever its context
+            print(
+                f"Recurrent state (batch={kv_batch}): {_convert_bytes(state)} "
+                f"({_convert_bytes(state // kv_batch)} a lane, whatever the context)"
+            )
+            paged_alone = kv_fn
+            kv_fn = lambda dtype_bytes: paged_alone(dtype_bytes) + recurrent_state_bytes(config, kv_batch, dtype_bytes)  # noqa: E731
     elif kv_seq is not None:
         reason = (
             "needs a model config (registry name or config.json)"

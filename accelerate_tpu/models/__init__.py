@@ -4,13 +4,14 @@ from .config import TransformerConfig, get_config, list_models, param_count, reg
 from .exaone_moe import ExaoneMoe
 from .generation import generate
 from .gpt2 import GPT2
+from .jamba import Jamba
 from .llama import Llama
 from .mellum import Mellum
 from .moe import MoEBlock
 from .t5 import T5
 
 
-_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5, "exaone_moe": ExaoneMoe, "mellum": Mellum}
+_ARCHS = {"llama": Llama, "bert": Bert, "gpt2": GPT2, "t5": T5, "exaone_moe": ExaoneMoe, "mellum": Mellum, "jamba": Jamba}
 
 
 def build_model(name: str):

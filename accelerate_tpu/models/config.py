@@ -16,7 +16,7 @@ from typing import Optional
 class TransformerConfig:
     """One config for both decoder (llama-style) and encoder (bert-style) stacks."""
 
-    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5" | "exaone_moe" | "mellum"
+    arch: str = "llama"  # "llama" | "bert" | "gpt2" | "t5" | "exaone_moe" | "mellum" | "jamba"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -60,6 +60,17 @@ class TransformerConfig:
     # ``rope_theta``. Pairs, not a dict: a config is hashed (``rope_by_kind``
     # builds it from the source's nested dict; ``rope_of`` reads it)
     rope_parameters: tuple = ()
+    # state-space layers beside attention layers (arch "jamba", models/jamba.py):
+    # ``layer_types`` names every layer "mamba" or "attention"
+    # (``mamba_layer_types`` builds it from the source's period and offset),
+    # and a Mamba-1 mixer has ``mamba_expand * hidden_size`` channels, each
+    # with ``mamba_d_state`` states, a causal depthwise convolution over
+    # ``mamba_d_conv`` tokens and a step size projected through ``mamba_dt_rank``
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
+    mamba_conv_bias: bool = True
     # encoder-decoder (t5) extras: relative-position bias bucketing and the
     # decoder's BOS (t5 starts generation from the pad token)
     rel_buckets: int = 32
@@ -74,6 +85,14 @@ class TransformerConfig:
     def dim_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def mamba_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.hidden_size // 16)
+
     def rope_of(self, kind: str) -> dict:
         """The rotary parameters of a layer kind, as the source's keys."""
         return dict(dict(self.rope_parameters).get(kind, (("rope_type", "default"), ("rope_theta", self.rope_theta))))
@@ -86,6 +105,13 @@ def rope_by_kind(rope_parameters: dict) -> tuple:
     """``TransformerConfig.rope_parameters`` from a source config's
     ``rope_parameters`` nested by layer kind."""
     return tuple((kind, tuple(sorted(of_kind.items()))) for kind, of_kind in sorted(rope_parameters.items()))
+
+
+def mamba_layer_types(num_layers: int, attn_layer_period: int, attn_layer_offset: int) -> tuple:
+    """``TransformerConfig.layer_types`` of a stack whose layer ``i`` attends
+    iff ``i % attn_layer_period == attn_layer_offset`` and is a Mamba mixer
+    otherwise (the source's two keys)."""
+    return tuple("attention" if i % attn_layer_period == attn_layer_offset else "mamba" for i in range(num_layers))
 
 
 _REGISTRY: dict[str, TransformerConfig] = {
@@ -119,6 +145,13 @@ _REGISTRY: dict[str, TransformerConfig] = {
         arch="llama", vocab_size=1024, hidden_size=128, intermediate_size=256,
         num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=256,
         num_experts=4, moe_top_k=2, moe_capacity_factor=2.0,
+    ),
+    # state-space layers beside attention layers (models/jamba.py): two Mamba
+    # layers either side of an attention layer, one KV head, tied head
+    "jamba-tiny": TransformerConfig(
+        arch="jamba", vocab_size=256, hidden_size=32, intermediate_size=64, num_layers=6,
+        num_heads=4, num_kv_heads=1, head_dim=8, max_seq_len=256, norm_eps=1e-6, tie_embeddings=True,
+        layer_types=mamba_layer_types(6, 4, 1), mamba_d_state=4, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=4,
     ),
     # gpt2 family (decoder, learned positions + LayerNorm + tied embeddings) —
     # the reference's big-model benchmark lineage (GPT-J/NeoX, README.md:31-34)
@@ -328,6 +361,20 @@ def param_count(config: TransformerConfig) -> int:
         if not config.tie_embeddings:
             total += h * v  # lm head
         return total
+    if config.arch == "jamba":
+        c, n, r = config.mamba_d_inner, config.mamba_d_state, config.mamba_rank
+        mixer = (
+            h * 2 * c                                        # in: input and gate
+            + config.mamba_d_conv * c + (c if config.mamba_conv_bias else 0)
+            + c * (r + 2 * n) + r + 2 * n                    # x projection and its three small norms
+            + r * c + c                                      # dt projection with bias
+            + c * n + c                                      # A_log, D
+            + c * h                                          # out
+        )
+        attention = 2 * h * nh * d + 2 * h * nkv * d
+        mamba = sum(kind == "mamba" for kind in config.layer_types)
+        total = v * h + mamba * mixer + (config.num_layers - mamba) * attention + config.num_layers * (3 * h * i + 2 * h) + h
+        return total if config.tie_embeddings else total + h * v
     if config.arch == "gpt2":
         embed = v * h + config.max_seq_len * h  # token + learned positions (tied head)
         per_layer = (

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from .attention import apply_rotary, cached_causal_attention, dense_init, rotary_embedding
 from .config import TransformerConfig, get_config
-from .llama import rms_norm
+from .llama import gated_mlp, rms_norm
 from .moe import dropless_experts
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -197,7 +197,7 @@ class ExaoneMoe:
         of each row chose each held expert; None for a dense layer)."""
         cfg = self.config
         if "router" not in lp:
-            return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"], None
+            return gated_mlp(x, lp), None
         b, s, h = x.shape
         with jax.named_scope("moe.shared"):
             shared = (jax.nn.silu(x @ lp["shared_gate"]) * (x @ lp["shared_up"])) @ lp["shared_down"]

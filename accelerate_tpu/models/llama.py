@@ -34,6 +34,11 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
+def gated_mlp(x: jax.Array, lp: dict, dot=lambda a, w: a @ w) -> jax.Array:
+    """The dense gated-SiLU MLP of one layer's ``w_gate``/``w_up``/``w_down``."""
+    return dot(jax.nn.silu(dot(x, lp["w_gate"])) * dot(x, lp["w_up"]), lp["w_down"])
+
+
 def decoder_layer(
     cfg: TransformerConfig,
     h: jax.Array,  # [B, S, H]
@@ -101,8 +106,7 @@ def decoder_layer(
             top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
         )
     else:
-        gated = jax.nn.silu(dot(x, lp["w_gate"])) * dot(x, lp["w_up"])
-        mlp_out = dot(gated, lp["w_down"])
+        mlp_out = gated_mlp(x, lp, dot)
     if dropout_rngs[1] is not None:
         mlp_out = dropout(mlp_out, dropout_rate, dropout_rngs[1])
     h = h + mlp_out

@@ -45,6 +45,7 @@ from .kv_cache import (
     bucket_for,
     kv_cache_bytes,
     paged_kv_cache_bytes,
+    recurrent_state_bytes,
     prefill_buckets,
 )
 from .loadgen import (
@@ -89,6 +90,7 @@ __all__ = [
     "make_mixed_prompts",
     "make_prompts",
     "paged_kv_cache_bytes",
+    "recurrent_state_bytes",
     "pages_for",
     "params_from_streamed",
     "quantized_resident_params",

@@ -233,6 +233,7 @@ class _StepBooks:
         self.phases: dict[str, float] = {}
         self.landed: Optional[int] = None  # the program whose tokens it delivered
         self.tokens = self.context = 0
+        self.lanes = 0  # the active lanes of the program it dispatched
         self.experts: dict[str, int] = {}
 
     def close(self, phase: str) -> None:
@@ -340,13 +341,24 @@ class ServingEngine:
         # giving the window layers' rings; its full layers alone are paged
         init_window = getattr(model, "init_window_cache", None)
         if init_window is not None:
-            self._refuse_for_window_layers(
+            self._refuse_for(
+                "sliding-window layers",
                 speculative is not None and "speculative decoding: a rejected window cannot be rolled back out of a ring",
                 prefix_sharing and "prefix sharing: a shared page holds the full layers' K/V, and no ring to resume from",
             )
+        # the third kind: recurrent layers keep a convolution tail and a state
+        # a lane, whatever the context; the model says so by giving them
+        init_state = getattr(model, "init_state_cache", None)
+        if init_state is not None:
+            self._refuse_for(
+                "recurrent state",
+                speculative is not None and "speculative decoding: a rejected window cannot be rolled back out of a recurrent state",
+                prefix_sharing and "prefix sharing: a shared page holds the attention layers' K/V, and no state to resume from",
+            )
+        if init_window is not None or init_state is not None:
             self._init_cache = model.init_kv_pool
         if prefix_sharing is None:
-            prefix_sharing = init_window is None
+            prefix_sharing = init_window is None and init_state is None
         # routed experts over a held share (models/moe.py:dropless_experts):
         # [sparse layers, held experts], the shape of what the model's decode
         # protocol counts under ``moe_held`` and the decode step's fetch brings
@@ -359,7 +371,7 @@ class ServingEngine:
             num_pages=num_pages, dtype=dtype, prefix_entries=prefix_cache_entries,
             # beside the rings: the tokens each held expert was chosen by, a
             # sparse layer, and the (layer, held expert) pairs hit
-            init_window=init_window, counters=self._held_counts + 1,
+            init_window=init_window, counters=self._held_counts + 1, init_state=init_state,
         )
         base_buckets = tuple(buckets) if buckets is not None else prefill_buckets(max_len - 1)
         if prefill_chunk is not None:
@@ -403,6 +415,17 @@ class ServingEngine:
                     "paged decode kernel not engaged — decoding through the "
                     f"gather program: {self._kernel_fallback_reason}"
                 )
+        # the recurrent layers' scan: the Pallas kernel over the stacked state
+        # where Mosaic takes its shape (ops/ssm_scan.py), else the plain scan
+        self._scan_fallback_reason: Optional[str] = None
+        self._use_scan_kernel = False
+        if self.cache.stateful:
+            from ..ops.ssm_scan import ssm_kernel_fallback_reason
+
+            self._scan_fallback_reason = (
+                ssm_kernel_fallback_reason(self.cache.extras.ssm.shape) if self.use_kernels else "use_kernels is off"
+            )
+            self._use_scan_kernel = self._scan_fallback_reason is None
         self._kernels_reported = False  # one {"kind": "kernels"} record per engine
         self.scheduler = ContinuousBatchingScheduler(num_slots, max_queue=max_queue)
         # next input token per slot, where the host knows it; negative where it
@@ -494,6 +517,8 @@ class ServingEngine:
         self._parked: dict[int, dict] = {}
         if self._experts_shape is not None:
             self.stats.moe_tokens_by_held_expert = np.zeros((model.experts_here,), np.int64)
+        if self.stateful:
+            self.stats.ssm_layers = int(self.cache.extras.ssm.shape[1])
         # the last decode program's `fetched`, on the device: the tokens, and
         # behind them what a model with routed experts counts (two sets of
         # held counts and the pairs hit)
@@ -506,13 +531,30 @@ class ServingEngine:
         the page pool (``serving/paging.py``)."""
         return self.cache.windowed
 
+    @property
+    def stateful(self) -> bool:
+        """Whether the model has recurrent (state-space) layers, whose state a
+        lane carries beside its pages (``serving/paging.py``)."""
+        return self.cache.stateful
+
     @staticmethod
-    def _refuse_for_window_layers(*reasons) -> None:
+    def _refuse_for(what: str, *reasons) -> None:
         """Raise for the first thing asked of the engine that a model with
-        window layers cannot be given; each ``reason`` is falsy or names it."""
+        ``what`` (window layers' rings, recurrent layers' state: what belongs
+        to a lane and lies in no page) cannot be given; each ``reason`` is
+        falsy or names it."""
         for reason in reasons:
             if reason:
-                raise NotImplementedError(f"a model with sliding-window layers cannot be served with {reason}")
+                raise NotImplementedError(f"a model with {what} cannot be served with {reason}")
+
+    def _scan_hook(self) -> dict:
+        """What the programs add to a stateful model's cache: the scan kernel
+        as its ``scan`` hook, or nothing (the model's plain scan)."""
+        if not self._use_scan_kernel:
+            return {}
+        from ..ops.ssm_scan import ssm_scan
+
+        return {"scan": ssm_scan}
 
     # -- jitted programs (dot-keyed: shared cache with generate()) ----------
 
@@ -531,6 +573,30 @@ class ServingEngine:
     # every reachable page stays FINITE (0 × NaN = NaN): inactive/probe lanes
     # therefore write sanitized zeros to the null page, and quarantine scrubs
     # freed pages on device.
+
+    @staticmethod
+    def _rows_scattered(pool, rows, wpage, woff):
+        """A decode step's write-back: lane ``s``'s row ``rows[s]`` ``[S, L,
+        KV, D]`` into ``pool`` ``[L, P, ps, KV, D]`` at ``(wpage[s],
+        woff[s])``. Inactive and probe lanes come with the null page, offset
+        0 and a zero row."""
+        return pool.at[:, wpage, woff].set(jnp.moveaxis(rows, 0, 1))
+
+    @staticmethod
+    def _rows_through_pages(pool, rows, wpage, woff):
+        """The same pool as :meth:`_rows_scattered` leaves
+        (``tests/test_serving.py`` holds the two equal), by another road: each
+        lane's page is read, its one row replaced and the page written back
+        whole, through the view whose faces fill tiles (as the prefill writes
+        its pages). Inactive lanes all rewrite the null page with what it
+        held, a zero row over zeros."""
+        from ..ops.paged_attention import pool_tile_view
+
+        view, ps = pool_tile_view(pool), pool.shape[2]
+        pages = jnp.take(view, wpage, axis=1).reshape(pool.shape[0], -1, *pool.shape[2:])  # [L, S, ps, KV, D]
+        hit = (jnp.arange(ps)[None, :] == woff[:, None])[None, :, :, None, None]
+        pages = jnp.where(hit, jnp.moveaxis(rows, 0, 1)[:, :, None], pages)
+        return view.at[:, wpage].set(pages.reshape(pages.shape[:2] + view.shape[2:])).reshape(pool.shape)
 
     @staticmethod
     def _gathered_view(pool_k, pool_v, row, length, layer=None):
@@ -559,7 +625,7 @@ class ServingEngine:
     def _paged_decode_program(self):
         """The decode step over every lane: ``decode_step(params, pk, pv,
         extras, prev, tokens, lengths, active, tables, keys) -> (fetched, ok,
-        pk, pv, *extras)``, with ``fetched`` the lanes' tokens ``[S]``.
+        pk, pv, extras)``, with ``fetched`` the lanes' tokens ``[S]``.
         ``prev`` is the last decode program's ``fetched``, still on the
         device: a lane whose ``tokens`` entry is negative takes its input
         token from there (the host has not read it yet: ``step()`` dispatches
@@ -578,8 +644,14 @@ class ServingEngine:
         the tokens in the one fetched vector, and behind them what the prefill
         programs since the last step counted (``counts``, which they pass from
         one to the next on the device): ``[S + sparse layers * held experts +
-        that and one more]`` int32."""
+        that and one more]`` int32. Where it holds recurrent state, ``conv``
+        and ``ssm`` ``[S, ...]``, each lane's own rides the slot axis into the
+        protocol with ``real`` = 1 for an active lane and 0 for any other, and
+        comes back whole: the model advances an active lane's by its token and
+        leaves every other lane's as it was (a lane between the chunks of its
+        prefill is inactive at length 0, and holds the chunks' state)."""
         fwc, sample = self._fwc, self._sample
+        scan_hook = self._scan_hook()
         ps = self.cache.page_size
         gathered = self._gathered_view
         use_kernel = self._use_decode_kernel
@@ -587,14 +659,19 @@ class ServingEngine:
         def build():
             def decode_step(params, pk, pv, extras, prev, tokens, lengths, active, tables, keys):
                 tokens = jnp.where(tokens < 0, prev[: tokens.shape[0]], tokens)
-                rings = extras[:2]  # (wk, wv), or nothing
+                rings = (extras.wk, extras.wv) if extras.wk is not None else ()
+                state = (extras.conv, extras.ssm, active) if extras.ssm is not None else ()
                 kinds = ("k", "v", "wk", "wv")[: 2 + len(rings)]
 
-                def beside(ring):  # this lane's rings, as the protocol takes them
-                    return {kind: r[:, None] for kind, r in zip(kinds[2:], ring)}
+                def beside(more):  # this lane's rings and state, as the protocol takes them
+                    lane = {kind: r[:, None] for kind, r in zip(kinds[2:], more)}
+                    if state:
+                        conv, ssm, live = more[len(rings):]
+                        lane.update(conv=conv[None], ssm=ssm[None], real=live.astype(jnp.int32), **scan_hook)
+                    return lane
 
-                def counted(nc):  # what a model with rings counts of its routed experts
-                    return (nc["moe_held"],) if rings else ()
+                def counted(nc):  # what a model with rings counts of its routed experts, and a lane's state advanced
+                    return ((nc["moe_held"],) if rings else ()) + ((nc["conv"][0], nc["ssm"][0]) if state else ())
 
                 if use_kernel:
                     # the Pallas path (ops/paged_attention.py): attention
@@ -633,9 +710,12 @@ class ServingEngine:
                                 for kind, r in zip(kinds[2:], ring)]
                         return sample(logits, key)[0], ok, *new, *counted(nc)
 
-                nxt, ok, fk, fv, *of_rings = jax.vmap(one_slot, in_axes=(0, 0, 0, 0) + (1,) * len(rings))(
-                    tokens, tables, lengths, keys, *rings
+                nxt, ok, fk, fv, *of_rings = jax.vmap(one_slot, in_axes=(0, 0, 0, 0) + (1,) * len(rings) + (0,) * len(state))(
+                    tokens, tables, lengths, keys, *rings, *state
                 )
+                if state:
+                    *of_rings, conv, ssm = of_rings
+                    extras = extras._replace(conv=conv, ssm=ssm)
                 # write-back: active slots append at (table[length // ps],
                 # length % ps); inactive and probe lanes redirect to the null
                 # page — with ZEROED values, so the shared null page stays
@@ -646,15 +726,19 @@ class ServingEngine:
                 lane = active.reshape((-1,) + (1,) * (fk.ndim - 1))
                 fk = jnp.where(lane, fk.astype(pk.dtype), jnp.zeros((), pk.dtype))
                 fv = jnp.where(lane, fv.astype(pv.dtype), jnp.zeros((), pv.dtype))
-                pk = pk.at[:, wpage, woff].set(jnp.moveaxis(fk, 0, 1))
-                pv = pv.at[:, wpage, woff].set(jnp.moveaxis(fv, 0, 1))
+                # one KV head: a token's row is a fraction of a tile, and XLA relays
+                # the WHOLE pool out around a scatter of such rows and back (four
+                # copies of it a step, from the compiled text for a described v5e;
+                # two and more heads have none), so the row goes in through its page
+                write = ServingEngine._rows_scattered if pk.shape[3] > 1 else ServingEngine._rows_through_pages
+                pk, pv = write(pk, fk, wpage, woff), write(pv, fv, wpage, woff)
                 if not rings:
-                    return jnp.where(active, nxt, jnp.int32(0)), ok, pk, pv
+                    return jnp.where(active, nxt, jnp.int32(0)), ok, pk, pv, extras
                 # the rings' write-back: inactive and probe lanes leave their
                 # ring as it was: a lane in the middle of a chunked prefill is
                 # inactive at length 0, and its ring holds the chunks' live
                 # K/V (scrubbed of poison where a lane is quarantined)
-                (wk, wv, counts), (rk, rv, held) = extras, of_rings
+                (wk, wv, counts), (rk, rv, held) = extras[:3], of_rings
                 # one entry of each active lane's ring, written as ONE select over the
                 # rings where they lie: a scatter (or an update slice) over (lane, entry)
                 # has XLA relay the stacked rings out entries-major and back, four copies
@@ -667,14 +751,14 @@ class ServingEngine:
                 fetched = jnp.concatenate(
                     [jnp.where(active, nxt, jnp.int32(0)), held.reshape(-1).astype(jnp.int32), counts]
                 )
-                return fetched, ok, pk, pv, wk, wv, jnp.zeros_like(counts)
+                return fetched, ok, pk, pv, extras._replace(wk=wk, wv=wv, counts=jnp.zeros_like(counts))
 
             donate = (1, 2, 3) if self._donate else ()
             return jax.jit(decode_step, donate_argnums=donate)
 
         return self._jit(
             ("serve_paged_decode", self.cache.num_slots, self.cache.view_len, ps,
-             self.temperature, self._donate, use_kernel),
+             self.temperature, self._donate, use_kernel, self._use_scan_kernel),
             build,
         )
 
@@ -795,7 +879,7 @@ class ServingEngine:
         view is the full gathered table, so a shared/chunked prefix is
         attended exactly as a monolithic prefill would — split points change
         nothing but which pages get written. ``prefill(params, ids, pk, pv,
-        extras, row, start, real, slot) -> (pk, pv, *extras)``, with
+        extras, row, start, real, slot) -> (pk, pv, extras)``, with
         ``extras`` as in the decode step. Where it holds rings, lane ``slot``'s
         stand beside its gathered pages: the model attends the ring (what the
         window layers kept before ``start``) and returns it holding the last
@@ -803,24 +887,33 @@ class ServingEngine:
         ``counts`` adds up, from program to program, the real tokens each held
         expert was chosen by a layer, then the (layer, held expert) pairs a
         program hit: the next decode step's fetch brings them home. Where it
-        is empty, ``real`` and ``slot`` are not read, and ``jit`` leaves them
-        out of the program."""
+        holds recurrent state, lane ``slot``'s stands beside its pages too: a
+        span at ``start`` 0 starts it from zeros (the reset of a reused lane),
+        a later one resumes from it, and it comes back advanced over the
+        ``real`` tokens alone. Where ``extras`` is empty, ``real`` and ``slot``
+        are not read, and ``jit`` leaves them out of the program."""
         from ..ops.paged_attention import pool_tile_view
 
         fwc = self._fwc
         ps = self.cache.page_size
         n_pages = span // ps
         gathered = self._gathered_view
+        scan_hook = self._scan_hook()
 
         def build():
             def prefill(params, ids, pk, pv, extras, row, start, real, slot):
                 cache = gathered(pk, pv, row, start)
-                windowed = len(extras) > 0  # the pytree's structure: known as the program is traced
+                windowed, stateful = extras.wk is not None, extras.ssm is not None  # the pytree's structure: known as the program is traced
                 if windowed:
-                    wk, wv, counts = extras
+                    wk, wv, counts = extras[:3]
                     cache.update(
                         wk=jax.lax.dynamic_index_in_dim(wk, slot, axis=1), wv=jax.lax.dynamic_index_in_dim(wv, slot, axis=1),
                         real=real,
+                    )
+                if stateful:
+                    cache.update(
+                        conv=jax.lax.dynamic_index_in_dim(extras.conv, slot, axis=0), ssm=jax.lax.dynamic_index_in_dim(extras.ssm, slot, axis=0),
+                        real=real, **scan_hook,
                     )
                 _, nc = fwc(params, ids, cache)
                 new_k = jax.lax.dynamic_slice_in_dim(nc["k"][:, 0], start, span, axis=1)
@@ -838,15 +931,20 @@ class ServingEngine:
                     wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)
                     held = nc["moe_held"].reshape(-1).astype(jnp.int32)
                     counts = counts + jnp.concatenate([held, jnp.count_nonzero(held).astype(jnp.int32)[None]])
-                    extras = (wk, wv, counts)
-                return pk, pv, *extras
+                    extras = extras._replace(wk=wk, wv=wv, counts=counts)
+                if stateful:
+                    extras = extras._replace(
+                        conv=jax.lax.dynamic_update_index_in_dim(extras.conv, nc["conv"][0].astype(extras.conv.dtype), slot, axis=0),
+                        ssm=jax.lax.dynamic_update_index_in_dim(extras.ssm, nc["ssm"][0], slot, axis=0),
+                    )
+                return pk, pv, extras
 
             donate = (2, 3, 4) if self._donate else ()
             return jax.jit(prefill, donate_argnums=donate)
 
         return self._jit(
             ("serve_paged_prefill", span, self.cache.num_slots, self.cache.view_len,
-             ps, self._donate),
+             ps, self._donate, self._use_scan_kernel),
             build,
         )
 
@@ -964,22 +1062,23 @@ class ServingEngine:
             build,
         )
 
-    def _ring_scrub_program(self):
-        """Zero one lane's rings (a model with window layers): quarantine's
-        scrub of the second kind of cache. Compiled on the first quarantine."""
+    def _lane_scrub_program(self):
+        """Zero what one lane carries beside its pages (its rings, its
+        recurrent state): quarantine's scrub of the second and third kinds of
+        cache. Compiled on the first quarantine."""
 
         def build():
-            def scrub(wk, wv, slot):
-                zeros = jnp.zeros((wk.shape[0], 1) + wk.shape[2:], wk.dtype)
-                return (
-                    jax.lax.dynamic_update_slice_in_dim(wk, zeros, slot, axis=1),
-                    jax.lax.dynamic_update_slice_in_dim(wv, zeros.astype(wv.dtype), slot, axis=1),
-                )
+            def scrub(extras, slot):
+                def zeroed(array, axis):
+                    zeros = jnp.zeros(array.shape[:axis] + (1,) + array.shape[axis + 1:], array.dtype)
+                    return jax.lax.dynamic_update_slice_in_dim(array, zeros, slot, axis=axis)
 
-            donate = (0, 1) if self._donate else ()
+                return extras._replace(**{name: zeroed(array, axis) for name, (array, axis) in extras.by_lane.items()})
+
+            donate = (0,) if self._donate else ()
             return jax.jit(scrub, donate_argnums=donate)
 
-        return self._jit(("serve_ring_scrub", self.cache.num_slots, self._donate), build)
+        return self._jit(("serve_lane_scrub", self.cache.num_slots, self._donate), build)
 
     # -- request intake ----------------------------------------------------
 
@@ -1032,7 +1131,7 @@ class ServingEngine:
             # state whenever this engine is a disaggregated pool member:
             # compile both now against the null page (reading it is free,
             # and re-inserting its own zeros changes nothing)
-            if not self.windowed:  # no handoff of a ring: adopt_kv refuses
+            if not self.cache.extras.by_lane:  # no handoff of a ring or a state: adopt_kv refuses
                 kb, vb = self.extract_pages([0])
                 self.cache.k, self.cache.v = self._page_insert_program()(
                     self.cache.k, self.cache.v, kb[0], vb[0], np.int32(0)
@@ -1122,8 +1221,13 @@ class ServingEngine:
             raise ValueError("prompt must hold at least one token")
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        self._refuse_for_window_layers(
+        self._refuse_for(
+            "sliding-window layers",
             prefill_only and self.windowed and "prefill_only: parking frees the lane, and the rings go with the lane"
+        )
+        self._refuse_for(
+            "recurrent state",
+            prefill_only and self.stateful and "prefill_only: parking frees the lane, and the recurrent state goes with the lane"
         )
         prefill_len = prompt.size - 1
         # longer than the largest bucket is served only in chunks, where the
@@ -1498,8 +1602,8 @@ class ServingEngine:
                 )
                 if int(self.spec.draft_len[slot]) == request.prefilled:
                     self.spec.draft_len[slot] = request.prefilled + take
+            self.stats.record_prefill(span, take, position=request.prefilled)
             request.prefilled += take
-            self.stats.record_prefill(span, take)
             if chunked_span:
                 self.stats.record_prefill_chunk()
             if request.prefilled >= prefill_len:
@@ -2310,7 +2414,8 @@ class ServingEngine:
             )
             self.cache.put(*handed_back)
             self._prev = flight.fetched
-            self.stats.record_dispatch(overlapped)
+            self.stats.record_dispatch(overlapped, lanes=len(flight.lanes))
+            books.lanes = len(flight.lanes)
             for slot in flight.lanes:
                 request = flight.requests[slot]
                 request.in_flight += 1
@@ -2397,14 +2502,16 @@ class ServingEngine:
         (warm-up's compiles are not a serving step's time) and what only the
         end of a step knows onto its ``engine.step`` span: the tokens it
         delivered, of which program (``landed``; ``experts``: a model with
-        routed experts adds ``assignments_held``, ``experts_hit``)."""
+        routed experts adds ``assignments_held``, ``experts_hit``), and the
+        active lanes of the program it dispatched (``lanes``: times the
+        recurrent layers, ``stats.ssm_decode_tokens``)."""
         if not self._warming:
             self.stats.record_phases(number, books.phases, books.stamp - books.t0)
         if root is not None:
             landed = {} if books.landed is None else {"landed": books.landed}
             root.set_metadata(
                 tokens=books.tokens, context=books.context, decoded=int(books.landed is not None),
-                **landed, **books.experts,
+                lanes=books.lanes, **landed, **books.experts,
             )
 
     def _deliver(self, books: _StepBooks, flight: _Flight, tokens_mat, emit, finite, drafted, live) -> None:
@@ -2506,11 +2613,10 @@ class ServingEngine:
                         self.spec.scrub_pages(freed)
                 if self.spec is not None:
                     self.spec.draft_len[slot] = 0
-                if self.windowed:
-                    # the lane's rings hold the poison too, and a masked
-                    # entry's 0 x NaN would fail every probe
-                    wk, wv, counts = self.cache.extras
-                    self.cache.extras = (*self._ring_scrub_program()(wk, wv, np.int32(slot)), counts)
+                if self.cache.extras.by_lane:
+                    # the lane's rings and state hold the poison too, and a
+                    # masked entry's 0 x NaN would fail every probe
+                    self.cache.extras = self._lane_scrub_program()(self.cache.extras, np.int32(slot))
                 self._pending[slot] = 0
                 self._probe_failures[slot] = 0
                 self.stats.record_quarantine()
@@ -2707,8 +2813,13 @@ class ServingEngine:
         never compiles in steady state whatever set of pages moves. All n
         reads dispatch before the first host copy blocks, so the transfers
         pipeline instead of paying n serialized round-trips."""
-        self._refuse_for_window_layers(
+        self._refuse_for(
+            "sliding-window layers",
             self.windowed and "extract_pages: a handoff moves pages, and the window layers' rings are in none"
+        )
+        self._refuse_for(
+            "recurrent state",
+            self.stateful and "extract_pages: a handoff moves pages, and the recurrent layers' state is in none"
         )
         self._land()
         program = self._page_extract_program()
@@ -2744,8 +2855,13 @@ class ServingEngine:
         (fatal: a retry cannot fix it); exhausted lanes/pages raise
         :class:`QueueFull` (transient: the router retries or falls back to
         re-prefill). Returns the adopted request id."""
-        self._refuse_for_window_layers(
+        self._refuse_for(
+            "sliding-window layers",
             self.windowed and "adopt_kv: a handoff moves pages, and the window layers' rings are in none"
+        )
+        self._refuse_for(
+            "recurrent state",
+            self.stateful and "adopt_kv: a handoff moves pages, and the recurrent layers' state is in none"
         )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         length = int(layout["length"])
@@ -3066,7 +3182,9 @@ class ServingEngine:
 
             gate_reports([report], contracts_dir)
         if write_record and self.telemetry is not None:
-            self.telemetry.write_record("analysis", {"analysis": report.to_dict()})
+            cache = self.cache
+            held = {"pages": int(cache.k.nbytes + cache.v.nbytes), "a_lane": cache.lane_bytes, "state_a_lane": cache.state_bytes_per_slot}
+            self.telemetry.write_record("analysis", {"analysis": report.to_dict(), "cache_bytes": held})
         return report
 
     # -- telemetry ---------------------------------------------------------
@@ -3118,6 +3236,10 @@ class ServingEngine:
             # a model with sliding-window layers: they attend a ring a slot, under XLA
             "window_attention": "xla_ring" if self.windowed else None,
             "decode_fallback_reason": self._kernel_fallback_reason,
+            # a model with recurrent layers: their scan over the stacked state
+            "state_scan": ("pallas" if self._use_scan_kernel else "xla_scan") if self.stateful else None,
+            "state_scan_fallback_reason": self._scan_fallback_reason,
+            "state_bytes_per_slot": self.cache.state_bytes_per_slot,
             "quant_matmul": quant_mode,
             "quant_fallback_reason": fallbacks[0] if fallbacks else None,
             "quant_fallback_leaves": len(fallbacks),

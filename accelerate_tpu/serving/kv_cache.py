@@ -48,6 +48,13 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
     raise ValueError(f"prefill length {n} exceeds largest bucket {buckets[-1]}")
 
 
+def kv_layers(config) -> int:
+    """The layers that cache keys and values: all of them, but for a stack
+    whose ``layer_types`` names recurrent ("mamba") layers, which keep a
+    state a sequence instead (:func:`recurrent_state_bytes`)."""
+    return config.num_layers - sum(kind == "mamba" for kind in config.layer_types)
+
+
 def kv_cache_bytes(
     config, batch: int, max_seq_len: Optional[int] = None, dtype_bytes: int = 2
 ) -> int:
@@ -58,8 +65,20 @@ def kv_cache_bytes(
     pool's sizing is :func:`paged_kv_cache_bytes`."""
     seq = max_seq_len if max_seq_len is not None else config.max_seq_len
     return int(
-        2 * config.num_layers * config.kv_heads * config.dim_per_head * seq * batch * dtype_bytes
+        2 * kv_layers(config) * config.kv_heads * config.dim_per_head * seq * batch * dtype_bytes
     )
+
+
+def recurrent_state_bytes(config, batch: int, dtype_bytes: int = 2) -> int:
+    """Device bytes of the recurrent (state-space) layers' state for ``batch``
+    sequences (serving lanes), whatever their lengths: a layer and sequence, a
+    float32 state ``d_state × d_inner`` and a convolution tail of ``d_conv - 1``
+    inputs in the activations' type. 0 for a stack with no such layer."""
+    layers = sum(kind == "mamba" for kind in config.layer_types)
+    if not layers:
+        return 0
+    c = config.mamba_d_inner
+    return int(batch * layers * (config.mamba_d_state * c * 4 + (config.mamba_d_conv - 1) * c * dtype_bytes))
 
 
 def paged_kv_cache_bytes(
@@ -86,7 +105,7 @@ def paged_kv_cache_bytes(
     if num_pages is None:
         num_pages = batch * pages_per_seq + 1
     pool = int(
-        2 * config.num_layers * config.kv_heads * config.dim_per_head
+        2 * kv_layers(config) * config.kv_heads * config.dim_per_head
         * num_pages * page_size * dtype_bytes
     )
     table = int(batch * pages_per_seq * 4)

@@ -44,8 +44,19 @@ the engine refuses those for such a model, by name. What a lane carries
 beside its pages is the cache's to say, as ``extras``: the pytree the engine's
 decode and prefill programs take after the pool and hand back. It is empty
 for a model of one kind, so that its programs have no such argument; for this
-one it is ``(wk, wv, counts)``, the rings and the int32 counters the programs
-pass along with them.
+one it holds ``wk``, ``wv`` and ``counts``, the rings and the int32 counters
+the programs pass along with them.
+
+A model with state-space layers (``models/jamba.py``) gives the manager the
+third kind: the RECURRENT layers keep, a lane, a convolution tail and a float32
+state, ``conv`` ``[num_slots, Lm, (K - 1) * C]`` and ``ssm`` ``[num_slots, Lm,
+N, C]`` — a fixed cost a LANE whatever its context, so that slots, not tokens,
+fill the memory. Like a ring it belongs to its lane: nothing of it lies in a
+page, so prefix sharing, parking and handoff are refused for such a model too,
+and a speculative window could not be rolled back out of it. ``extras`` is one
+named structure, :class:`LaneExtras`: each of rings (``wk``, ``wv``), counters
+(``counts``) and state (``conv``, ``ssm``) is there or is ``None``, and what is
+``None`` is no argument of a compiled program.
 
 Copy-on-write: sharing is page-aligned (full pages only — the unaligned tail
 of a shared prefix is recomputed, never half-shared), so in steady state a
@@ -60,7 +71,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -81,6 +92,24 @@ def paged_buckets(buckets: Sequence[int], page_size: int, capacity: int) -> tupl
     if not rounded:
         raise ValueError(f"no usable prefill buckets in {tuple(buckets)}")
     return tuple(rounded)
+
+
+class LaneExtras(NamedTuple):
+    """What a lane carries beside its pages, by name; a member that the model
+    has no use for is ``None`` (no leaf of the pytree, so no argument of a
+    program). The order is the programs' argument order."""
+
+    wk: Any = None  # the window layers' rings [Lw, num_slots, KV, window, D]
+    wv: Any = None
+    counts: Any = None  # int32 counters the programs pass along (routed experts)
+    conv: Any = None  # the recurrent layers' convolution tails [num_slots, Lm, (K - 1) * C]
+    ssm: Any = None  # and their float32 states [num_slots, Lm, N, C]
+
+    @property
+    def by_lane(self) -> dict:
+        """The members that hold something a LANE, with the axis the lanes lie on."""
+        held = {"wk": (self.wk, 1), "wv": (self.wv, 1), "conv": (self.conv, 0), "ssm": (self.ssm, 0)}
+        return {name: (array, axis) for name, (array, axis) in held.items() if array is not None}
 
 
 class PageAllocator:
@@ -271,8 +300,10 @@ class PagedKVCache:
     shipped into the jitted programs per step; all device shapes are fixed at
     construction. ``init_window(num_slots)`` (a model with window layers)
     adds the second kind of cached layer: ``wk``/``wv``, one ring a slot, and
-    ``counters`` int32 counters beside them, together ``extras`` (see the
-    module docstring); ``windowed`` says whether there is one."""
+    ``counters`` int32 counters beside them; ``init_state(num_slots)`` (a
+    model with recurrent layers) the third, ``conv``/``ssm``; together
+    ``extras`` (:class:`LaneExtras`, see the module docstring). ``windowed``
+    and ``stateful`` say which there are."""
 
     def __init__(
         self,
@@ -285,6 +316,7 @@ class PagedKVCache:
         prefix_entries: int = 256,
         init_window=None,
         counters: int = 0,
+        init_state=None,
     ):
         import jax.numpy as jnp
 
@@ -301,10 +333,13 @@ class PagedKVCache:
         dtype = dtype if dtype is not None else jnp.bfloat16
         cache = init_cache(num_pages, page_size, dtype=dtype)
         self.k, self.v = cache["k"], cache["v"]
-        self.extras: tuple = ()
+        self.extras = LaneExtras()
         if init_window is not None:
             rings = init_window(num_slots, dtype=dtype)
-            self.extras = (rings["wk"], rings["wv"], jnp.zeros((counters,), jnp.int32))
+            self.extras = self.extras._replace(wk=rings["wk"], wv=rings["wv"], counts=jnp.zeros((counters,), jnp.int32))
+        if init_state is not None:
+            state = init_state(num_slots, dtype=dtype)
+            self.extras = self.extras._replace(conv=state["conv"], ssm=state["ssm"])
         self.num_pages = num_pages
         self.num_slots = num_slots
         self.max_len = max_len
@@ -319,26 +354,45 @@ class PagedKVCache:
 
     # -- capacity ------------------------------------------------------------
 
-    def put(self, k, v, *extras) -> None:
+    def put(self, k, v, extras: LaneExtras) -> None:
         """Take back what a decode or prefill program hands back: the pools
-        and whatever else a lane carries, in ``extras``' order."""
+        and whatever else a lane carries."""
         self.k, self.v, self.extras = k, v, extras
 
     @property
     def windowed(self) -> bool:
-        return bool(self.extras)
+        return self.extras.wk is not None
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a lane carries recurrent state (a model with state-space layers)."""
+        return self.extras.ssm is not None
 
     @property
     def wk(self):
-        return self.extras[0]
+        return self.extras.wk
 
     @property
     def wv(self):
-        return self.extras[1]
+        return self.extras.wv
+
+    def _lane_bytes(self, *names) -> int:
+        return sum(int(array.nbytes) for name, (array, _) in self.extras.by_lane.items() if name in names) // self.num_slots
+
+    @property
+    def lane_bytes(self) -> int:
+        """Device bytes a LANE holds whatever its context: its rings and its
+        recurrent state (0 for a model of pages alone)."""
+        return self._lane_bytes("wk", "wv", "conv", "ssm")
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Of ``lane_bytes``, the recurrent layers' convolution tail and state."""
+        return self._lane_bytes("conv", "ssm")
 
     @property
     def nbytes(self) -> int:
-        return int(self.k.nbytes + self.v.nbytes) + sum(int(ring.nbytes) for ring in self.extras[:2])
+        return int(self.k.nbytes + self.v.nbytes) + self.lane_bytes * self.num_slots
 
     @property
     def page_bytes(self) -> int:

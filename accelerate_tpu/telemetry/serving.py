@@ -149,6 +149,14 @@ class ServingStats:
         self.moe_tokens_by_held_expert: Optional[np.ndarray] = None
         self.attended_window_tokens = 0  # cached tokens the decoded tokens attended, window layers
         self.attended_full_tokens = 0  # and full layers
+        # recurrent (state-space) layers (models/jamba.py): how many the model
+        # has (0: none, and the counters below stay out of the snapshot), and
+        # the scan's work, cut where the programs are dispatched
+        self.ssm_layers = 0
+        self.ssm_decode_tokens = 0  # (active lane, recurrent layer) of every decode program dispatched
+        self.ssm_prefill_tokens = 0  # (real token, recurrent layer) of every prefill program
+        self.ssm_prefill_programs = 0
+        self.ssm_state_resets = 0  # prefill programs that started a lane's state from zeros (a span at position 0)
 
     # -- intake ------------------------------------------------------------
 
@@ -182,10 +190,16 @@ class ServingStats:
     def record_watchdog_trip(self) -> None:
         self.watchdog_trips += 1
 
-    def record_prefill(self, bucket: int, tokens: int) -> None:
-        """One prefill program: ``bucket`` positions computed for ``tokens`` of a prompt."""
+    def record_prefill(self, bucket: int, tokens: int, position: int = 0) -> None:
+        """One prefill program: ``bucket`` positions computed for ``tokens`` of
+        a prompt, after ``position`` cached ones (a model with recurrent
+        layers: each runs its scan over the tokens, from zeros at position 0)."""
         self.prefill_tokens += bucket
         self.prefill_tokens_real += tokens
+        if self.ssm_layers:
+            self.ssm_prefill_programs += 1
+            self.ssm_prefill_tokens += tokens * self.ssm_layers
+            self.ssm_state_resets += position == 0 and tokens > 0
 
     def record_admission(self, wait_s: float) -> None:
         self.admissions += 1
@@ -295,9 +309,11 @@ class ServingStats:
         self.attended_window_tokens += window
         self.attended_full_tokens += full
 
-    def record_dispatch(self, overlapped: bool) -> None:
-        """One decode program enqueued, with the one before it landed or not."""
+    def record_dispatch(self, overlapped: bool, lanes: int = 0) -> None:
+        """One decode program enqueued, with the one before it landed or not,
+        over ``lanes`` active lanes."""
         self.decode_overlapped += overlapped
+        self.ssm_decode_tokens += lanes * self.ssm_layers
 
     def record_step(
         self,
@@ -427,6 +443,11 @@ class ServingStats:
             out["moe_prefill_experts_hit"] = self.moe_prefill_experts_hit
             out["attended_window_tokens"] = self.attended_window_tokens
             out["attended_full_tokens"] = self.attended_full_tokens
+        if self.ssm_layers:
+            out["ssm_decode_tokens"] = self.ssm_decode_tokens
+            out["ssm_prefill_tokens"] = self.ssm_prefill_tokens
+            out["ssm_prefill_programs"] = self.ssm_prefill_programs
+            out["ssm_state_resets"] = self.ssm_state_resets
         out["spec_steps"] = self.spec_steps
         out["spec_proposed_tokens"] = self.spec_proposed_tokens
         out["spec_accepted_tokens"] = self.spec_accepted_tokens

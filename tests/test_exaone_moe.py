@@ -225,8 +225,7 @@ def test_a_quarantined_lane_has_its_ring_scrubbed_and_its_probe_recovers(tiny):
     [prompt] = _prompts(cfg, [11], seed=5)
     rid = engine.submit(prompt, max_new_tokens=6)
     engine.step()  # prefilled, and a first decode program out
-    wk, wv, counts = engine.cache.extras
-    engine.cache.extras = (wk.at[:, 0].set(jnp.nan), wv, counts)
+    engine.cache.extras = engine.cache.extras._replace(wk=engine.cache.wk.at[:, 0].set(jnp.nan))
     engine.step()  # the program that attends the poisoned ring goes out; the clean one's token lands
     engine.step()  # its verdict lands, one program late: the lane and the ring it wrote meanwhile are scrubbed
     assert engine.cache.quarantined == frozenset({0}) and engine.scheduler.waiting == 1

@@ -8,6 +8,7 @@ loads the TPU's library (and skips, loudly, where it cannot)."""
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -163,3 +164,85 @@ def test_mellum2s_experts_compile_as_the_grouped_kernel_named_for_the_trace(one_
         text = fn.lower(x, *weights).compile().as_text()
         assert len(re.findall(r"%moe\.experts[.\d]* = bf16\[\d+,\d+\]\S* custom-call\(", text)) == 3, tokens
         assert "ragged-dot" not in text
+
+
+def test_one_kv_head_under_twenty_query_rows_is_a_page_of_16_rows_viewed_not_copied(one_chip, monkeypatch):
+    """``jamba2.serve-reasoning``: 256 slots on two attention layers' pool of
+    67,585 pages at ONE KV head of 128 under 20 query heads (R = 20 query rows,
+    not a multiple of 8): a page ``[16, 1, 128]`` is the matrix ``[16, 128]``.
+    The gate names no reason, Mosaic takes it, the view of the stacked pool is a
+    bitcast, and the one launch needs no scratch in HBM."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.paged_attention import paged_kernel_fallback_reason
+
+    slots, layers, pages, ps, kv, nh, d, pps = 256, 2, 67585, 16, 1, 20, 128, 264
+    assert paged_kernel_fallback_reason((pages, ps, kv, d), nh, kv) is None
+    compiled = _compile_paged_decode(one_chip, slots, layers, pages, ps, kv, nh, d, pps)
+    _pool_is_viewed_where_it_lies(compiled, layers, pages, ps * kv, d)
+
+
+def _compile_ssm_scan(one_chip, lanes, tokens, layers=26, states=16, channels=5120, run=13):
+    """``ops/ssm_scan.py`` as the model calls it: a run of layers scanned, each
+    advancing its layer of the state by ``tokens`` tokens; ``lanes`` lanes
+    under the engine's slot vmap (None: one lane alone, a prefill chunk)."""
+    from accelerate_tpu.ops.ssm_scan import ssm_scan
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def lane(state, dt, du, b, c, a):
+        def body(state, layer):
+            state, y = ssm_scan(state, layer, layer == 0, dt, du, b, c, a)
+            return state, y.sum()
+
+        return jax.lax.scan(body, state, 7 + jnp.arange(run, dtype=jnp.int32))
+
+    lead = () if lanes is None else (lanes,)
+    fn = lane if lanes is None else jax.vmap(lane, in_axes=(0, 0, 0, 0, 0, None))
+    return jax.jit(fn, donate_argnums=0).lower(
+        shape((*lead, layers, states, channels)), shape((*lead, tokens, channels)), shape((*lead, tokens, channels)),
+        shape((*lead, tokens, states)), shape((*lead, tokens, states)), shape((states, channels)),
+    ).compile()
+
+
+@pytest.mark.parametrize("lanes,tokens", [(256, 1), (None, 512), (None, 32)], ids=["decode_step_256_lanes", "prefill_chunk_512", "smallest_bucket"])
+def test_the_selective_scan_advances_the_stacked_state_where_it_lies(lanes, tokens, one_chip, monkeypatch):
+    """The state-space cell's two launches: a decode step (256 lanes, one token
+    each: the slot vmap lands in ONE custom call with the lanes on the grid)
+    and a prefill chunk (one lane, 512 tokens in chunks of 128 with the state
+    resident). The stacked state ``[lanes, 26, 16, 5120]`` float32, 2.2 GB at
+    256 lanes, is the call's operand AND its result (aliased), inside the
+    layer scan's loop: nothing copies it, a layer of it or a lane of it, and
+    the program needs no scratch in HBM beyond the tokens' own operands."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.ssm_scan import ssm_kernel_fallback_reason
+
+    state = (26, 16, 5120) if lanes is None else (lanes, 26, 16, 5120)
+    assert ssm_kernel_fallback_reason(state) is None
+    compiled = _compile_ssm_scan(one_chip, lanes, tokens)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%ssm_scan" in text
+    dims = ",".join(map(str, state))
+    assert not re.search(rf"= f32\[(1,)?{dims}\]\S* (copy|fusion)\(", text), "the stacked state is copied, not aliased"
+    assert re.search(r"output_to_operand_aliasing=\{\{0\}: \(\d+, \{\}\)", text), "the call's state result does not alias its state operand"
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * int(np.prod(state)) and memory.temp_size_in_bytes < 32 << 20
+
+
+@pytest.mark.parametrize("states,channels", [(16, 5120), (4, 256), (12, 1000), (16, 32768), (64, 65536), (16, 524288)])
+def test_the_scans_gate_says_what_mosaic_takes(states, channels, one_chip, monkeypatch):
+    """``ssm_kernel_fallback_reason`` against the compiler itself, on both
+    sides of its one rule (a lane's state, in and out, beside eight tokens'
+    operands in VMEM): where it names no reason the kernel compiles, a decode
+    step and a prefill chunk whose token chunk is cut to fit, states and
+    channels that fill no tile among them; where it names one Mosaic refuses."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.ssm_scan import ssm_kernel_fallback_reason
+
+    for lanes, tokens in ((4, 1), (None, 128)):
+        if ssm_kernel_fallback_reason((2, states, channels)) is None:
+            text = _compile_ssm_scan(one_chip, lanes, tokens, layers=2, states=states, channels=channels, run=1).as_text()
+            assert text.count('custom_call_target="tpu_custom_call"') == 1
+        else:
+            with pytest.raises(Exception, match="(?i)vmem|memory|mosaic"):
+                _compile_ssm_scan(one_chip, lanes, tokens, layers=2, states=states, channels=channels, run=1)

@@ -406,7 +406,7 @@ def test_the_decode_program_hands_back_the_slots_tokens_first(with_and_without_r
     assert len(seen) == 4  # both requests decode from the first step on, a token a step
     for step, (nxt, rest, decoding) in enumerate(seen):
         assert nxt.ndim == 1 and nxt.dtype == np.int32 and nxt.size >= 3
-        assert rest == 3 + len(engine.cache.extras)  # the finite verdicts, the pools, and what else a lane carries
+        assert rest == 4  # the finite verdicts, the pools, and what else a lane carries (one named structure, empty or not)
         assert sorted(decoding.values()) == ids
         for slot in range(3):
             assert nxt[slot] == (results[decoding[slot]].generated[step] if slot in decoding else 0)
@@ -761,8 +761,7 @@ def test_a_poisoned_lane_is_quarantined_a_program_late_and_the_lane_beside_it_is
     pages = engine.cache.pages_of(slot)
     _poison_slot_kv(engine, slot)
     if engine.windowed:
-        wk, wv, counts = engine.cache.extras
-        engine.cache.extras = (wk.at[:, slot].set(jnp.nan), wv, counts)
+        engine.cache.extras = engine.cache.extras._replace(wk=engine.cache.wk.at[:, slot].set(jnp.nan))
     engine.step()  # the program that reads the poison goes out
     assert engine.stats.slot_quarantines == 0
     for result in engine.step():  # its verdict lands; the step ends landed, its scrubs enqueued behind everything
@@ -881,3 +880,25 @@ def test_a_speculative_engine_lands_every_step_where_it_is_made_until_it_is_disa
     assert engine.stats.decode_overlapped > 0 and engine.stats.tokens_dropped_late == 0
     for prompt, rid in zip(prompts, ids):
         np.testing.assert_array_equal(results[rid].generated, _reference(model, params, prompt, 12))
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2, 4])
+def test_a_decode_steps_rows_reach_the_pool_alike_scattered_or_through_their_pages(kv_heads):
+    """The decode program writes a step's rows by a scatter at two and more KV
+    heads and through whole pages at one (PERF.md §6, PR 36): either road
+    leaves the same pool, the null page of inactive and probe lanes included."""
+    rng = np.random.default_rng(kv_heads)
+    layers, pages, ps, d, lanes = 2, 12, 4, 8, 6
+    pool = rng.normal(size=(layers, pages, ps, kv_heads, d)).astype(np.float32)
+    pool[:, 0] = 0.0  # the null page
+    active = np.array([True, False, True, True, False, False])  # lane 1 waits between chunks, 4 is probed, 5 is empty
+    wpage = np.where(active, np.array([3, 7, 9, 5, 2, 0]), 0).astype(np.int32)
+    woff = np.where(active, np.array([0, 2, 3, 1, 1, 0]), 0).astype(np.int32)
+    rows = np.where(active[:, None, None, None], rng.normal(size=(lanes, layers, kv_heads, d)), 0.0).astype(np.float32)
+    scattered = np.asarray(ServingEngine._rows_scattered(jnp.asarray(pool), jnp.asarray(rows), wpage, woff))
+    through = np.asarray(jax.jit(ServingEngine._rows_through_pages)(jnp.asarray(pool), jnp.asarray(rows), wpage, woff))
+    assert np.array_equal(scattered, through) and not scattered[:, 0].any()
+    expected = pool.copy()
+    for lane in np.flatnonzero(active):
+        expected[:, wpage[lane], woff[lane]] = rows[lane]
+    assert np.array_equal(through, expected) and not np.array_equal(through, pool)
