@@ -24,7 +24,9 @@ Two kinds of cached layer (the decode protocol of ``models/generation.py``,
 extended): full layers keep every token, ``cache["k"]/["v"]`` ``[Lf, B, T, KV,
 D]`` (or the serving engine's page pool under the ``attend`` protocol); window
 layers keep a ring of ``sliding_window`` tokens a sequence, ``cache["wk"]/
-["wv"]`` ``[Lw, B, KV, R, D]`` (heads before entries: the layout the scores'
+["wv"]`` a tuple of one array a window layer, ``[B, KV, R, D]`` (a layer's ring
+is an array of its own, so that taking it is no slice of a stack and a write
+into it touches no other layer's; heads before entries: the layout the scores'
 product reads without a relayout), position ``p`` at entry ``p % R``, whatever
 the length. ``cache["real"]`` (optional) says how many of the fed tokens are real:
 a bucket's padding must not enter a ring, where it would overwrite live
@@ -178,11 +180,11 @@ class ExaoneMoe:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def init_window_cache(self, batch: int, dtype=jnp.bfloat16) -> dict:
-        """The window layers' rings, ``[Lw, batch, KV, sliding_window, D]``:
-        the same size whatever the sequences' lengths."""
+        """The window layers' rings, one array a layer, ``[batch, KV,
+        sliding_window, D]``: the same size whatever the sequences' lengths."""
         cfg = self.config
-        shape = (len(self.window_layers), batch, cfg.kv_heads, cfg.sliding_window or 1, cfg.dim_per_head)
-        return {"wk": jnp.zeros(shape, dtype), "wv": jnp.zeros(shape, dtype)}
+        shape = (batch, cfg.kv_heads, cfg.sliding_window or 1, cfg.dim_per_head)
+        return {kind: tuple(jnp.zeros(shape, dtype) for _ in self.window_layers) for kind in ("wk", "wv")}
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16) -> dict:
         return {
@@ -243,8 +245,9 @@ class ExaoneMoe:
         token) against the cache. Returns (last position's logits [B, V], new
         cache). Without an ``attend`` hook the new cache holds both kinds
         updated; with one (the engine's paged kernel) it holds the fed tokens'
-        K/V of both kinds as deltas ``[L*, B, S, KV, D]`` and the engine writes
-        them. ``new_cache["moe_held"]`` ``[sparse layers, held experts]``
+        K/V of both kinds as deltas (``[Lf, B, S, KV, D]``; a window layer's
+        ``[B, S, KV, D]``, a member of the tuple like its ring) and the engine
+        writes them. ``new_cache["moe_held"]`` ``[sparse layers, held experts]``
         counts the real fed tokens each held expert was chosen by, a layer."""
         cfg = self.config
         b, s = input_ids.shape
@@ -296,7 +299,7 @@ class ExaoneMoe:
 
         new_cache = {
             "k": stacked(full_k, cache["k"]), "v": stacked(full_v, cache["v"]),
-            "wk": stacked(ring_k, cache["wk"]), "wv": stacked(ring_v, cache["wv"]),
+            "wk": tuple(ring_k), "wv": tuple(ring_v),
             "length": length + s,
             "moe_held": jnp.stack(held) if held else jnp.zeros((0, self.experts_here), jnp.int32),
         }

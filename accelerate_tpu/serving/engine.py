@@ -415,6 +415,22 @@ class ServingEngine:
                     "paged decode kernel not engaged — decoding through the "
                     f"gather program: {self._kernel_fallback_reason}"
                 )
+        # the window layers' rings: the kernel program writes a decode step's
+        # entries through their own tiles where Mosaic takes the rings' shape
+        # (ops/ring_write.py); the gather program keeps the select over each ring
+        self._ring_fallback_reason: Optional[str] = None
+        self._use_ring_kernel = False
+        if self.cache.wk:
+            from ..ops.ring_write import ring_write_fallback_reason
+
+            ring = self.cache.wk[0]
+            if not self.use_kernels:
+                self._ring_fallback_reason = "use_kernels is off"
+            elif not self._use_decode_kernel:
+                self._ring_fallback_reason = "the gather program writes by select"
+            else:
+                self._ring_fallback_reason = ring_write_fallback_reason(ring.shape, ring.dtype)
+            self._use_ring_kernel = self._ring_fallback_reason is None
         # the recurrent layers' scan: the Pallas kernel over the stacked state
         # where Mosaic takes its shape (ops/ssm_scan.py), else the plain scan
         self._scan_fallback_reason: Optional[str] = None
@@ -519,6 +535,8 @@ class ServingEngine:
             self.stats.moe_tokens_by_held_expert = np.zeros((model.experts_here,), np.int64)
         if self.stateful:
             self.stats.ssm_layers = int(self.cache.extras.ssm.shape[1])
+        if self.windowed:
+            self.stats.window_layers = len(self.cache.wk)
         # the last decode program's `fetched`, on the device: the tokens, and
         # behind them what a model with routed experts counts (two sets of
         # held counts and the pairs hit)
@@ -555,6 +573,13 @@ class ServingEngine:
         from ..ops.ssm_scan import ssm_scan
 
         return {"scan": ssm_scan}
+
+    def _ring_writer(self):
+        """What the decode program writes a step's ring entries with: the
+        ``ring_write`` kernel, or the select it replaces."""
+        from ..ops.ring_write import ring_write, ring_write_reference
+
+        return ring_write if self._use_ring_kernel else ring_write_reference
 
     # -- jitted programs (dot-keyed: shared cache with generate()) ----------
 
@@ -634,11 +659,17 @@ class ServingEngine:
         token). ``extras`` is what a lane carries beside its pages
         (``PagedKVCache.extras``): one body serves whatever it holds, and an
         empty one is no argument of the compiled program. Where it holds the
-        window layers' rings ``wk``/``wv`` ``[Lw, S, KV, R, D]`` and
-        ``counts``, each lane attends its own ring (mapped over the slot
-        axis); the new token's K/V of the window layers come back as deltas
-        like the full layers' and are written at entry ``length % R`` of the
-        lane's ring. The routed experts see all lanes' tokens as one batch
+        window layers' rings ``wk``/``wv`` (a tuple, one ``[S, KV, R, D]`` a
+        layer) and ``counts``, each lane attends its own ring (mapped over
+        the slot axis, axis 0 like the state's; a layer's ring is a member of
+        the tuple, so nothing is sliced out of a stack); the new token's K/V
+        of the window layers come back as deltas like the full layers' and
+        are written at entry ``length % R`` of the lane's ring: by the kernel
+        program through the entry's own tile (``ops/ring_write.py``: one
+        launch for all of the model's rings, nothing else of a ring moves),
+        by the gather program, and where the rings' shape holds no whole
+        tiles, as one select over each ring where it lies. The routed experts
+        see all lanes' tokens as one batch
         (``models/moe.py``'s batching rule), and the tokens each held expert
         was chosen by, a sparse layer, over the ACTIVE lanes, ride home behind
         the tokens in the one fetched vector, and behind them what the prefill
@@ -655,6 +686,7 @@ class ServingEngine:
         ps = self.cache.page_size
         gathered = self._gathered_view
         use_kernel = self._use_decode_kernel
+        write_rings = self._ring_writer()
 
         def build():
             def decode_step(params, pk, pv, extras, prev, tokens, lengths, active, tables, keys):
@@ -664,7 +696,7 @@ class ServingEngine:
                 kinds = ("k", "v", "wk", "wv")[: 2 + len(rings)]
 
                 def beside(more):  # this lane's rings and state, as the protocol takes them
-                    lane = {kind: r[:, None] for kind, r in zip(kinds[2:], more)}
+                    lane = {kind: tuple(r[None] for r in of_kind) for kind, of_kind in zip(kinds[2:], more)}
                     if state:
                         conv, ssm, live = more[len(rings):]
                         lane.update(conv=conv[None], ssm=ssm[None], real=live.astype(jnp.int32), **scan_hook)
@@ -695,7 +727,8 @@ class ServingEngine:
                                  "table": row, "attend": attend, **beside(ring)}
                         logits, nc = fwc(params, token[None, None], cache)
                         ok = jnp.all(jnp.isfinite(logits))
-                        return sample(logits, key)[0], ok, *(nc[kind][:, 0, 0] for kind in kinds), *counted(nc)
+                        new = [nc[kind][:, 0, 0] for kind in kinds[:2]] + [tuple(x[0, 0] for x in nc[kind]) for kind in kinds[2:]]
+                        return sample(logits, key)[0], ok, *new, *counted(nc)
                 else:
                     def one_slot(token, row, length, key, *ring):
                         cache = {**gathered(pk, pv, row, length), **beside(ring)}
@@ -706,13 +739,11 @@ class ServingEngine:
                         # instead of re-scattering the view
                         new = [jax.lax.dynamic_slice_in_dim(nc[kind][:, 0], length, 1, axis=1)[:, 0]
                                for kind in kinds[:2]]
-                        new += [jax.lax.dynamic_slice_in_dim(nc[kind][:, 0], length % r.shape[2], 1, axis=2)[:, :, 0]
-                                for kind, r in zip(kinds[2:], ring)]
+                        new += [tuple(jax.lax.dynamic_slice_in_dim(x[0], length % x.shape[2], 1, axis=1)[:, 0] for x in nc[kind])
+                                for kind in kinds[2:]]
                         return sample(logits, key)[0], ok, *new, *counted(nc)
 
-                nxt, ok, fk, fv, *of_rings = jax.vmap(one_slot, in_axes=(0, 0, 0, 0) + (1,) * len(rings) + (0,) * len(state))(
-                    tokens, tables, lengths, keys, *rings, *state
-                )
+                nxt, ok, fk, fv, *of_rings = jax.vmap(one_slot)(tokens, tables, lengths, keys, *rings, *state)
                 if state:
                     *of_rings, conv, ssm = of_rings
                     extras = extras._replace(conv=conv, ssm=ssm)
@@ -739,14 +770,13 @@ class ServingEngine:
                 # inactive at length 0, and its ring holds the chunks' live
                 # K/V (scrubbed of poison where a lane is quarantined)
                 (wk, wv, counts), (rk, rv, held) = extras[:3], of_rings
-                # one entry of each active lane's ring, written as ONE select over the
-                # rings where they lie: a scatter (or an update slice) over (lane, entry)
-                # has XLA relay the stacked rings out entries-major and back, four copies
-                # of the whole of them a step (PERF.md §6, PR 34)
-                hit = (jnp.arange(wk.shape[3])[None, :] == (lengths % wk.shape[3])[:, None]) & active[:, None]  # [S, R]
-                hit = hit[None, :, None, :, None]
-                wk = jnp.where(hit, jnp.moveaxis(rk, 0, 1)[:, :, :, None, :].astype(wk.dtype), wk)  # [S, Lw, KV, D] -> [Lw, S, KV, 1, D]
-                wv = jnp.where(hit, jnp.moveaxis(rv, 0, 1)[:, :, :, None, :].astype(wv.dtype), wv)
+                # one entry of each active lane's ring, each ring where it lies: through the
+                # entry's own tile (the kernel program: a launch for all the rings), or as one
+                # select over each ring, read and written whole. A scatter or an update slice
+                # over (lane, entry) has XLA relay every ring out entries-major and back
+                # (PERF.md §6, PRs 34 and 37)
+                written = write_rings((*wk, *wv), (*rk, *rv), lengths, active)
+                wk, wv = written[: len(wk)], written[len(wk):]
                 held = jnp.sum(jnp.where(active[:, None, None], held, 0), axis=0)
                 fetched = jnp.concatenate(
                     [jnp.where(active, nxt, jnp.int32(0)), held.reshape(-1).astype(jnp.int32), counts]
@@ -758,7 +788,7 @@ class ServingEngine:
 
         return self._jit(
             ("serve_paged_decode", self.cache.num_slots, self.cache.view_len, ps,
-             self.temperature, self._donate, use_kernel, self._use_scan_kernel),
+             self.temperature, self._donate, use_kernel, self._use_scan_kernel, self._use_ring_kernel),
             build,
         )
 
@@ -907,7 +937,8 @@ class ServingEngine:
                 if windowed:
                     wk, wv, counts = extras[:3]
                     cache.update(
-                        wk=jax.lax.dynamic_index_in_dim(wk, slot, axis=1), wv=jax.lax.dynamic_index_in_dim(wv, slot, axis=1),
+                        wk=tuple(jax.lax.dynamic_index_in_dim(r, slot, axis=0) for r in wk),
+                        wv=tuple(jax.lax.dynamic_index_in_dim(r, slot, axis=0) for r in wv),
                         real=real,
                     )
                 if stateful:
@@ -927,8 +958,8 @@ class ServingEngine:
 
                 pk, pv = written(pk, new_k), written(pv, new_v)
                 if windowed:
-                    wk = jax.lax.dynamic_update_index_in_dim(wk, nc["wk"][:, 0].astype(wk.dtype), slot, axis=1)
-                    wv = jax.lax.dynamic_update_index_in_dim(wv, nc["wv"][:, 0].astype(wv.dtype), slot, axis=1)
+                    wk = tuple(jax.lax.dynamic_update_index_in_dim(r, new[0].astype(r.dtype), slot, axis=0) for r, new in zip(wk, nc["wk"]))
+                    wv = tuple(jax.lax.dynamic_update_index_in_dim(r, new[0].astype(r.dtype), slot, axis=0) for r, new in zip(wv, nc["wv"]))
                     held = nc["moe_held"].reshape(-1).astype(jnp.int32)
                     counts = counts + jnp.concatenate([held, jnp.count_nonzero(held).astype(jnp.int32)[None]])
                     extras = extras._replace(wk=wk, wv=wv, counts=counts)
@@ -1069,11 +1100,10 @@ class ServingEngine:
 
         def build():
             def scrub(extras, slot):
-                def zeroed(array, axis):
-                    zeros = jnp.zeros(array.shape[:axis] + (1,) + array.shape[axis + 1:], array.dtype)
-                    return jax.lax.dynamic_update_slice_in_dim(array, zeros, slot, axis=axis)
+                def zeroed(array):
+                    return jax.lax.dynamic_update_slice_in_dim(array, jnp.zeros((1,) + array.shape[1:], array.dtype), slot, axis=0)
 
-                return extras._replace(**{name: zeroed(array, axis) for name, (array, axis) in extras.by_lane.items()})
+                return extras._replace(**jax.tree.map(zeroed, extras.by_lane))
 
             donate = (0,) if self._donate else ()
             return jax.jit(scrub, donate_argnums=donate)
@@ -2504,7 +2534,8 @@ class ServingEngine:
         delivered, of which program (``landed``; ``experts``: a model with
         routed experts adds ``assignments_held``, ``experts_hit``), and the
         active lanes of the program it dispatched (``lanes``: times the
-        recurrent layers, ``stats.ssm_decode_tokens``)."""
+        recurrent layers, ``stats.ssm_decode_tokens``; times the window
+        layers, ``stats.ring_entries_written``)."""
         if not self._warming:
             self.stats.record_phases(number, books.phases, books.stamp - books.t0)
         if root is not None:
@@ -2704,7 +2735,7 @@ class ServingEngine:
             # layer, the window's a window layer
             live_lengths = np.asarray(contexts, np.int64)
             self.stats.record_attended(
-                window=int(np.minimum(live_lengths, self.cache.window_tokens_per_slot - 1).sum()) * int(self.cache.wk.shape[0]),
+                window=int(np.minimum(live_lengths, self.cache.window_tokens_per_slot - 1).sum()) * len(self.cache.wk),
                 full=int(live_lengths.sum()) * int(self.cache.k.shape[0]),
             )
         context = sum(contexts)
@@ -3236,6 +3267,9 @@ class ServingEngine:
             # a model with sliding-window layers: they attend a ring a slot, under XLA
             "window_attention": "xla_ring" if self.windowed else None,
             "decode_fallback_reason": self._kernel_fallback_reason,
+            # and a decode step's new entries go into the rings through their own tiles, or by a select over each ring
+            "ring_write": ("pallas" if self._use_ring_kernel else "select") if self.windowed else None,
+            "ring_write_fallback_reason": self._ring_fallback_reason,
             # a model with recurrent layers: their scan over the stacked state
             "state_scan": ("pallas" if self._use_scan_kernel else "xla_scan") if self.stateful else None,
             "state_scan_fallback_reason": self._scan_fallback_reason,

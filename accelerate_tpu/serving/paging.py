@@ -36,9 +36,14 @@ Not every cached layer has the pool's shape. A model with sliding-window
 layers beside full ones (``models/exaone_moe.py``) gives the manager two kinds:
 the FULL layers live in the page pool above, ``[Lf, num_pages, ...]``, every
 token kept, pages growing with the context; the WINDOW layers live in one ring
-a slot, ``wk``/``wv`` ``[Lw, num_slots, KV, window, D]`` (position ``p`` at
-entry ``p % window``), allocated once and the same size whatever the length:
-a window layer never keeps or reads more than its window. A ring belongs to
+a slot, ``wk``/``wv`` a tuple of one array a window layer, ``[num_slots, KV,
+window, D]`` (position ``p`` at entry ``p % window``), allocated once and the
+same size whatever the length: a window layer never keeps or reads more than
+its window. One array a layer, lanes first: a layer's ring is then no slice of
+a stack (which XLA is free to copy out before a product reads it), a lane's
+ring is one contiguous run of bytes, and a decode step's new entry goes in
+through its own tile while nothing else of the ring moves
+(``ops/ring_write.py``). A ring belongs to
 its lane, not to pages, so nothing of it can be shared, parked or handed off:
 the engine refuses those for such a model, by name. What a lane carries
 beside its pages is the cache's to say, as ``extras``: the pytree the engine's
@@ -99,7 +104,7 @@ class LaneExtras(NamedTuple):
     has no use for is ``None`` (no leaf of the pytree, so no argument of a
     program). The order is the programs' argument order."""
 
-    wk: Any = None  # the window layers' rings [Lw, num_slots, KV, window, D]
+    wk: Any = None  # the window layers' rings: a tuple, one [num_slots, KV, window, D] a layer
     wv: Any = None
     counts: Any = None  # int32 counters the programs pass along (routed experts)
     conv: Any = None  # the recurrent layers' convolution tails [num_slots, Lm, (K - 1) * C]
@@ -107,9 +112,10 @@ class LaneExtras(NamedTuple):
 
     @property
     def by_lane(self) -> dict:
-        """The members that hold something a LANE, with the axis the lanes lie on."""
-        held = {"wk": (self.wk, 1), "wv": (self.wv, 1), "conv": (self.conv, 0), "ssm": (self.ssm, 0)}
-        return {name: (array, axis) for name, (array, axis) in held.items() if array is not None}
+        """The members that hold something a LANE: an array, or a tuple of
+        them, with the lanes on axis 0."""
+        held = {"wk": self.wk, "wv": self.wv, "conv": self.conv, "ssm": self.ssm}
+        return {name: member for name, member in held.items() if member is not None}
 
 
 class PageAllocator:
@@ -377,7 +383,10 @@ class PagedKVCache:
         return self.extras.wv
 
     def _lane_bytes(self, *names) -> int:
-        return sum(int(array.nbytes) for name, (array, _) in self.extras.by_lane.items() if name in names) // self.num_slots
+        import jax
+
+        held = [member for name, member in self.extras.by_lane.items() if name in names]
+        return sum(int(array.nbytes) for array in jax.tree.leaves(held)) // self.num_slots
 
     @property
     def lane_bytes(self) -> int:
@@ -403,7 +412,7 @@ class PagedKVCache:
     def window_tokens_per_slot(self) -> int:
         """Tokens a slot's window layers keep, each: the ring's length, whatever
         the context (0 without window layers)."""
-        return int(self.wk.shape[3]) if self.windowed else 0
+        return int(self.wk[0].shape[2]) if self.wk else 0
 
     @property
     def pages_in_use(self) -> int:

@@ -149,6 +149,11 @@ class ServingStats:
         self.moe_tokens_by_held_expert: Optional[np.ndarray] = None
         self.attended_window_tokens = 0  # cached tokens the decoded tokens attended, window layers
         self.attended_full_tokens = 0  # and full layers
+        # sliding-window layers: how many the model has (0: none, and the
+        # counter stays out of the snapshot), and the entries the decode
+        # programs put into their rings, cut where the programs are dispatched
+        self.window_layers = 0
+        self.ring_entries_written = 0  # (active lane, window layer) of every decode program dispatched: a K and a V entry each
         # recurrent (state-space) layers (models/jamba.py): how many the model
         # has (0: none, and the counters below stay out of the snapshot), and
         # the scan's work, cut where the programs are dispatched
@@ -314,6 +319,7 @@ class ServingStats:
         over ``lanes`` active lanes."""
         self.decode_overlapped += overlapped
         self.ssm_decode_tokens += lanes * self.ssm_layers
+        self.ring_entries_written += lanes * self.window_layers
 
     def record_step(
         self,
@@ -443,6 +449,8 @@ class ServingStats:
             out["moe_prefill_experts_hit"] = self.moe_prefill_experts_hit
             out["attended_window_tokens"] = self.attended_window_tokens
             out["attended_full_tokens"] = self.attended_full_tokens
+        if self.window_layers:
+            out["ring_entries_written"] = self.ring_entries_written
         if self.ssm_layers:
             out["ssm_decode_tokens"] = self.ssm_decode_tokens
             out["ssm_prefill_tokens"] = self.ssm_prefill_tokens

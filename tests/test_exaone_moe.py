@@ -61,6 +61,9 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_references_full_
     engine = ServingEngine(model, params, use_kernels=use_kernels, **ENGINE)
     assert engine.kernel_summary()["decode_attention"] == ("pallas" if use_kernels else "gather_reference")
     assert engine.kernel_summary()["window_attention"] == "xla_ring"
+    # the kernel program writes a step's ring entries by the kernel (here through the interpreter), the gather program by select
+    assert engine.kernel_summary()["ring_write"] == ("pallas" if use_kernels else "select")
+    assert (engine.kernel_summary()["ring_write_fallback_reason"] is None) == use_kernels
     engine.warmup()
     # contexts of 1 to 7 windows: chunked (41, 33: several 16-token spans), bucketed and single-token prefills
     prompts = _prompts(cfg, [5, 23, 41, 12, 33, 2])
@@ -104,6 +107,43 @@ def test_a_decode_step_between_a_prompts_chunks_leaves_its_ring_alone(tiny):
     for prompt, rid, new in ((short, first, 20), (long, second, 8)):
         row = np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)])
         assert _served_gaps(cfg, prompt, row, new).max() < 1e-4
+
+
+def test_the_kernel_program_writes_its_rings_by_the_kernel_and_serves_the_gather_programs_tokens(tiny, tmp_path):
+    """The same requests through the gather program (a select over each ring)
+    and through the kernel program (``ring_write``, through the interpreter):
+    the same tokens at temperature 0, with lanes idle, lanes between their
+    prompt's chunks and contexts of several windows on the way; and the
+    entries the decode programs wrote are counted where they are dispatched,
+    (active lane, window layer), as the ``engine.step`` spans' ``lanes`` say."""
+    from accelerate_tpu.telemetry import profiler
+
+    cfg, model, params = tiny
+    prompts = _prompts(cfg, [5, 41, 12, 33, 2, 27, 9], seed=13)  # seven requests on three lanes: lanes are reused, and idle at the end
+    rows = {}
+    for use_kernels in (False, True):
+        engine = ServingEngine(model, params, use_kernels=use_kernels, **{**ENGINE, "prefill_chunk": 12, "buckets": (4, 8, 12), "page_size": 4})
+        summary = engine.kernel_summary()
+        assert summary["ring_write"] == ("pallas" if use_kernels else "select") and summary["window_attention"] == "xla_ring"
+        engine.warmup()
+        before = engine.stats.snapshot()["ring_entries_written"]
+        profiler.clear()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path / str(use_kernels)), profiler_options=options)  # spans are live exactly while a session is
+        try:
+            rows[use_kernels] = engine.generate_many(prompts, max_new_tokens=21)  # past two windows of 8 from any start
+        finally:
+            jax.profiler.stop_trace()
+        steps = [span.ids for span in profiler.recorded() if span.name == "engine.step"]
+        profiler.clear()
+        written = engine.stats.snapshot()["ring_entries_written"] - before
+        assert written == len(model.window_layers) * sum(ids["lanes"] for ids in steps) > 0
+        assert written >= len(model.window_layers) * 21 * len(prompts)  # every served token's entry, and those of tokens dropped late
+    for kernel_row, gather_row in zip(rows[True], rows[False]):
+        assert np.array_equal(kernel_row, gather_row)
+    llama = build_model("llama-tiny")
+    assert "ring_entries_written" not in ServingEngine(llama, llama.init(jax.random.key(0)), num_slots=2, max_len=32).stats.snapshot()
 
 
 def test_a_long_spans_assignments_go_through_the_experts_in_several_chunks_and_are_counted(tiny):
@@ -198,9 +238,11 @@ def test_a_window_layers_tokens_a_slot_stay_put_while_the_context_grows_tenfold(
     cfg, model, params = tiny
     engine = ServingEngine(model, params, **ENGINE)
     cache, window, page = engine.cache, cfg["sliding_window"], ENGINE["page_size"]
-    assert cache.windowed and cache.wk.shape == (4, 3, cfg["num_key_value_heads"], window, cfg["head_dim"])
+    ring = (3, cfg["num_key_value_heads"], window, cfg["head_dim"])  # one array a window layer, lanes first
+    assert cache.windowed and len(cache.wk) == len(cache.wv) == 4 and all(r.shape == ring for r in (*cache.wk, *cache.wv))
     assert cache.k.shape[0] == 1  # the one full layer alone is paged
-    ring_bytes = cache.wk.nbytes + cache.wv.nbytes
+    ring_bytes = sum(r.nbytes for r in (*cache.wk, *cache.wv))
+    assert ring_bytes == 2 * 4 * int(np.prod(ring)) * cache.wk[0].dtype.itemsize == cache.lane_bytes * 3  # what the stacked rings held, to the byte
     [prompt] = _prompts(cfg, [7])
     engine.submit(prompt, max_new_tokens=66)
     pages, lengths = [], []
@@ -212,7 +254,7 @@ def test_a_window_layers_tokens_a_slot_stay_put_while_the_context_grows_tenfold(
             assert cache.window_tokens_per_slot == window <= window + page
     assert lengths[-1] >= 10 * lengths[0] and pages[-1] >= 8 * pages[0]  # the full layer's pages grow with the context
     assert all(-(-n // page) <= held <= -(-n // page) + 1 for held, n in zip(pages, lengths))  # and hold no more than it
-    assert cache.wk.nbytes + cache.wv.nbytes == ring_bytes and cache.nbytes == ring_bytes + cache.k.nbytes + cache.v.nbytes
+    assert sum(r.nbytes for r in (*cache.wk, *cache.wv)) == ring_bytes and cache.nbytes == ring_bytes + cache.k.nbytes + cache.v.nbytes
 
 
 def test_a_quarantined_lane_has_its_ring_scrubbed_and_its_probe_recovers(tiny):
@@ -225,11 +267,11 @@ def test_a_quarantined_lane_has_its_ring_scrubbed_and_its_probe_recovers(tiny):
     [prompt] = _prompts(cfg, [11], seed=5)
     rid = engine.submit(prompt, max_new_tokens=6)
     engine.step()  # prefilled, and a first decode program out
-    engine.cache.extras = engine.cache.extras._replace(wk=engine.cache.wk.at[:, 0].set(jnp.nan))
+    engine.cache.extras = engine.cache.extras._replace(wk=tuple(r.at[0].set(jnp.nan) for r in engine.cache.wk))
     engine.step()  # the program that attends the poisoned ring goes out; the clean one's token lands
     engine.step()  # its verdict lands, one program late: the lane and the ring it wrote meanwhile are scrubbed
     assert engine.cache.quarantined == frozenset({0}) and engine.scheduler.waiting == 1
-    assert not np.asarray(engine.cache.wk[:, 0]).any() and not np.asarray(engine.cache.wv[:, 0]).any()  # zeros, not NaN
+    assert not any(np.asarray(r[0]).any() for r in (*engine.cache.wk, *engine.cache.wv))  # zeros, not NaN
     engine.step()  # the probe alone rides this step
     assert engine.cache.quarantined == frozenset() and engine.stats.slot_quarantine_releases == 1
     results = engine.run()

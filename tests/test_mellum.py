@@ -73,6 +73,7 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(tiny, use_ker
     summary = engine.kernel_summary()
     assert summary["decode_attention"] == ("pallas" if use_kernels else "gather_reference") and summary["decode_fallback_reason"] is None
     assert summary["window_attention"] == "xla_ring" and engine.windowed
+    assert summary["ring_write"] == ("pallas" if use_kernels else "select") and (summary["ring_write_fallback_reason"] is None) == use_kernels
     seen, protocol = [], engine._fwc
 
     def tapped(params, ids, cache):  # the decode protocol, reporting the logits of every one-token call (a lane of a decode step)
@@ -96,6 +97,24 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(tiny, use_ker
         assert np.array_equal(want.argmax(-1), row[prompt.size:])  # every served token is the reference's first choice ...
         for logits in want:  # ... and the logits it was sampled from are the reference's: some lane of some step reported them
             assert np.abs(reported - logits).max(-1).min() < 2e-4
+
+
+def test_the_kernel_programs_tokens_are_the_gather_programs_with_the_rings_written_by_the_kernel(tiny):
+    """Temperature 0, the same requests through both programs: the kernel
+    program puts a step's entries into the rings through ``ring_write`` (the
+    interpreter here), the gather program by a select over each ring; chunks of
+    24 wrap the ring of 8 three times, and lanes are reused."""
+    cfg, model, params = tiny
+    prompts = _prompts(cfg, [66, 5, 41, 12, 33, 2, 19], seed=9)
+    rows = {}
+    for use_kernels in (False, True):
+        engine = ServingEngine(model, params, use_kernels=use_kernels, **{**ENGINE, "prefill_chunk": 24, "buckets": (8, 24)})
+        assert engine.kernel_summary()["ring_write"] == ("pallas" if use_kernels else "select")
+        assert all(r.shape == (3, cfg["num_key_value_heads"], cfg["sliding_window"], cfg["head_dim"]) for r in (*engine.cache.wk, *engine.cache.wv))
+        rows[use_kernels] = engine.generate_many(prompts, max_new_tokens=13)
+        assert engine.stats.snapshot()["ring_entries_written"] >= len(model.window_layers) * 13 * len(prompts)
+    for kernel_row, gather_row in zip(rows[True], rows[False]):
+        assert np.array_equal(kernel_row, gather_row)
 
 
 def test_plain_generate_and_the_engine_share_one_protocol(tiny):

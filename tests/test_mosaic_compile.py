@@ -246,3 +246,61 @@ def test_the_scans_gate_says_what_mosaic_takes(states, channels, one_chip, monke
         else:
             with pytest.raises(Exception, match="(?i)vmem|memory|mosaic"):
                 _compile_ssm_scan(one_chip, lanes, tokens, layers=2, states=states, channels=channels, run=1)
+
+
+def _compile_window_layers_then_ring_write(one_chip, slots, layers, kv, r, nh, d=128, dtype=jnp.bfloat16):
+    """What a decode step does with the window layers' rings, one array
+    ``[slots, kv, r, d]`` a layer: every lane attends its own ring of every
+    layer (the engine's slot ``vmap`` of ``window_attention``), then the
+    step's new entries go into the donated rings through ``ring_write``."""
+    from accelerate_tpu.models.exaone_moe import window_attention
+    from accelerate_tpu.ops.ring_write import ring_write
+
+    def shape(dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def step(q, k, v, wk, wv, lengths, active):
+        lane = lambda q, k, v, rk, rv, n: window_attention(q[None], k[None], v[None], rk[None], rv[None], n, r)[0]
+        attended = [jax.vmap(lane)(q[w], k[w], v[w], wk[w], wv[w], lengths) for w in range(layers)]
+        written = ring_write((*wk, *wv), [x[:, 0] for x in (*k, *v)], lengths, active)
+        return attended, written[:layers], written[layers:]
+
+    rings = tuple(shape((slots, kv, r, d)) for _ in range(layers))
+    new = tuple(shape((slots, 1, kv, d)) for _ in range(layers))
+    return jax.jit(step, donate_argnums=(3, 4)).lower(
+        tuple(shape((slots, 1, nh, d)) for _ in range(layers)), new, new, rings, rings, shape((slots,), jnp.int32), shape((slots,), jnp.bool_),
+    ).compile()
+
+
+@pytest.mark.parametrize("slots,layers,kv,r,nh", [(64, 6, 4, 1024, 32), (128, 3, 8, 128, 64)], ids=["mellum2_serve_code", "k_exaone_serve_mixed"])
+def test_a_decode_steps_ring_entries_go_in_where_they_lie(slots, layers, kv, r, nh, one_chip, monkeypatch):
+    """The two cells with window layers (``mellum2.serve-code``: 64 lanes, 6
+    window layers, 4 KV heads, rings of 1024; ``k-exaone.serve-mixed``: 128, 3,
+    8, 128): ``window_attention`` reads the rings under XLA, ``ring_write``
+    puts the step's entries into all ``2 * layers`` of them in ONE custom call
+    whose ring operands are its results (aliased, donated), and nothing in the
+    program makes a ring anew: no select over one, no copy or slice of one, no
+    update slice into one. The asynchronous copies that bring parts of a ring
+    into VMEM ahead of the products that read it (``slice-start``,
+    ``copy-start``) are XLA's prefetch, and write no ring in HBM."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.ops.ring_write import ring_write_fallback_reason
+
+    assert ring_write_fallback_reason((slots, kv, r, 128), jnp.bfloat16) is None
+    compiled = _compile_window_layers_then_ring_write(one_chip, slots, layers, kv, r, nh)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%ring_write" in text
+    ring = rf"bf16\[{slots},{kv},{r},128\]"
+    made = re.findall(rf"= {ring}\{{[^}}]*\}} (select|copy|dynamic-update-slice)\(", text)
+    assert not made, f"a ring is made anew by {sorted(set(made))}"
+    # a fusion with a ring for its result only views one (a bitcast that the product reading it has fused), and no
+    # fused computation ends in an op that would write one
+    fused = re.findall(rf"= {ring}\{{[^}}]*\}} fusion\(.*", text)
+    assert all("calls=%bitcast_fusion" in line for line in fused), fused
+    assert not re.search(rf"ROOT \S+ = {ring}\S* (select|copy|slice|dynamic-slice|dynamic-update-slice|concatenate)\(", text)
+    # every ring result of the call aliases the ring operand behind the two prefetched scalars
+    for j in range(2 * layers):
+        assert f"{{{j}}}: ({2 + j}, {{}})" in text
+    # and the program's scratch in HBM (the layers' scores and weights, float32) holds nothing of a ring's size
+    memory, ring_bytes = compiled.memory_analysis(), slots * kv * r * 128 * 2
+    assert memory.alias_size_in_bytes >= 2 * layers * ring_bytes and memory.temp_size_in_bytes < ring_bytes // 2

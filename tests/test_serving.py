@@ -761,7 +761,7 @@ def test_a_poisoned_lane_is_quarantined_a_program_late_and_the_lane_beside_it_is
     pages = engine.cache.pages_of(slot)
     _poison_slot_kv(engine, slot)
     if engine.windowed:
-        engine.cache.extras = engine.cache.extras._replace(wk=engine.cache.wk.at[:, slot].set(jnp.nan))
+        engine.cache.extras = engine.cache.extras._replace(wk=tuple(r.at[slot].set(jnp.nan) for r in engine.cache.wk))
     engine.step()  # the program that reads the poison goes out
     assert engine.stats.slot_quarantines == 0
     for result in engine.step():  # its verdict lands; the step ends landed, its scrubs enqueued behind everything
@@ -770,7 +770,7 @@ def test_a_poisoned_lane_is_quarantined_a_program_late_and_the_lane_beside_it_is
     assert engine.stats.tokens_dropped_late == 1
     assert not np.asarray(engine.cache.k[:, np.asarray(pages)], np.float32).any()  # zeros, the late write included
     if engine.windowed:
-        assert not np.asarray(engine.cache.wk[:, slot], np.float32).any()
+        assert not any(np.asarray(r[slot], np.float32).any() for r in (*engine.cache.wk, *engine.cache.wv))
     _drain_steps(engine, results)
     assert engine.stats.slot_quarantine_releases == 1 and engine.stats.requests_requeued == 1
     for prompt, rid in zip(prompts, ids):
