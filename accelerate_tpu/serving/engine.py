@@ -2500,8 +2500,13 @@ class ServingEngine:
 
     def _deliver_flight(self, books: _StepBooks, flight: _Flight) -> None:
         """Fetch one decode program's tokens (the wait for that program: the
-        fence of everything enqueued before it) and deliver them."""
-        with books.mark("engine.fetch"):
+        fence of everything enqueued before it) and deliver them. While a
+        profiler session is on, ``engine.fetch`` says which program landed
+        and whether it had landed before the host came (``ready``: then the
+        landing marks the host's time, not the device's)."""
+        with books.mark("engine.fetch") as live:
+            if live is not None:
+                live.set_metadata(program=flight.number, ready=int(flight.fetched.is_ready()))
             fetched = np.asarray(flight.fetched)
             finite = np.asarray(flight.ok)
         lanes = self.cache.num_slots

@@ -46,7 +46,9 @@ logger = get_logger(__name__)
 
 class Span(NamedTuple):
     """One closed span of the ring. ``parent_id`` is 0 for a root; the stamps
-    are ``time.time_ns()``, the clock the profiler stamps its own events with;
+    are ``time.time_ns()``, the wall clock, where the trace's copy of the same
+    span counts from its session's start (one offset a session, which no
+    reader is told: ``benchmark/lib/landings.py`` anchors one on the other);
     ``ids`` holds small scalars only — never an engine, a request or an array."""
 
     span_id: int

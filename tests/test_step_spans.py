@@ -167,6 +167,29 @@ def test_with_no_session_the_engine_keeps_nothing_builds_no_annotation_and_serve
     assert profiler.recorded() == []
 
 
+@pytest.mark.parametrize("traced", [False, True], ids=["no_session", "session"])
+def test_the_engine_asks_whether_a_program_landed_only_in_a_session_and_serves_the_same_tokens(traced, llama, monkeypatch, tmp_path):
+    """``engine.fetch``'s ``ready`` is one ``is_ready()`` a landing, asked while a session is on and never else."""
+    model, params = llama
+    array_type = type(jnp.zeros(1))
+    asked, is_ready = [], array_type.is_ready
+
+    def counted(self):
+        asked.append(1)
+        return is_ready(self)
+
+    monkeypatch.setattr(array_type, "is_ready", counted)
+    prompts = _prompts([5, 17, 3, 30, 9])
+    engine = ServingEngine(model, params, num_slots=3, max_len=64)
+    with session(tmp_path) if traced else contextlib.nullcontext():
+        rows = engine.generate_many(prompts, max_new_tokens=6)
+    for prompt, row in zip(prompts, rows):
+        np.testing.assert_array_equal(row, np.asarray(generate(model, params, prompt[None], max_new_tokens=6)[0]))
+    fetches = [s for s in profiler.recorded() if s.name == "engine.fetch"]
+    assert len(asked) == len(fetches) == (engine.stats.steps if traced else 0)
+    assert all(s.ids["ready"] in (0, 1) for s in fetches)
+
+
 def _mini_cell(seed=2**31 + 13):
     """The serving cell at rehearsal size, in this process: the harness's own
     closed loop (and its arithmetic over ``cache.lengths``) around the engine."""
@@ -255,6 +278,21 @@ def test_a_traced_slice_gives_one_root_a_step_with_the_tables_children_and_the_e
     fetches = sorted((s for s in recorded if s.name == "engine.fetch"), key=lambda s: s.start_ns)
     offsets = [s.start_ns - e.start_ns for s, e in zip(fetches, sorted(by_name["engine.fetch"], key=lambda e: e.start_ns))]
     assert max(offsets) - min(offsets) < 1e6  # under a millisecond apart over the whole slice
+
+
+def test_a_traced_steps_fetch_names_the_program_its_root_landed_and_whether_it_had_landed(tmp_path):
+    """What ``benchmark/lib/landings.py`` keeps a step by: the fetch's ``program`` and ``ready``."""
+    engine, loop, spans = _mini_cell(seed=2**31 + 29)
+    with session(tmp_path):
+        for _ in range(12):
+            loop.step()
+    recorded = profiler.recorded()
+    roots = [s for s in recorded if s.name == "engine.step"]
+    assert len(roots) == 12
+    for root in roots:
+        fetches = [c for c in _under(recorded, root) if c.name == "engine.fetch"]
+        assert [f.ids["program"] for f in fetches] == [root.ids["landed"]] == [root.ids["step"] - 1]
+        assert fetches[0].ids["ready"] in (0, 1) and set(fetches[0].ids) == {"program", "ready"}
 
 
 @pytest.mark.parametrize("traced_requests", [False, True], ids=["no_tracer", "request_tracer"])
