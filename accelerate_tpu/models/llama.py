@@ -65,9 +65,16 @@ def decoder_layer(
     b, s = h.shape[:2]
     nh, nkv, d = cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-    q = dot(x, lp["wq"]).reshape(b, s, nh, d)
-    k = dot(x, lp["wk"]).reshape(b, s, nkv, d)
-    v = dot(x, lp["wv"]).reshape(b, s, nkv, d)
+    q, k, v = dot(x, lp["wq"]), dot(x, lp["wk"]), dot(x, lp["wv"])
+    if cache is not None:
+        # the three products held in the layout the weights give them: XLA
+        # would otherwise carry the heads-major layout that rotary and
+        # attention want back through the reshapes, and transpose the weight
+        # in every layer of every step
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    q = q.reshape(b, s, nh, d)
+    k = k.reshape(b, s, nkv, d)
+    v = v.reshape(b, s, nkv, d)
     q = apply_rotary(q, cos, sin)
     k = apply_rotary(k, cos, sin)
     new_cache = None

@@ -304,3 +304,53 @@ def test_a_decode_steps_ring_entries_go_in_where_they_lie(slots, layers, kv, r, 
     # and the program's scratch in HBM (the layers' scores and weights, float32) holds nothing of a ring's size
     memory, ring_bytes = compiled.memory_analysis(), slots * kv * r * 128 * 2
     assert memory.alias_size_in_bytes >= 2 * layers * ring_bytes and memory.temp_size_in_bytes < ring_bytes // 2
+
+
+def _mistral_widths(layers=2):
+    """``mistral-7b-v0.3``'s widths (hidden 4096, 32 query and 8 KV heads of
+    128, MLP 14,336, vocabulary 32,768) at ``layers`` layers, its parameters
+    as shapes in bf16 on the described chip."""
+    from accelerate_tpu.models import Llama
+    from accelerate_tpu.models.config import TransformerConfig
+
+    cfg = TransformerConfig(
+        arch="llama", vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=layers, num_heads=32,
+        num_kv_heads=8, head_dim=128, max_seq_len=32768, rope_theta=1e6, norm_eps=1e-5, tie_embeddings=False,
+    )
+    return Llama(cfg)
+
+
+def _as_shapes(tree, one_chip, dtype=None):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+
+# an op whose result is one layer of a stacked projection weight, wq [1, 4096, 4096] or wk / wv [1, 4096, 1024]
+_PROJECTION_WEIGHT = r"bf16\[1,4096,(?:4096|1024)\]\S*"
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512"])
+def test_the_serving_programs_read_the_projection_weights_where_they_lie(program, one_chip, monkeypatch):
+    """The engine's paged decode program (32 lanes, the paged kernel) and its
+    512-token prefill program at mistral's widths, two layers: the q/k/v
+    products are held in the layout the weights give them
+    (``models/llama.py:decoder_layer``), so no program slices a layer's
+    ``wq``, ``wk`` or ``wv`` into VMEM and transposes it there. The layouts
+    that rotary and attention want fall on the activations."""
+    monkeypatch.setenv("ACCELERATE_PALLAS_INTERPRET", "0")
+    from accelerate_tpu.serving import ServingEngine
+
+    model = _mistral_widths()
+    params = _as_shapes(jax.eval_shape(model._init, jax.random.key(0)), one_chip, jnp.bfloat16)
+    engine = ServingEngine(model, params, num_slots=32, max_len=640, buckets=(32, 512), num_pages=64, use_kernels=True, dtype=jnp.bfloat16)
+    assert engine.kernel_summary()["decode_attention"] == "pallas"
+    engine._donate = True  # as on the chip
+    if program == "decode":
+        fn, args = engine._paged_decode_program(), engine._decode_arguments(engine._sampling_keys(0))
+    else:
+        fn, args = engine._paged_prefill_program(512), (np.zeros((1, 512), np.int32), *engine._prefill_arguments(engine.cache.tables[0].copy(), 0, 512, 0))
+    text = fn.lower(params, *_as_shapes(args, one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (1 if program == "decode" else 0)
+    relaid = [name for name, op in re.findall(rf"%(\S+) = {_PROJECTION_WEIGHT} (copy|copy-start|fusion)\(", text)
+              if op != "fusion" or "dynamic-slice" in name]
+    assert not relaid, f"a projection weight is sliced out or relaid by {relaid}"
+
