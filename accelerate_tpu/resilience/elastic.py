@@ -597,7 +597,10 @@ class ElasticCoordinator:
     def _mirror(self) -> None:
         """Refresh the buddy copy of the step-boundary state: params +
         optimizer state (the authoritative 1/N shards) device_put onto the
-        rolled mesh. Gradients are recomputed, the scaler scalars are
+        rolled mesh, one leaf at a time: each leaf's put is a
+        collective-permute over every device, and with many in flight at once
+        XLA:CPU under load can wait on a participant that never starts and
+        abort the process. Gradients are recomputed, the scaler scalars are
         replicated everywhere already — neither needs a buddy. With only one
         host's devices left there is nowhere redundant to roll onto: the
         mirror stands down (a further loss falls to the checkpoint rung)."""
@@ -614,9 +617,13 @@ class ElasticCoordinator:
         p_sh = buddy_shardings(self.model.params_shardings, bmesh)
         o_sh = buddy_shardings(self.optimizer._opt_state_device_shardings, bmesh)
         first_mirror = self._buddy is None
+
+        def put(tree, shardings):
+            return jax.tree.map(lambda x, s: jax.block_until_ready(jax.device_put(x, s)), tree, shardings)
+
         self._buddy = {
-            "params": jax.device_put(self.model.params, p_sh),
-            "opt_state": jax.device_put(self.optimizer.opt_state, o_sh),
+            "params": put(self.model.params, p_sh),
+            "opt_state": put(self.optimizer.opt_state, o_sh),
         }
         self._mirror_step = self.completed_steps
         if first_mirror:

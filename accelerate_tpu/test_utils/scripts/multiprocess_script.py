@@ -224,7 +224,12 @@ def main():
         if state.is_main_process:
             sink = os.path.join(d3, "telemetry.jsonl")
             lines = [json.loads(l) for l in open(sink)]
-            assert lines and lines[0]["metrics"]["steps"] == 4, lines
+            # one compile after the first step, and no other: the second backward() adds its gradients to
+            # the first's (optimizer.accumulate_grads), a program the first did not run
+            compiled = [l for l in lines if l["kind"] == "compile"]
+            assert [(l["step"], l["new_compiles"]) for l in compiled] == [(2, 1)], compiled
+            flushed = [l for l in lines if l["kind"] == "telemetry"]
+            assert flushed and flushed[0]["metrics"]["steps"] == 4, lines
     finally:
         state.wait_for_everyone()
         if state.is_main_process:

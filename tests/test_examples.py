@@ -12,8 +12,7 @@ EXAMPLES_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__fi
 
 
 def run_example(path, *args, timeout=420):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env = dict(os.environ)  # XLA_FLAGS as tests/conftest.py sets them: the 8-device mesh
     env["JAX_PLATFORMS"] = "cpu"
     result = subprocess.run(
         [sys.executable, os.path.join(EXAMPLES_DIR, path), *args],

@@ -42,14 +42,17 @@ def _prompts(cfg, lengths, seed=0):
     return [rng.integers(1, cfg["vocab_size"], (n,)).astype(np.int32) for n in lengths]
 
 
-def _served_gaps(cfg, prompt, row, new_tokens):
+def _served_gaps(cfg, prompts, rows, new_tokens):
     """By how much each served token's reference logit lies below the
-    reference's best, in the reference's one full forward pass."""
-    ids = np.zeros((1, ENGINE["max_len"]), np.int32)
-    ids[0, : row.size] = row
-    positions = (prompt.size - 1 + np.arange(new_tokens))[None]
-    logits = reference.logits_at(cfg, SEED, ids, positions, jnp.float32)[0]
-    return logits.max(-1) - logits[np.arange(new_tokens), row[prompt.size:]]
+    reference's best ``[rows, new_tokens]``, in the reference's one full
+    forward pass over all the rows at once (each call compiles its programs)."""
+    ids = np.zeros((len(rows), ENGINE["max_len"]), np.int32)
+    for i, row in enumerate(rows):
+        ids[i, : row.size] = row
+    positions = np.stack([prompt.size - 1 + np.arange(new_tokens) for prompt in prompts])
+    logits = reference.logits_at(cfg, SEED, ids, positions, jnp.float32)
+    served = np.stack([row[prompt.size:] for prompt, row in zip(prompts, rows)])
+    return logits.max(-1) - np.take_along_axis(logits, served[..., None], -1)[..., 0]
 
 
 # -- (a) prefill, then decode through both kinds of cache, against the reference ---
@@ -69,8 +72,7 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_references_full_
     prompts = _prompts(cfg, [5, 23, 41, 12, 33, 2])
     rows = engine.generate_many(prompts, max_new_tokens=14)
     assert engine.stats.prefill_chunks > 0 and engine.compiles.compile_count >= 1
-    for prompt, row in zip(prompts, rows):
-        assert _served_gaps(cfg, prompt, row, 14).max() < 1e-4  # every served token is the reference's first choice
+    assert _served_gaps(cfg, prompts, rows, 14).max() < 1e-4  # every served token is the reference's first choice
     # the engine and plain generate() share one protocol: the same tokens at temperature 0
     for prompt, row in zip(prompts[:3], rows):
         assert np.array_equal(generate(model, params, prompt[None], max_new_tokens=14)[0], row)
@@ -83,8 +85,7 @@ def test_a_bucket_s_padding_never_enters_a_ring(tiny):
     cfg, model, params = tiny
     engine = ServingEngine(model, params, **{**ENGINE, "buckets": (16, 32), "prefill_chunk": None})
     prompts = _prompts(cfg, [18, 19, 26], seed=3)  # 17, 18, 25 prefilled tokens in a span of 32
-    for prompt, row in zip(prompts, engine.generate_many(prompts, max_new_tokens=10)):
-        assert _served_gaps(cfg, prompt, row, 10).max() < 1e-4
+    assert _served_gaps(cfg, prompts, engine.generate_many(prompts, max_new_tokens=10), 10).max() < 1e-4
 
 
 def test_a_decode_step_between_a_prompts_chunks_leaves_its_ring_alone(tiny):
@@ -106,7 +107,7 @@ def test_a_decode_step_between_a_prompts_chunks_leaves_its_ring_alone(tiny):
     assert engine.stats.prefill_chunks >= 3
     for prompt, rid, new in ((short, first, 20), (long, second, 8)):
         row = np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)])
-        assert _served_gaps(cfg, prompt, row, new).max() < 1e-4
+        assert _served_gaps(cfg, [prompt], [row], new).max() < 1e-4
 
 
 def test_the_kernel_program_writes_its_rings_by_the_kernel_and_serves_the_gather_programs_tokens(tiny, tmp_path):

@@ -44,11 +44,15 @@ def _prompts(cfg, lengths, seed=0):
     return [rng.integers(1, cfg["vocab_size"], (n,)).astype(np.int32) for n in lengths]
 
 
-def _reference_logits(cfg, prompt, row, new_tokens, pad_to=ENGINE["max_len"]):
-    ids = np.zeros((1, pad_to), np.int32)
-    ids[0, : row.size] = row
-    positions = (prompt.size - 1 + np.arange(new_tokens))[None]
-    return reference.logits_at(cfg, SEED, ids, positions, jnp.float32)[0]
+def _reference_logits(cfg, prompts, rows, new_tokens, pad_to=ENGINE["max_len"]):
+    """The reference's one full forward pass over the served rows, all at once
+    (each call compiles its programs): each row's logits at the
+    ``new_tokens`` positions that produced its served tokens."""
+    ids = np.zeros((len(rows), pad_to), np.int32)
+    for i, row in enumerate(rows):
+        ids[i, : row.size] = row
+    positions = np.stack([prompt.size - 1 + np.arange(new_tokens) for prompt in prompts])
+    return reference.logits_at(cfg, SEED, ids, positions, jnp.float32)
 
 
 def _run(engine, results=None):
@@ -58,10 +62,11 @@ def _run(engine, results=None):
     return results
 
 
-def _agrees(cfg, prompt, generated, new_tokens):
-    row = np.concatenate([prompt, np.asarray(generated, np.int32)])
-    want = _reference_logits(cfg, prompt, row, new_tokens)
-    return np.array_equal(want.argmax(-1), row[prompt.size:])
+def _agrees(cfg, prompts, generated, new_tokens):
+    """Whether each request's served tokens are the reference's first choices."""
+    rows = [np.concatenate([prompt, np.asarray(tokens, np.int32)]) for prompt, tokens in zip(prompts, generated)]
+    wants = _reference_logits(cfg, prompts, rows, new_tokens)
+    return [np.array_equal(want.argmax(-1), row[prompt.size:]) for prompt, row, want in zip(prompts, rows, wants)]
 
 
 # -- (a) prefill in one and in two chunks, then decode through pages and state: LOGITS against the reference ---
@@ -95,9 +100,8 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(tiny, use_ker
     results = _run(engine)
     jax.effects_barrier()
     reported = np.stack(seen)
-    for prompt, rid in zip(prompts, ids):
-        row = np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)])
-        want = _reference_logits(cfg, prompt, row, new)
+    rows = [np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)]) for prompt, rid in zip(prompts, ids)]
+    for prompt, row, want in zip(prompts, rows, _reference_logits(cfg, prompts, rows, new)):
         assert np.array_equal(want.argmax(-1), row[prompt.size:])  # every served token is the reference's first choice ...
         for logits in want:  # ... and the logits it was sampled from are the reference's: some lane of some step reported them
             assert np.abs(reported - logits).max(-1).min() < TOLERANCE
@@ -142,7 +146,7 @@ def test_an_inactive_lane_keeps_its_state_between_its_chunks(tiny):
     engine.cache.lengths[other] += 1  # the step made by hand wrote the active lane's token: keep the host's books with the device
     results = _run(engine)
     assert engine.stats.prefill_chunks >= 4
-    assert _agrees(cfg, long, results[second].generated, 8)
+    assert _agrees(cfg, [long], [results[second].generated], 8) == [True]
 
 
 def test_a_reused_lane_starts_from_zeros_whatever_it_held(tiny):
@@ -150,12 +154,13 @@ def test_a_reused_lane_starts_from_zeros_whatever_it_held(tiny):
     huge values between them: a span at position 0 resets it."""
     cfg, model, params = tiny
     engine = ServingEngine(model, params, **{**ENGINE, "num_slots": 1})
-    for n, prompt in enumerate(_prompts(cfg, [9, 30, 1], seed=11)):
+    prompts, generated = _prompts(cfg, [9, 30, 1], seed=11), []
+    for prompt in prompts:
         rid = engine.submit(prompt, max_new_tokens=6)
-        results = _run(engine)
-        assert _agrees(cfg, prompt, results[rid].generated, 6), n
+        generated.append(_run(engine)[rid].generated)
         extras = engine.cache.extras
         engine.cache.extras = extras._replace(ssm=jnp.full_like(extras.ssm, 1e6), conv=jnp.full_like(extras.conv, -50.0))
+    assert _agrees(cfg, prompts, generated, 6) == [True] * 3
     assert engine.stats.ssm_state_resets >= 2  # the one-token prompt has no prefill program: its first decode step resets
 
 
@@ -167,16 +172,17 @@ def test_a_buckets_padding_does_not_advance_the_state(tiny, use_kernels):
     served after it agrees with the reference."""
     cfg, model, params = tiny
     padded = ServingEngine(model, params, use_kernels=use_kernels, **{**ENGINE, "num_slots": 1, "buckets": (16, 32), "prefill_chunk": None})
-    exact = ServingEngine(model, params, use_kernels=use_kernels, **{**ENGINE, "num_slots": 1, "buckets": (17, 32), "prefill_chunk": None, "page_size": 1})
+    # one page of 17 holds the exact span whole, with no padding; the padded engine's span of 32 still crosses pages
+    exact = ServingEngine(model, params, use_kernels=use_kernels, **{**ENGINE, "num_slots": 1, "buckets": (17, 32), "prefill_chunk": None, "page_size": 17, "max_len": 85})
     [prompt] = _prompts(cfg, [18], seed=3)  # 17 tokens prefilled: a span of 32 with 15 of padding, or a span of 17 with none
-    states = []
+    states, generated = [], []
     for engine in (padded, exact):
         rid = engine.submit(prompt, max_new_tokens=8)
         engine.step()  # prefilled; a first decode program out
         engine._land()
-        results = _run(engine)
-        assert _agrees(cfg, prompt, results[rid].generated, 8)
+        generated.append(_run(engine)[rid].generated)
         states.append(engine.stats.prefill_tokens)
+    assert _agrees(cfg, [prompt, prompt], generated, 8) == [True, True]
     assert states == [32, 17]  # positions computed: one padded span of 32, one exact span of 17
 
 
@@ -211,7 +217,7 @@ def test_a_token_dropped_late_advanced_a_state_that_the_next_request_resets(tiny
     results = _run(engine)
     assert results[rid].finish_reason in ("length", "eos")
     generated = np.asarray(results[rid].generated, np.int32)
-    assert _agrees(cfg, after, generated, generated.size)
+    assert _agrees(cfg, [after], [generated], generated.size) == [True]
 
 
 # -- (c) the cache manager's third kind, counters and spans ---
@@ -289,7 +295,7 @@ def test_a_quarantined_lane_has_its_state_scrubbed_and_its_probe_recovers(tiny):
     assert not np.asarray(engine.cache.extras.ssm).any() and not np.asarray(engine.cache.extras.conv, np.float32).any()
     results = _run(engine)
     assert engine.stats.slot_quarantine_releases == 1 and not engine.cache.quarantined
-    assert _agrees(cfg, prompt, results[rid].generated, 6)
+    assert _agrees(cfg, [prompt], [results[rid].generated], 6) == [True]
 
 
 # -- (d) what a state cannot do is refused by name ---

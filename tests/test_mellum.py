@@ -47,13 +47,15 @@ def _prompts(cfg, lengths, seed=0):
     return [rng.integers(1, cfg["vocab_size"], (n,)).astype(np.int32) for n in lengths]
 
 
-def _reference_logits(cfg, prompt, row, new_tokens, pad_to):
-    """The reference's one full forward pass over a served row: its logits at
-    the ``new_tokens`` positions that produced the served tokens."""
-    ids = np.zeros((1, pad_to), np.int32)
-    ids[0, : row.size] = row
-    positions = (prompt.size - 1 + np.arange(new_tokens))[None]
-    return reference.logits_at(cfg, SEED, ids, positions, jnp.float32)[0]
+def _reference_logits(cfg, prompts, rows, new_tokens, pad_to):
+    """The reference's one full forward pass over the served rows, all at once
+    (each call compiles its programs): each row's logits at the
+    ``new_tokens`` positions that produced its served tokens."""
+    ids = np.zeros((len(rows), pad_to), np.int32)
+    for i, row in enumerate(rows):
+        ids[i, : row.size] = row
+    positions = np.stack([prompt.size - 1 + np.arange(new_tokens) for prompt in prompts])
+    return reference.logits_at(cfg, SEED, ids, positions, jnp.float32)
 
 
 # -- (a) prefill in chunks, then decode through pool and rings: LOGITS against the reference ---
@@ -91,9 +93,8 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(tiny, use_ker
     jax.effects_barrier()
     assert engine.stats.prefill_chunks >= 4
     reported = np.stack(seen)
-    for prompt, rid in zip(prompts, ids):
-        row = np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)])
-        want = _reference_logits(cfg, prompt, row, new, ENGINE["max_len"])
+    rows = [np.concatenate([prompt, np.asarray(results[rid].generated, np.int32)]) for prompt, rid in zip(prompts, ids)]
+    for prompt, row, want in zip(prompts, rows, _reference_logits(cfg, prompts, rows, new, ENGINE["max_len"])):
         assert np.array_equal(want.argmax(-1), row[prompt.size:])  # every served token is the reference's first choice ...
         for logits in want:  # ... and the logits it was sampled from are the reference's: some lane of some step reported them
             assert np.abs(reported - logits).max(-1).min() < 2e-4
@@ -135,8 +136,8 @@ def test_a_ring_longer_than_a_prefill_chunk_keeps_what_earlier_chunks_left(tiny)
     model, params = family.build(cfg), family.params(cfg, SEED, jnp.float32)
     engine = ServingEngine(model, params, num_slots=2, max_len=80, page_size=8, buckets=(8,), prefill_chunk=8)
     prompts = _prompts(cfg, [61, 30], seed=9)
-    for prompt, row in zip(prompts, engine.generate_many(prompts, max_new_tokens=12)):
-        want = _reference_logits(cfg, prompt, row, 12, 80)
+    rows = engine.generate_many(prompts, max_new_tokens=12)
+    for prompt, row, want in zip(prompts, rows, _reference_logits(cfg, prompts, rows, 12, 80)):
         assert np.array_equal(want.argmax(-1), row[prompt.size:])
     assert engine.stats.prefill_chunks >= 7
 
@@ -168,8 +169,9 @@ def test_a_long_view_is_attended_a_block_of_keys_at_a_time_and_only_its_live_blo
     model = configs.family(cfg).build(cfg)  # the blocked path is traced into programs cached on the model
     engine = ServingEngine(model, params, **ENGINE)
     prompts = _prompts(cfg, [61, 19], seed=13)
-    for prompt, row in zip(prompts, engine.generate_many(prompts, max_new_tokens=10)):
-        assert np.array_equal(_reference_logits(cfg, prompt, row, 10, ENGINE["max_len"]).argmax(-1), row[prompt.size:])
+    rows = engine.generate_many(prompts, max_new_tokens=10)
+    for prompt, row, want in zip(prompts, rows, _reference_logits(cfg, prompts, rows, 10, ENGINE["max_len"])):
+        assert np.array_equal(want.argmax(-1), row[prompt.size:])
 
 
 def test_small_expert_matrices_go_through_the_grouped_kernel_a_whole_matrix_a_tile():
